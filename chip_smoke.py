@@ -1,0 +1,398 @@
+"""Smoke test of the system's main path on a TPU: the quickest proof that it
+still starts on the chip.
+
+    python3 chip_smoke.py            # one chip: phases device, serve, pallas
+    python3 chip_smoke.py --chips 4  # four chips: phases device, train4
+
+Phases (each prints ``phase <name>: {...}`` lines; any failure raises, so
+the script exits non-zero and prints no result line):
+
+- ``device`` — JAX must see a TPU (and the chips asked for).  There is no
+  CPU fallback.
+- ``serve`` — qwen25_3b at its published widths and full depth, bf16
+  weights from seed 0, served through ``Router.replicate`` / ``Engine``
+  with the paged KV cache and the default XLA attention: 8 greedy requests
+  with prompts spread over 16–1024 tokens, 32 new tokens each.  The same
+  requests run twice: the first pass pays the compiles, the second is the
+  warm wall time, and both must give the same tokens.
+- ``pallas`` — the same requests with ``attn_impl="pallas"`` (flash prefill,
+  paged flash-decode).  The compiled decode step must hold a Mosaic kernel
+  (``tpu_custom_call``: nothing runs in interpret mode); first tokens must
+  equal phase serve's (a near-tie may resolve to another near-tied token:
+  ``TIE_MARGIN``) and prefill logits agree within ``LOGIT_TOL``; the
+  paged decode kernel alone matches the f32 oracle at serving widths.
+- ``train4`` (``--chips 4`` only) — the ``futurized`` trainer on a 2×2
+  ``("data", "model")`` mesh: qwen25_3b at full widths and ``TRAIN4_LAYERS``
+  layers (the deepest that fits) for 3 steps (per-chip peak memory shows
+  the state split), then
+  ``CMP_LAYERS`` layers on the mesh and on one chip from the same seed and
+  batches, whose losses agree within ``LOSS_TOL``.
+
+The last line of standard output is ``{"ok": true, "device": {...}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import sys
+import time
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
+
+ARCH = "qwen25_3b"
+SEED = 0
+PROMPT_LENS = (16, 100, 200, 300, 500, 700, 900, 1024)
+MAX_NEW = 32
+SERVE = dict(max_batch=8, cache_len=2048, page_size=16, max_new_tokens=MAX_NEW)
+# Prefill logits, XLA vs Pallas attention, as fractions of the reference
+# logits' standard deviation.  36 bf16 layers of random weights amplify
+# any rounding difference to a floor: on a v5e, two XLA programs that
+# differ only in the precision of one contraction (P·V at HIGH vs HIGHEST)
+# differ by rms 0.018, max 0.09; XLA vs the flash kernel by rms 0.019–
+# 0.020, max 0.094–0.107.  A wrong mask or head mapping gives uncorrelated
+# logits (rms ~1.4), so these bounds separate rounding from faults.
+LOGIT_TOL = {"rms": 0.05, "max": 0.25}
+# First tokens must be equal, except where the reference's top two logits
+# are nearer than TIE_MARGIN (of the std): a margin change between two
+# programs at that floor has std ≈ √2·0.019 = 0.027, and on the chip the
+# XLA programs above flipped the first token of a request whose margin was
+# 0.04.  For such a near-tie the Pallas token must itself be one of the
+# reference's near-tied top tokens.
+TIE_MARGIN = 0.1
+# paged decode kernel vs the f32 oracle, bf16 inputs (as tests/test_kernels)
+KERNEL_ATOL = 8e-2
+# Depth cut: memory_analysis of the futurized step on a described v5e 2x2
+# (batch 4 × 128 tokens) needs 16.23 GiB per chip at 36 layers (15.75 GiB
+# usable; 16.23 even at batch 2), 15.09 at 32, 14.25 at 30.  The four-layer
+# comparison needs 14.08 GiB on one chip.
+TRAIN4_LAYERS = 30
+CMP_LAYERS = 4
+TRAIN = dict(batch_size=4, seq_len=128)
+# Loss, one chip vs the 2×2 mesh, absolute, per step.  Same math; the
+# mesh splits every matmul's reduction and reduces gradients across chips
+# in another order, and compute is bf16.  Adam's first update is about
+# lr·sign(g), so gradients at rounding level flip whole steps: at lr 1e-2
+# the CPU rehearsal (4 virtual devices, smoke widths) differed by 1.1e-2,
+# at lr 1e-3 by 8.2e-4 while the loss fell by 0.40.
+LOSS_TOL = 2e-2
+
+
+def log(phase: str, **fields) -> None:
+    print(f"phase {phase}: {json.dumps(fields, default=str)}", flush=True)
+
+
+class CompileClock:
+    """Seconds the backend spent compiling, from JAX's own monitoring."""
+
+    def __init__(self):
+        import jax
+
+        self.total = 0.0
+
+        def on_event(event: str, secs: float, **_kw) -> None:
+            if event == "/jax/core/compile/backend_compile_duration":
+                self.total += secs
+
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+
+
+def phase_device(chips: int):
+    import jax
+
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        raise SystemExit(f"phase device: FAIL: JAX found no TPU "
+                         f"(platform {devs[0].platform!r})")
+    if len(devs) < chips:
+        raise SystemExit(f"phase device: FAIL: {chips} chips asked for, "
+                         f"{len(devs)} found")
+    log("device", platform=devs[0].platform, kind=devs[0].device_kind,
+        count=len(devs))
+    return devs
+
+
+def peak_bytes(device) -> int:
+    return int(device.memory_stats()["peak_bytes_in_use"])
+
+
+# ------------------------------------------------------------------- serving
+def make_prompts(vocab: int, lens=PROMPT_LENS):
+    import numpy as np
+
+    rng = np.random.default_rng(SEED)
+    return [rng.integers(1, vocab, size=n).tolist() for n in lens]
+
+
+def prefill_logits(engine, prompt):
+    """Last-position prefill logits through the engine's own bucketed,
+    jitted prefill (the program that produced the first token)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    bucket = engine._bucket_for(len(prompt))
+    toks = np.zeros((1, bucket), np.int32)
+    toks[0, :len(prompt)] = prompt
+    logits, _ = engine._prefill(engine.params, {"tokens": jnp.asarray(toks)},
+                                cache_len=bucket,
+                                valid_len=jnp.asarray([len(prompt)], jnp.int32))
+    return np.asarray(logits[0, :engine.model.cfg.vocab_size], np.float32)
+
+
+def decode_hlo(engine) -> str:
+    """Compiled text of the engine's decode step."""
+    import jax
+    import jax.numpy as jnp
+
+    B = engine.scfg.max_batch
+    return engine._decode.lower(
+        engine.params, engine.backend.device_cache(),
+        jnp.zeros((B, 1), jnp.int32), jax.random.PRNGKey(0),
+        jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
+        jnp.ones((B,), jnp.float32)).compile().as_text()
+
+
+def serve_requests(model, params, prompts, clock, scfg_kwargs=SERVE):
+    """Serve ``prompts`` twice through one router; returns the tokens, the
+    prefill logits and the engine."""
+    from repro.serve.engine import ServeConfig
+    from repro.serve.router import Router
+
+    router = Router.replicate(model, params, ServeConfig(**scfg_kwargs), 1)
+    c0 = clock.total
+    passes = []
+    for _ in range(2):
+        t0 = time.perf_counter()
+        futs = [router.submit(p) for p in prompts]
+        outs = [f.get(timeout=900) for f in futs]
+        passes.append((outs, time.perf_counter() - t0))
+    (cold, cold_s), (warm, warm_s) = passes
+    want = scfg_kwargs["max_new_tokens"] + 1  # prefill token + max_new
+    bad = [i for i, o in enumerate(cold) if len(o) != want]
+    if bad:
+        raise AssertionError(f"requests {bad} did not return {want} tokens")
+    if warm != cold:
+        raise AssertionError("second pass gave other tokens than the first")
+    engine = router.engines[0]
+    stats = dict(compile_s=clock.total - c0, cold_wall_s=cold_s,
+                 wall_s=warm_s, requests=len(cold),
+                 tokens=sum(len(o) for o in cold),
+                 tokens_per_s=sum(len(o) for o in cold) / warm_s)
+    logits = [prefill_logits(engine, p) for p in prompts]
+    return cold, logits, engine, stats
+
+
+def compare_logits(ref, got):
+    import numpy as np
+
+    worst = {"rms": 0.0, "max": 0.0}
+    for a, b in zip(ref, got):
+        sd = float(a.std())
+        d = np.abs(a - b)
+        worst["rms"] = max(worst["rms"], float(np.sqrt((d ** 2).mean())) / sd)
+        worst["max"] = max(worst["max"], float(d.max()) / sd)
+    return worst
+
+
+def top2_margins(logits):
+    """Gap between the two largest logits, in units of the logits' std:
+    how far each first token is from flipping."""
+    import numpy as np
+
+    return [float(np.diff(np.partition(a, -2)[-2:])[0] / a.std())
+            for a in logits]
+
+
+def first_tokens_agree(ref_logits, ref_first, got_first):
+    """Per request: the first tokens are equal, or the reference's top two
+    logits are a near-tie and ``got`` is within ``TIE_MARGIN`` of the
+    reference's best (see ``TIE_MARGIN``)."""
+    return [a == b or bool(m < TIE_MARGIN
+                           and (r.max() - r[b]) / r.std() < TIE_MARGIN)
+            for r, a, b, m in zip(ref_logits, ref_first, got_first,
+                                  top2_margins(ref_logits))]
+
+
+def paged_kernel_vs_oracle(cfg, B=8, page=16, maxp=128, seed=SEED):
+    """The paged flash-decode kernel at serving widths against the f32
+    oracle, on shuffled page lists with mixed fills; returns max |err|."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import ops, ref
+
+    H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    T = page * maxp
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, H, Dh), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, T, KV, Dh), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, T, KV, Dh), jnp.bfloat16)
+    rng = np.random.default_rng(seed)
+    P = B * maxp + 1
+    pt = jnp.asarray(1 + rng.permutation(P - 1).reshape(B, maxp), jnp.int32)
+
+    def pool(x):  # (B, T, KV, Dh) → (P, KV, page, Dh) at the table's pages
+        x = x.reshape(B * maxp, page, KV, Dh).transpose(0, 2, 1, 3)
+        return jnp.zeros((P, KV, page, Dh), x.dtype).at[pt.reshape(-1)].set(x)
+
+    lens = jnp.asarray(rng.integers(1, T + 1, size=B), jnp.int32)
+    o = ops.paged_decode_attention(q, pool(k), pool(v), pt, lens)
+    e = ref.decode_mha(q, k, v, length=lens)
+    return float(jnp.max(jnp.abs(o.astype(jnp.float32) - e.astype(jnp.float32))))
+
+
+def phase_serve_and_pallas(dev) -> None:
+    import jax
+
+    import repro.core as core
+    from repro.configs import get_config
+    from repro.dist.plan import get_plan
+    from repro.models.model import build_model
+
+    clock = CompileClock()
+    core.init(pools={"default": 4, "prefill": 2, "io": 1})
+    cfg = dataclasses.replace(get_config(ARCH), param_dtype="bfloat16")
+    plan = get_plan("serve")
+    model = build_model(cfg, plan)
+    t0 = time.perf_counter()
+    # one program: each weight is drawn and cast in place, so no f32 copy
+    # of a stacked weight ever sits beside the bf16 ones
+    params = jax.block_until_ready(jax.jit(model.init)(jax.random.PRNGKey(SEED)))
+    log("serve", arch=cfg.name, layers=cfg.num_layers, d_model=cfg.d_model,
+        param_dtype=cfg.param_dtype, attn_impl=cfg.attn_impl,
+        init_s=time.perf_counter() - t0,
+        bytes_in_use_after_init=int(dev.memory_stats()["bytes_in_use"]),
+        peak_bytes_after_init=peak_bytes(dev), prompt_lens=list(PROMPT_LENS),
+        **SERVE)
+    prompts = make_prompts(cfg.vocab_size)
+
+    toks, logits, _, stats = serve_requests(model, params, prompts, clock)
+    log("serve", peak_bytes_in_use=peak_bytes(dev), **stats)
+
+    pcfg = dataclasses.replace(cfg, attn_impl="pallas")
+    ptoks, plogits, pengine, pstats = serve_requests(
+        build_model(pcfg, plan), params, prompts, clock)
+    log("pallas", attn_impl=pcfg.attn_impl, peak_bytes_in_use=peak_bytes(dev),
+        **pstats)
+    kernels = decode_hlo(pengine).count("tpu_custom_call")
+    ref_first, got_first = [t[0] for t in toks], [t[0] for t in ptoks]
+    argmax_ok = [int(r.argmax()) == a for r, a in zip(logits, ref_first)]
+    first_ok = first_tokens_agree(logits, ref_first, got_first)
+    agree = [sum(x == y for x, y in zip(a, b)) for a, b in zip(toks, ptoks)]
+    worst = compare_logits(logits, plogits)
+    kerr = paged_kernel_vs_oracle(cfg)
+    log("pallas", decode_tpu_custom_calls=kernels,
+        first_token_match=[a == b for a, b in zip(ref_first, got_first)],
+        first_token_ok=first_ok, top2_margin=top2_margins(logits),
+        tie_margin=TIE_MARGIN, tokens_equal_to_serve=agree,
+        logit_err=worst, logit_tol=LOGIT_TOL,
+        paged_kernel_max_err=kerr, paged_kernel_atol=KERNEL_ATOL)
+    if kernels == 0:
+        raise AssertionError("decode step compiled without a Mosaic kernel")
+    if not all(argmax_ok):
+        raise AssertionError(f"served first tokens are not the argmax of "
+                             f"the prefill logits: {argmax_ok}")
+    if not all(first_ok):
+        raise AssertionError(f"first tokens differ from phase serve: {first_ok}")
+    if any(worst[k] > LOGIT_TOL[k] for k in LOGIT_TOL):
+        raise AssertionError(f"prefill logits {worst} exceed {LOGIT_TOL}")
+    if not kerr <= KERNEL_ATOL:
+        raise AssertionError(f"paged kernel error {kerr} > {KERNEL_ATOL}")
+    core.finalize()
+
+
+# ------------------------------------------------------------------ training
+def guard_replicated(plan, specs, mesh):
+    """Parameters the plan's divisibility guard kept from a mesh axis: the
+    spec at the real shape differs from the spec at a shape every axis
+    divides.  Returns (count, elements)."""
+    import numpy as np
+
+    n = int(np.prod(list(dict(mesh.shape).values())))
+    hit = [s for s in specs.values()
+           if plan.spec(s.axes, s.shape, mesh)
+           != plan.spec(s.axes, tuple(d * n for d in s.shape), mesh)]
+    return len(hit), int(sum(np.prod(s.shape) for s in hit))
+
+
+def train(cfg, mesh, steps=3, lr=1e-3):
+    """Train ``steps`` steps from seed 0 and free the state; returns the
+    model, the losses, the wall seconds and the chips the state was on."""
+    import jax
+
+    from repro.core import agas as _agas
+    from repro.data.pipeline import DataConfig
+    from repro.dist.plan import get_plan
+    from repro.models.model import build_model
+    from repro.optim.adamw import AdamWConfig
+    from repro.train.trainer import TrainConfig, Trainer
+
+    model = build_model(cfg, get_plan("futurized"))
+    tr = Trainer(model, AdamWConfig(lr=lr, warmup_steps=1, total_steps=steps),
+                 DataConfig(**TRAIN), TrainConfig(steps=steps, log_every=1),
+                 mesh=mesh, rng_seed=SEED)
+    t0 = time.perf_counter()
+    hist = tr.fit()
+    wall = time.perf_counter() - t0
+    devices = sorted({d.id for a in jax.tree.leaves(tr.params)
+                      for d in a.sharding.device_set})
+    _agas.default().unregister(tr.gid)  # the last reference to the state
+    return model, [h["loss"] for h in hist], wall, devices
+
+
+def phase_train4(devs) -> None:
+    import repro.core as core
+    from repro.configs import get_config
+    from repro.launch.mesh import make_mesh_shape
+
+    clock = CompileClock()
+    core.init(pools={"default": 2, "io": 1})
+    mesh = make_mesh_shape((2, 2), ("data", "model"))
+    full = get_config(ARCH)
+    cfg = dataclasses.replace(full, num_layers=TRAIN4_LAYERS)
+    model, losses, wall, on = train(cfg, mesh)
+    n_rep, el_rep = guard_replicated(model.plan, model.param_specs(), mesh)
+    log("train4", arch=cfg.name, layers=cfg.num_layers,
+        published_layers=full.num_layers, mesh=dict(mesh.shape),
+        plan=model.plan.name, **TRAIN, losses=losses, wall_s=wall,
+        compile_s=clock.total, state_devices=on,
+        guard_replicated_params=n_rep, guard_replicated_elements=el_rep,
+        peak_bytes_in_use={d.id: peak_bytes(d) for d in devs})
+    small = dataclasses.replace(full, num_layers=CMP_LAYERS)
+    _, mesh_losses, _, _ = train(small, mesh)
+    _, one_losses, _, one_on = train(small, None)
+    diff = max(abs(a - b) for a, b in zip(mesh_losses, one_losses))
+    log("train4", compare_layers=CMP_LAYERS, mesh_losses=mesh_losses,
+        one_chip_losses=one_losses, one_chip_state_devices=one_on,
+        max_abs_diff=diff, tol=LOSS_TOL)
+    if not diff <= LOSS_TOL:
+        raise AssertionError(f"mesh and one-chip losses differ by {diff}")
+    core.finalize()
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the sharded-trainer phase on a 2x2 mesh")
+    args = ap.parse_args()
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+    devs = phase_device(args.chips)
+    if args.chips == 4:
+        phase_train4(devs)
+    else:
+        phase_serve_and_pallas(devs[0])
+    import jax
+
+    print(json.dumps({"ok": True, "device": {
+        "platform": devs[0].platform, "kind": devs[0].device_kind,
+        "count": len(jax.devices())}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -15,6 +15,4 @@ Public API mirrors the HPX surface:
   repro.core.algorithms                    — C++17-style parallel algorithms
 """
 
-from repro import _compat  # noqa: F401  (backfills old-JAX API gaps; must be first)
-
 __version__ = "1.0.0"

@@ -235,15 +235,11 @@ class MeshExecutor(Executor):
         import jax
         import jax.numpy as jnp
 
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # jax<0.5 spelling
-            from jax.experimental.shard_map import shard_map
-
         def _body(x):  # axis=0: elements may be batched arrays
             return jax.lax.psum(jnp.sum(x, axis=0), self.axis)
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 _body,
                 mesh=self.mesh,
                 in_specs=jax.sharding.PartitionSpec(self.axis),

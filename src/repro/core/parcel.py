@@ -14,7 +14,7 @@ TPU/JAX adaptation — two transport planes:
    whose operands never leave their shards.
 
 2. **Device plane**: inside an XLA program, parcel transport *is* a
-   collective.  ``shard_parcel`` wraps ``jax.experimental.shard_map`` so an
+   collective.  ``shard_parcel`` wraps ``jax.shard_map`` so an
    action body executes per-shard with explicit collectives available; the
    flagship production user is MoE expert dispatch (``models/moe.py``) where
    tokens are parcels ``all_to_all``-routed to expert localities.

@@ -28,7 +28,6 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -55,7 +54,6 @@ def pod_manual_value_and_grad(loss_fn: Callable, mesh: Any,
     """
     axis = _pod_axis(mesh)
     n_pods = dict(mesh.shape)[axis]
-    auto = frozenset(a for a in mesh.axis_names if a != axis)
 
     def vg(params, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -71,10 +69,10 @@ def pod_manual_value_and_grad(loss_fn: Callable, mesh: Any,
 
         return loss, jax.tree.map(reduce_grad, grads)
 
-    return shard_map(vg, mesh,
-                     in_specs=(P(), P(axis)),
-                     out_specs=(P(), P()),
-                     check_rep=False, auto=auto)
+    return jax.shard_map(vg, mesh=mesh,
+                         in_specs=(P(), P(axis)),
+                         out_specs=(P(), P()),
+                         axis_names={axis}, check_vma=False)
 
 
 def all_gather_tree(tree: Any, mesh: Any, axis: str | None = None,
@@ -92,11 +90,10 @@ def all_gather_tree(tree: Any, mesh: Any, axis: str | None = None,
             lambda x: jax.lax.all_gather(x, axis, tiled=tiled and jnp.ndim(x) > 0),
             t)
 
-    auto = frozenset(a for a in mesh.axis_names if a != axis)
     # partial-auto shard_map only has a jit lowering (no eager impl)
-    return jax.jit(shard_map(gather, mesh, in_specs=(in_specs,),
-                             out_specs=P(), check_rep=False,
-                             auto=auto))(tree)
+    return jax.jit(jax.shard_map(gather, mesh=mesh, in_specs=(in_specs,),
+                                 out_specs=P(), axis_names={axis},
+                                 check_vma=False))(tree)
 
 
 # ------------------------------------------------------- error feedback

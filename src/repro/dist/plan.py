@@ -43,8 +43,6 @@ from typing import Any, Dict, Mapping, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import _compat
-
 # A rule value: mesh-axis name, preference-ordered tuple of mesh axes (the
 # dim is sharded over every present one jointly), or None (replicate).
 Rule = Union[str, Tuple[str, ...], None]
@@ -57,7 +55,13 @@ def _active_mesh() -> Optional[Any]:
     (``models/moe.py``) — model code runs unchanged on bare CPU (no mesh →
     constraints are no-ops) and on production meshes.
     """
-    return _compat.active_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        return mesh
+    from jax.interpreters import pxla  # legacy ``with mesh:`` resource env
+
+    phys = pxla.thread_resources.env.physical_mesh
+    return None if phys.empty else phys
 
 
 def _mesh_sizes(mesh: Any) -> Dict[str, int]:

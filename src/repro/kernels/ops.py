@@ -71,24 +71,18 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      interpret: Optional[bool] = None) -> jax.Array:
     """q: (B,H,Dh), k/v: (B,T,KV,Dh) → (B,H,Dh).
 
-    ``length`` is the valid cache prefix: a scalar (uniform fill, the
-    non-paged reference fast path) or (B,) per-slot (continuous batching —
-    every slot at its own depth)."""
+    ``length`` is the valid cache prefix: a scalar (uniform fill) or (B,)
+    per-slot (continuous batching — every slot at its own depth)."""
     if interpret is None:
         interpret = not _on_tpu()
     B, H, Dh = q.shape
     T, KV = k.shape[1], k.shape[2]
-    G = H // KV
-    qr = q.reshape(B * KV * G, Dh)
-    kr = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1).reshape(B * KV * G, T, Dh)
-    vr = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(B * KV * G, T, Dh)
-    block_k = min(block_k, max(128, T))
-    kr, _ = _pad_to(kr, 1, block_k)
-    vr, _ = _pad_to(vr, 1, block_k)
-    length = jnp.minimum(jnp.asarray(length, jnp.int32), T)
-    if length.ndim == 1:  # (B,) → one entry per kernel row
-        length = jnp.repeat(length, KV * G)
-    o = decode_attention_fwd(qr, kr, vr, length,
+    block_k = min(block_k, -(-T // 128) * 128)
+    kr, _ = _pad_to(k.transpose(0, 2, 1, 3), 2, block_k)  # (B, KV, T, Dh)
+    vr, _ = _pad_to(v.transpose(0, 2, 1, 3), 2, block_k)
+    lengths = jnp.broadcast_to(
+        jnp.minimum(jnp.asarray(length, jnp.int32), T), (B,))
+    o = decode_attention_fwd(q.reshape(B, KV, H // KV, Dh), kr, vr, lengths,
                              block_k=block_k, interpret=interpret)
     return o.reshape(B, H, Dh)
 
@@ -99,19 +93,17 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Paged flash-decode over a block-pool KV cache.
 
-    q: (B,H,Dh); k_pages/v_pages: (P, page, KV, Dh); page_table: (B, maxp)
+    q: (B,H,Dh); k_pages/v_pages: (P, KV, page, Dh); page_table: (B, maxp)
     int32 (entries past the fill must be valid pool indices, e.g. 0);
-    lengths: (B,) int32 → (B,H,Dh).  No dense gather — each kernel row
-    walks its own page list via the scalar-prefetched table.
+    lengths: (B,) int32 → (B,H,Dh).  No dense gather — each request walks
+    its own page list via the scalar-prefetched table.
     """
     if interpret is None:
         interpret = not _on_tpu()
     B, H, Dh = q.shape
-    KV = k_pages.shape[2]
-    G = H // KV
-    qr = q.reshape(B * KV * G, Dh)
-    o = paged_decode_attention_fwd(qr, k_pages, v_pages, page_table,
-                                   lengths, num_kv_heads=KV,
+    KV = k_pages.shape[1]
+    o = paged_decode_attention_fwd(q.reshape(B, KV, H // KV, Dh), k_pages,
+                                   v_pages, page_table, lengths,
                                    interpret=interpret)
     return o.reshape(B, H, Dh)
 
@@ -120,16 +112,19 @@ def gather_paged_kv(k_pages: jax.Array, v_pages: jax.Array,
                     page_table: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Materialize per-request dense caches from the block pool.
 
-    k_pages/v_pages: (P, page, KV, Dh), page_table: (B, maxp)
-    → (B, maxp·page, KV, Dh).  The XLA (non-Pallas) decode path and the
+    k_pages/v_pages: (P, KV, page, Dh), page_table: (B, maxp)
+    → (B, KV, maxp·page, Dh).  The XLA (non-Pallas) decode path and the
     test oracles use this; the Pallas path never materializes it.
     """
-    P, page, KV, Dh = k_pages.shape
+    P, KV, page, Dh = k_pages.shape
     B, maxp = page_table.shape
-    k = jnp.take(k_pages, page_table.reshape(-1), axis=0)
-    v = jnp.take(v_pages, page_table.reshape(-1), axis=0)
-    return (k.reshape(B, maxp * page, KV, Dh),
-            v.reshape(B, maxp * page, KV, Dh))
+
+    def dense(pages):
+        g = jnp.take(pages, page_table.reshape(-1), axis=0)
+        g = g.reshape(B, maxp, KV, page, Dh).transpose(0, 2, 1, 3, 4)
+        return g.reshape(B, KV, maxp * page, Dh)
+
+    return dense(k_pages), dense(v_pages)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the device
 # count at first init).  This module is the ONLY place that forces 512
 # placeholder devices — smoke tests and benches see the real CPU device.
+# It is an emulation tool, pinned to the CPU (its --all children inherit
+# the pin): it never opens an accelerator another process may hold.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 

@@ -110,6 +110,9 @@ def main() -> None:
     from repro.models.model import build_model
     from repro.serve.engine import SamplingParams, ServeConfig
     from repro.serve.router import Router, default_extra_inputs
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     # Resource partition: decode continuations on "default", prefill on its
     # own pool, host I/O (logging/ckpt/parcel pumps) on "io" — capacity goes

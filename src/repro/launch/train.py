@@ -66,6 +66,9 @@ def main() -> None:
     from repro.models.model import build_model
     from repro.optim.adamw import AdamWConfig
     from repro.train.trainer import TrainConfig, Trainer
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     # Resource partition: compute-plane tasks on "default", prefetch
     # assembly + checkpoint writes on the single-worker "io" pool.  A

@@ -332,8 +332,9 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
                            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token attention against a *paged* KV cache.
 
-    x: (B, 1, D); k_pages/v_pages: (P, page, KV, Dh) block pool shared by
-    all requests; page_table: (B, maxp) int32 (per-request page lists, 0-
+    x: (B, 1, D); k_pages/v_pages: (P, KV, page, Dh) block pool shared by
+    all requests (head-major pages: the kernel's tiling wants (page, Dh)
+    last); page_table: (B, maxp) int32 (per-request page lists, 0-
     padded past the fill — page 0 is the pool's reserved scratch page);
     pos: (B,) current fill per slot.  The new token's K/V are scattered
     into page ``page_table[b, pos//page]`` at offset ``pos % page``;
@@ -350,7 +351,7 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     B, _, D = x.shape
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // KV
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     q = x @ p[f"{prefix}wq"].astype(dt)
     k = x @ p[f"{prefix}wk"].astype(dt)
     v = x @ p[f"{prefix}wv"].astype(dt)
@@ -366,8 +367,8 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
         k = _rope_single(cfg, k, pos)
     pidx = page_table[jnp.arange(B), pos // page]  # (B,) destination pages
     off = pos % page
-    k_pages = k_pages.at[pidx, off].set(k.astype(k_pages.dtype))
-    v_pages = v_pages.at[pidx, off].set(v.astype(v_pages.dtype))
+    k_pages = k_pages.at[pidx, :, off].set(k.astype(k_pages.dtype))
+    v_pages = v_pages.at[pidx, :, off].set(v.astype(v_pages.dtype))
     lengths = pos + 1
 
     from repro.kernels import ops as kops
@@ -378,14 +379,14 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
         o = o.reshape(B, 1, H * Dh)
     else:
         kc, vc = kops.gather_paged_kv(k_pages, v_pages, page_table)
-        T = kc.shape[1]
+        T = kc.shape[2]
         qh = q.reshape(B, KV, G, Dh)
-        s = jnp.einsum("bkgd,btkd->bkgt", qh, kc.astype(dt),
+        s = jnp.einsum("bkgd,bktd->bkgt", qh, kc.astype(dt),
                        preferred_element_type=jnp.float32) / math.sqrt(Dh)
         valid = jnp.arange(T)[None, :] < lengths[:, None]
         s = jnp.where(valid[:, None, None, :], s, -1e30)
         pr = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bkgt,btkd->bkgd", pr.astype(dt), vc.astype(dt))
+        o = jnp.einsum("bkgt,bktd->bkgd", pr.astype(dt), vc.astype(dt))
         o = o.reshape(B, 1, H * Dh)
     return o @ p[f"{prefix}wo"].astype(dt), k_pages, v_pages
 

@@ -16,6 +16,7 @@ precomputed frame embeddings, ``vlm`` takes precomputed patch embeddings.
 
 from __future__ import annotations
 
+from dataclasses import replace
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -34,18 +35,22 @@ class Model:
         fam = cfg.family
         if fam in ("dense", "moe", "vlm"):
             self._m = transformer
-            self._specs = transformer.decoder_param_specs(cfg)
+            specs = transformer.decoder_param_specs(cfg)
         elif fam == "ssm":
             self._m = ssm_lm
-            self._specs = ssm_lm.lm_param_specs(cfg)
+            specs = ssm_lm.lm_param_specs(cfg)
         elif fam == "hybrid":
             self._m = hybrid
-            self._specs = hybrid.hybrid_param_specs(cfg)
+            specs = hybrid.hybrid_param_specs(cfg)
         elif fam == "encdec":
             self._m = encdec
-            self._specs = encdec.encdec_param_specs(cfg)
+            specs = encdec.encdec_param_specs(cfg)
         else:
             raise ValueError(f"unknown family {fam!r}")
+        # weights are stored at the config's param dtype (f32 by default;
+        # serving passes bf16), whatever the family builders declare
+        dt = jnp.dtype(cfg.param_dtype)
+        self._specs = {p: replace(s, dtype=dt) for p, s in specs.items()}
 
     # ---------------------------------------------------------------- params
     def param_specs(self) -> Dict[str, ParamSpec]:

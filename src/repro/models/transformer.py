@@ -239,21 +239,22 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int,
                       ) -> Dict[str, jax.ShapeDtypeStruct]:
     """Abstract *paged* KV-cache pytree: a block pool of ``num_pages`` fixed
     ``page_size`` pages shared by every layer (same page index holds a
-    request's tokens in all layers, vLLM-style), plus per-slot page tables
-    and fill positions.  Memory scales with live tokens, not
+    request's tokens in all layers, vLLM-style; each page is head-major,
+    ``(KV, page, Dh)``, as the paged decode kernel tiles it), plus per-slot
+    page tables and fill positions.  Memory scales with live tokens, not
     ``max_batch × cache_len``."""
     KV, Dh = cfg.num_kv_heads, cfg.head_dim
     fd, Lm = cfg.first_dense, cfg.num_layers - cfg.first_dense
     dt = jnp.dtype(cfg.dtype)
     specs = {
-        "k": jax.ShapeDtypeStruct((Lm, num_pages, page_size, KV, Dh), dt),
-        "v": jax.ShapeDtypeStruct((Lm, num_pages, page_size, KV, Dh), dt),
+        "k": jax.ShapeDtypeStruct((Lm, num_pages, KV, page_size, Dh), dt),
+        "v": jax.ShapeDtypeStruct((Lm, num_pages, KV, page_size, Dh), dt),
         "page_table": jax.ShapeDtypeStruct((max_batch, max_pages_per_req), jnp.int32),
         "pos": jax.ShapeDtypeStruct((max_batch,), jnp.int32),
     }
     if fd > 0:
-        specs["k0"] = jax.ShapeDtypeStruct((fd, num_pages, page_size, KV, Dh), dt)
-        specs["v0"] = jax.ShapeDtypeStruct((fd, num_pages, page_size, KV, Dh), dt)
+        specs["k0"] = jax.ShapeDtypeStruct((fd, num_pages, KV, page_size, Dh), dt)
+        specs["v0"] = jax.ShapeDtypeStruct((fd, num_pages, KV, page_size, Dh), dt)
     return specs
 
 

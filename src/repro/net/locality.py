@@ -542,6 +542,7 @@ class NetRuntime:
         locality id."""
         if not self.is_root():
             raise RuntimeError("spawn_locality is root-only")
+        _require_chip_free_workers()
         import multiprocessing as _mp
 
         with self._topo_lock:
@@ -762,6 +763,31 @@ def require() -> NetRuntime:
 
 
 # ---------------------------------------------------------------- bootstrap
+_TPU_DEVICE_GLOBS = ("/dev/accel*", "/dev/vfio/[0-9]*")
+
+
+def _require_chip_free_workers() -> None:
+    """One process per chip: a TPU belongs to one process at a time, and a
+    worker locality that opens it while another process holds it fails or
+    hangs.  Workers inherit this process's environment, so spawning them
+    is refused at once when the host has a TPU and the inherited
+    ``JAX_PLATFORMS`` would let them reach it.  Decided from the
+    environment and the host's device files — never by initialising JAX
+    here, which would itself take the chip."""
+    import glob
+
+    platforms = os.environ.get("JAX_PLATFORMS", "").lower()
+    if platforms and "tpu" not in platforms.split(","):
+        return  # e.g. JAX_PLATFORMS=cpu: workers never touch the chip
+    found = [d for pat in _TPU_DEVICE_GLOBS for d in glob.glob(pat)]
+    if found:
+        raise RuntimeError(
+            f"one process per chip: this host has a TPU ({found[0]}) and "
+            f"worker localities would each open it. Run multi-locality "
+            f"with JAX_PLATFORMS=cpu, or serve replicas in one process "
+            f"(Router.replicate).")
+
+
 def _accept_worker_lanes(net: NetRuntime, listener: socket.socket,
                          n_workers: int, nlanes: int, timeout: float,
                          half_open: Dict[int, Dict[int, socket.socket]]
@@ -813,6 +839,8 @@ def bootstrap(n_localities: int, pools: Optional[Dict[str, int]] = None,
 
     if n_localities < 1:
         raise ValueError("need at least one locality")
+    if n_localities > 1:
+        _require_chip_free_workers()
     core.init(pools=pools)
     net = NetRuntime(ROOT, n_localities, config=config)
     if n_localities == 1:  # degenerate but useful: uniform API, no workers

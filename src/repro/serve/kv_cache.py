@@ -48,7 +48,7 @@ _POOL_KEYS = ("k", "v", "k0", "v0")
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_pages(pool: jax.Array, src: jax.Array,
                    page_ids: jax.Array) -> jax.Array:
-    """pool (L,P,page,KV,Dh) ← src (L,npg,page,KV,Dh) at pages ``page_ids``."""
+    """pool (L,P,KV,page,Dh) ← src (L,npg,KV,page,Dh) at pages ``page_ids``."""
     return pool.at[:, page_ids].set(src.astype(pool.dtype))
 
 
@@ -124,7 +124,8 @@ class PagedKVCache:
             if pad > 0:
                 src = jnp.pad(src, ((0, 0), (0, pad), (0, 0), (0, 0)))
             src = src[:, : npg * self.page_size]
-            src = src.reshape(L, npg, self.page_size, KV, Dh)
+            src = src.reshape(L, npg, self.page_size, KV, Dh
+                              ).transpose(0, 1, 3, 2, 4)
             self.pools[key] = _scatter_pages(self.pools[key], src, ids)
         self._owned[slot] = pages
         self.page_table[slot, :] = 0

@@ -66,11 +66,18 @@ class Trainer:
         # prefetch assembly and checkpoint writes run on the "io" pool.
         _sched.get_runtime().add_pool("io", 1)
 
-        self.params = model.init(jax.random.PRNGKey(rng_seed))
-        self.opt_state = adamw.init(self.params)
+        key = jax.random.PRNGKey(rng_seed)
+        if mesh is None:
+            self.params = model.init(key)
+            self.opt_state = adamw.init(self.params)
+        else:
+            # state is created already split over the mesh by the plan's
+            # shardings — never whole on one chip first
+            p_sh, o_sh = step_mod.train_state_shardings(model, mesh)
+            self.params = jax.jit(model.init, out_shardings=p_sh)(key)
+            self.opt_state = jax.jit(adamw.init, out_shardings=o_sh)(self.params)
         self.step_num = 0
-        self._step_fn = jax.jit(step_mod.make_train_step(model, opt_cfg, mesh),
-                                donate_argnums=(0, 1))
+        self._step_fn = self._jit_step(mesh)
         # Any ``get(step) -> Future[batch]`` source plugs in — notably
         # ``data.pipeline.LocalShardFeeder`` (locality-sharded datasets:
         # this trainer then feeds exclusively from segments its own
@@ -87,6 +94,27 @@ class Trainer:
         self.c_steps = reg.counter("/train{loop#0}/steps/cumulative")
         self.c_straggler = reg.counter("/train{loop#0}/stragglers/detected")
         self.g_loss = reg.gauge("/train{loop#0}/loss/instantaneous")
+
+    def _jit_step(self, mesh):
+        """The jitted step.  On a mesh its outputs keep the plan's state
+        shardings, each batch is split over the batch axes, and it is
+        traced under the mesh, so the model's logical sharding constraints
+        resolve against it."""
+        step = step_mod.make_train_step(self.model, self.opt_cfg, mesh)
+        if mesh is None:
+            return jax.jit(step, donate_argnums=(0, 1))
+        p_sh, o_sh = step_mod.train_state_shardings(self.model, mesh)
+        jitted = jax.jit(step, donate_argnums=(0, 1),
+                         out_shardings=(p_sh, o_sh,
+                                        self.model.plan.replicated(mesh)))
+
+        def run(params, opt_state, batch):
+            batch = jax.device_put(
+                batch, step_mod.batch_shardings(self.model, mesh, batch))
+            with jax.set_mesh(mesh):
+                return jitted(params, opt_state, batch)
+
+        return run
 
     # ------------------------------------------------------------------ fit
     def fit(self, steps: Optional[int] = None) -> List[Dict[str, float]]:
@@ -145,20 +173,11 @@ class Trainer:
     def elastic_restart(self, new_mesh) -> None:
         """Migrate live state onto a different mesh (failure shrink / regrow)
         and rebuild the step function against it."""
-        plan = self.model.plan
-        specs = self.model.param_specs()
-        p_sh = plan.param_shardings(specs, new_mesh)
-        o_ax = adamw.state_axes(specs)
-        o_sh = {
-            "m": {k: plan.sharding(o_ax["m"][k], specs[k].shape, new_mesh) for k in specs},
-            "v": {k: plan.sharding(o_ax["v"][k], specs[k].shape, new_mesh) for k in specs},
-            "step": plan.replicated(new_mesh),
-        }
+        p_sh, o_sh = step_mod.train_state_shardings(self.model, new_mesh)
         self.params = migration.migrate_tree(self.params, p_sh)
         self.opt_state = migration.migrate_tree(self.opt_state, o_sh)
         self.mesh = new_mesh
-        self._step_fn = jax.jit(step_mod.make_train_step(self.model, self.opt_cfg, new_mesh),
-                                donate_argnums=(0, 1))
+        self._step_fn = self._jit_step(new_mesh)
         _agas.default().rebind(self.gid,
                                {"params": self.params, "opt": self.opt_state},
                                placement=new_mesh)

@@ -1,11 +1,5 @@
 import pytest
 
-# Backfill optional test deps before any test module imports them: the shim
-# registers itself as `hypothesis` ONLY when the real library is missing.
-from repro import _hypothesis_shim
-
-_hypothesis_shim.install_if_missing()
-
 
 @pytest.fixture(scope="session")
 def rt():

@@ -12,15 +12,7 @@ import threading
 
 import numpy as np
 import pytest
-
-# Worker localities import THIS module to resolve shipped bodies by
-# reference; they don't run conftest, so the hypothesis backfill must be
-# installed here before the import below (inert when the real lib exists).
-from repro import _hypothesis_shim
-
-_hypothesis_shim.install_if_missing()
-
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from repro import net as rnet
 from repro.core import algorithms as alg

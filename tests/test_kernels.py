@@ -84,6 +84,7 @@ def test_decode_attention_per_row_lengths(dtype):
     (3, 4, 2, 64, 32, 8),   # GQA
     (2, 8, 8, 32, 16, 4),   # MHA, small pages
     (1, 8, 1, 64, 64, 4),   # MQA
+    (2, 24, 2, 128, 16, 6),  # starcoder2-3b heads: G=12, not a multiple of 8
 ])
 def test_paged_decode_attention_matches_oracle(B, H, KV, Dh, page, maxp, dtype):
     """Paged kernel walking shuffled per-request page lists == dense oracle."""
@@ -95,12 +96,13 @@ def test_paged_decode_attention_matches_oracle(B, H, KV, Dh, page, maxp, dtype):
     rng = np.random.default_rng(0)
     P = B * maxp + 3  # pool with spare pages; page 0 reserved
     perm = 1 + rng.permutation(P - 1)[: B * maxp].reshape(B, maxp)
-    k_pages = np.zeros((P, page, KV, Dh), np.float32)
-    v_pages = np.zeros((P, page, KV, Dh), np.float32)
+    k_pages = np.zeros((P, KV, page, Dh), np.float32)
+    v_pages = np.zeros((P, KV, page, Dh), np.float32)
     for b in range(B):
         for j in range(maxp):
-            k_pages[perm[b, j]] = np.asarray(k[b, j * page:(j + 1) * page], np.float32)
-            v_pages[perm[b, j]] = np.asarray(v[b, j * page:(j + 1) * page], np.float32)
+            blk = slice(j * page, (j + 1) * page)
+            k_pages[perm[b, j]] = np.asarray(k[b, blk], np.float32).transpose(1, 0, 2)
+            v_pages[perm[b, j]] = np.asarray(v[b, blk], np.float32).transpose(1, 0, 2)
     lens = jnp.asarray(rng.integers(1, T + 1, size=B), jnp.int32)
     o = ops.paged_decode_attention(q, jnp.asarray(k_pages, dtype),
                                    jnp.asarray(v_pages, dtype),
@@ -113,13 +115,13 @@ def test_paged_decode_attention_matches_oracle(B, H, KV, Dh, page, maxp, dtype):
 def test_gather_paged_kv_roundtrip():
     P, page, KV, Dh, B, maxp = 10, 16, 2, 32, 2, 4
     ks = jax.random.split(jax.random.PRNGKey(10), 2)
-    k_pages = jax.random.normal(ks[0], (P, page, KV, Dh))
-    v_pages = jax.random.normal(ks[1], (P, page, KV, Dh))
+    k_pages = jax.random.normal(ks[0], (P, KV, page, Dh))
+    v_pages = jax.random.normal(ks[1], (P, KV, page, Dh))
     pt = jnp.asarray([[1, 3, 5, 7], [2, 4, 6, 8]], jnp.int32)
     kg, vg = ops.gather_paged_kv(k_pages, v_pages, pt)
-    assert kg.shape == (B, maxp * page, KV, Dh)
-    np.testing.assert_array_equal(np.asarray(kg[0, :page]), np.asarray(k_pages[1]))
-    np.testing.assert_array_equal(np.asarray(vg[1, page:2 * page]),
+    assert kg.shape == (B, KV, maxp * page, Dh)
+    np.testing.assert_array_equal(np.asarray(kg[0, :, :page]), np.asarray(k_pages[1]))
+    np.testing.assert_array_equal(np.asarray(vg[1, :, page:2 * page]),
                                   np.asarray(v_pages[4]))
 
 

@@ -39,3 +39,26 @@ def test_bootstrap_after_factory_teardown(rt):
 
 def _probe(rt_remote):
     return rt_remote.locality
+
+
+@pytest.mark.parametrize("platforms", ["", "tpu", "tpu,cpu"])
+def test_bootstrap_refuses_workers_that_would_share_a_chip(
+        rt, tmp_path, monkeypatch, platforms):
+    """One process per chip: on a host with a TPU, workers that inherit a
+    platform list reaching it are refused at once, before any spawn."""
+    from repro.net import locality
+
+    (tmp_path / "accel0").touch()
+    monkeypatch.setattr(locality, "_TPU_DEVICE_GLOBS",
+                        (str(tmp_path / "accel*"),))
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        rnet.bootstrap(2, timeout=5.0)
+    assert rnet.current() is None
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    with rnet.running(2) as net:  # CPU-pinned workers may still spawn
+        assert rnet.run_on(1, _probe).get(timeout=60) == 1
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        with pytest.raises(RuntimeError, match="one process per chip"):
+            net.spawn_locality()
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
