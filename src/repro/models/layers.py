@@ -110,13 +110,14 @@ def mlp(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array, p: Dict[str, jax.Arr
         prefix: str) -> jax.Array:
     """Gated (SwiGLU/GeGLU) or plain 2-layer MLP. Weights: w_in/w_gate/w_out."""
     dt = cdtype(cfg)
-    h = x @ p[f"{prefix}w_in"].astype(dt)
-    if cfg.glu:
-        g = x @ p[f"{prefix}w_gate"].astype(dt)
-        h = act_fn(cfg, g) * h
-    else:
-        h = act_fn(cfg, h)
-    return h @ p[f"{prefix}w_out"].astype(dt)
+    with jax.named_scope("mlp"):
+        h = x @ p[f"{prefix}w_in"].astype(dt)
+        if cfg.glu:
+            g = x @ p[f"{prefix}w_gate"].astype(dt)
+            h = act_fn(cfg, g) * h
+        else:
+            h = act_fn(cfg, h)
+        return h @ p[f"{prefix}w_out"].astype(dt)
 
 
 # --------------------------------------------------------------- attention
@@ -172,11 +173,12 @@ def attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     B, S, D = x.shape
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // KV
-    q, k, v = _qkv(cfg, x, p, prefix)
-    if cfg.rope:
-        cos, sin = rope_tables(cfg, positions, Dh)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+    with jax.named_scope("qkv"):
+        q, k, v = _qkv(cfg, x, p, prefix)
+        if cfg.rope:
+            cos, sin = rope_tables(cfg, positions, Dh)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
     if getattr(plan, "bf16_boundaries", False):
         q, k, v = bf16_cotangent(q), bf16_cotangent(k), bf16_cotangent(v)
     q = plan.constrain(q.reshape(B, S, KV, G, Dh), ("batch", "seq", None, None, None))
@@ -346,48 +348,55 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     live slots; idle slots all target the scratch page and their output is
     discarded by the engine.
     Returns (out (B,1,D), new_k_pages, new_v_pages).
+
+    Named scopes, which the profiler's op names carry: ``qkv`` (the
+    projections and RoPE), ``kv_write`` (the two page scatters) and
+    ``paged_attention`` (the page walk, masking, softmax and PV).
     """
     dt = cdtype(cfg)
     B, _, D = x.shape
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // KV
     page = k_pages.shape[2]
-    q = x @ p[f"{prefix}wq"].astype(dt)
-    k = x @ p[f"{prefix}wk"].astype(dt)
-    v = x @ p[f"{prefix}wv"].astype(dt)
-    if cfg.qkv_bias:
-        q = q + p[f"{prefix}bq"].astype(dt)
-        k = k + p[f"{prefix}bk"].astype(dt)
-        v = v + p[f"{prefix}bv"].astype(dt)
-    q = q.reshape(B, KV * G, Dh)
-    k = k.reshape(B, KV, Dh)
-    v = v.reshape(B, KV, Dh)
-    if cfg.rope:
-        q = _rope_single(cfg, q, pos)
-        k = _rope_single(cfg, k, pos)
-    pidx = page_table[jnp.arange(B), pos // page]  # (B,) destination pages
-    off = pos % page
-    k_pages = k_pages.at[pidx, :, off].set(k.astype(k_pages.dtype))
-    v_pages = v_pages.at[pidx, :, off].set(v.astype(v_pages.dtype))
+    with jax.named_scope("qkv"):
+        q = x @ p[f"{prefix}wq"].astype(dt)
+        k = x @ p[f"{prefix}wk"].astype(dt)
+        v = x @ p[f"{prefix}wv"].astype(dt)
+        if cfg.qkv_bias:
+            q = q + p[f"{prefix}bq"].astype(dt)
+            k = k + p[f"{prefix}bk"].astype(dt)
+            v = v + p[f"{prefix}bv"].astype(dt)
+        q = q.reshape(B, KV * G, Dh)
+        k = k.reshape(B, KV, Dh)
+        v = v.reshape(B, KV, Dh)
+        if cfg.rope:
+            q = _rope_single(cfg, q, pos)
+            k = _rope_single(cfg, k, pos)
+    with jax.named_scope("kv_write"):
+        pidx = page_table[jnp.arange(B), pos // page]  # (B,) destination pages
+        off = pos % page
+        k_pages = k_pages.at[pidx, :, off].set(k.astype(k_pages.dtype))
+        v_pages = v_pages.at[pidx, :, off].set(v.astype(v_pages.dtype))
     lengths = pos + 1
 
     from repro.kernels import ops as kops
 
-    if cfg.attn_impl == "pallas":
-        o = kops.paged_decode_attention(q.reshape(B, H, Dh), k_pages, v_pages,
-                                        page_table, lengths)
-        o = o.reshape(B, 1, H * Dh)
-    else:
-        kc, vc = kops.gather_paged_kv(k_pages, v_pages, page_table)
-        T = kc.shape[2]
-        qh = q.reshape(B, KV, G, Dh)
-        s = jnp.einsum("bkgd,bktd->bkgt", qh, kc.astype(dt),
-                       preferred_element_type=jnp.float32) / math.sqrt(Dh)
-        valid = jnp.arange(T)[None, :] < lengths[:, None]
-        s = jnp.where(valid[:, None, None, :], s, -1e30)
-        pr = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bkgt,bktd->bkgd", pr.astype(dt), vc.astype(dt))
-        o = o.reshape(B, 1, H * Dh)
+    with jax.named_scope("paged_attention"):
+        if cfg.attn_impl == "pallas":
+            o = kops.paged_decode_attention(q.reshape(B, H, Dh), k_pages,
+                                            v_pages, page_table, lengths)
+            o = o.reshape(B, 1, H * Dh)
+        else:
+            kc, vc = kops.gather_paged_kv(k_pages, v_pages, page_table)
+            T = kc.shape[2]
+            qh = q.reshape(B, KV, G, Dh)
+            s = jnp.einsum("bkgd,bktd->bkgt", qh, kc.astype(dt),
+                           preferred_element_type=jnp.float32) / math.sqrt(Dh)
+            valid = jnp.arange(T)[None, :] < lengths[:, None]
+            s = jnp.where(valid[:, None, None, :], s, -1e30)
+            pr = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bkgt,bktd->bkgd", pr.astype(dt), vc.astype(dt))
+            o = o.reshape(B, 1, H * Dh)
     return o @ p[f"{prefix}wo"].astype(dt), k_pages, v_pages
 
 
@@ -405,13 +414,14 @@ def unembed(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     """x @ W_out → logits fp32, vocab-sharded. Padded vocab columns are
     masked to -inf so softmax/argmax semantics match the unpadded vocab."""
     w = table.astype(cdtype(cfg))
-    logits = jnp.einsum("bsd,vd->bsv" if transpose else "bsd,dv->bsv", x, w,
-                        preferred_element_type=jnp.float32)
-    Vp = logits.shape[-1]
-    if Vp != cfg.vocab_size:
-        pad_mask = jnp.arange(Vp) >= cfg.vocab_size
-        logits = jnp.where(pad_mask[None, None, :], -1e30, logits)
-    return plan.constrain(logits, ("batch", "seq", "vocab"))
+    with jax.named_scope("unembed"):
+        logits = jnp.einsum("bsd,vd->bsv" if transpose else "bsd,dv->bsv",
+                            x, w, preferred_element_type=jnp.float32)
+        Vp = logits.shape[-1]
+        if Vp != cfg.vocab_size:
+            pad_mask = jnp.arange(Vp) >= cfg.vocab_size
+            logits = jnp.where(pad_mask[None, None, :], -1e30, logits)
+        return plan.constrain(logits, ("batch", "seq", "vocab"))
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array,

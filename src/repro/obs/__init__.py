@@ -5,7 +5,8 @@ Two tiers (see DESIGN.md §10):
 **Recording** —
 
 - :mod:`repro.obs.trace`   — per-thread ring-buffer task/parcel tracer,
-  off by default, near-zero disabled cost;
+  off by default, near-zero disabled cost; its ``serve``/``train`` spans
+  are also JAX profiler annotations, on the device trace's clock;
 - :mod:`repro.obs.export`  — fleet trace collection over the parcelport,
   clock-corrected, merged into one Perfetto-loadable Chrome trace;
 - :mod:`repro.obs.sampler` — counter time-series (histories, rates) and

@@ -13,8 +13,8 @@ into one Chrome trace-event JSON that loads directly in Perfetto
   flow-start (``ph:"s"``), the remote execute span the matching
   flow-finish (``ph:"f"``, ``bp:"e"``) with the same id — Perfetto draws
   the arrow from sender to receiver;
-- serve requests render as *async spans* (``b``/``n``/``e``) spanning
-  admission → prefill → decode steps → finish.
+- serve requests render as *async spans* (``b``/``e``) from submit to
+  finish.
 
 Clock correction: ``time.perf_counter`` has a per-process arbitrary epoch,
 so worker timestamps are meaningless next to the root's.  For each worker
@@ -171,7 +171,7 @@ def _chrome_events(buffers: List[Dict[str, Any]], pid: int,
                 ev["id"] = f"{eid[0]}:{eid[1]}"
                 if ph == "f":
                     ev["bp"] = "e"  # bind to the enclosing slice
-            elif ph in ("b", "n", "e"):
+            elif ph in ("b", "e"):
                 # async events match on (cat, id); scope ids per locality
                 ev["id"] = f"{pid}:{eid}"
             if args:

@@ -11,16 +11,25 @@ the instrumented subsystems append to —
 - parcelport: serialize/send/recv/execute spans with wire byte counts,
   and *flow events* stitching a parcel's send span to its remote
   execution span;
-- serve engine: per-request async spans (admission → prefill → every
-  decode step → finish) so TTFT and inter-token latency fall out of the
-  trace with no extra bookkeeping;
+- serve engine: per-request async spans (submit → finish), and spans
+  for each phase of the decode loop and of each prefill;
 - trainer step loop and segmented-algorithm per-segment actions.
+
+Spans of the ``serve`` and ``train`` categories, which drive the device,
+also go to the JAX profiler: each opens a
+``jax.profiler.TraceAnnotation`` of the same name (a
+``StepTraceAnnotation`` where it carries ``step_num``), with its numeric
+arguments as the event's stats, so a profiler trace shows the host's
+phases on the device's clock.  The annotation is opened whether or not
+the recorder is enabled; it costs about a microsecond while no profiler
+is collecting.
 
 Cost model (the observability contract):
 
 - **Disabled** (the default): every recording entry point checks the
   module-level ``_enabled`` flag first and returns immediately — no
-  allocation, no clock read, no lock.  Instrumentation call sites on hot
+  allocation, no clock read, no lock (``serve``/``train`` spans still
+  open their profiler annotation).  Instrumentation call sites on hot
   paths additionally guard with ``if trace._enabled:`` so the disabled
   cost is one attribute load + branch.
 - **Enabled**: events append to a *per-thread* ring buffer (single
@@ -37,7 +46,8 @@ send span, ``ph:"f"`` at the receiver inside the execute span) that
 Perfetto draws as an arrow across localities.
 
 This module is a leaf: no ``repro`` imports at module scope (the
-scheduler imports it, so it must sit below everything).
+scheduler imports it, so it must sit below everything), and JAX is
+imported on the first profiled span.
 """
 
 from __future__ import annotations
@@ -63,10 +73,14 @@ _seq = itertools.count(1)  # span / flow id allocator (process-wide)
 
 _tls = threading.local()
 
+# Span categories that also annotate the JAX profiler's trace.
+PROFILED = frozenset(("serve", "train"))
+_annotations = None  # (TraceAnnotation, StepTraceAnnotation), on first use
+
 # Event tuples: (ph, name, cat, ts, dur, id, args)
 #   ph  — Chrome trace-event phase: "X" complete span, "i" instant,
-#         "s"/"f" flow start/finish, "b"/"n"/"e" async begin/instant/end
-#   id  — flow id (loc, seq) for s/f, async id (int) for b/n/e, else None
+#         "s"/"f" flow start/finish, "b"/"e" async begin/end
+#   id  — flow id (loc, seq) for s/f, async id (int) for b/e, else None
 #   ts/dur in seconds (perf_counter domain); export converts to µs.
 
 
@@ -209,18 +223,46 @@ class with_context:
 
 
 # ---------------------------------------------------------------- recording
-class _Span:
-    __slots__ = ("name", "cat", "args", "flow_in", "flow_out",
-                 "t0", "sid", "prev")
+def _numbers(args: Dict[str, Any]) -> Dict[str, Any]:
+    """The arguments a profiler event carries as stats: numbers only (the
+    profiler encodes them into the event's name, which a string holding
+    ``#``, ``,`` or ``=``, such as a request tag, would break)."""
+    return {k: v for k, v in args.items() if isinstance(v, (int, float))}
 
-    def __init__(self, name, cat, flow_in, flow_out, args):
+
+def _annotation(name: str, args: Dict[str, Any]):
+    global _annotations
+    if _annotations is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _annotations = (TraceAnnotation, StepTraceAnnotation)
+    cls = _annotations[1] if "step_num" in args else _annotations[0]
+    return cls(name, **_numbers(args))
+
+
+class _Span:
+    """One span: the ring's complete event when ``ring`` (the recorder was
+    enabled at creation), a profiler annotation when ``ann`` is given."""
+
+    __slots__ = ("name", "cat", "args", "flow_in", "flow_out",
+                 "t0", "sid", "prev", "ann", "ring")
+
+    def __init__(self, name, cat, flow_in, flow_out, args, ann=None,
+                 ring=True):
         self.name = name
         self.cat = cat
         self.flow_in = flow_in
         self.flow_out = flow_out
         self.args = args
+        self.ann = ann
+        self.ring = ring
+        self.sid = None
 
     def __enter__(self) -> "_Span":
+        if self.ann is not None:
+            self.ann.__enter__()
+        if not self.ring:
+            return self
         self.prev = getattr(_tls, "ctx", None)
         self.sid = new_id()
         _tls.ctx = self.sid
@@ -236,16 +278,26 @@ class _Span:
         return self
 
     def __exit__(self, *exc) -> bool:
-        t1 = time.perf_counter()
-        _tls.ctx = self.prev
-        if _enabled:  # disabled mid-span: drop silently
-            args = self.args
-            if self.prev is not None:
-                args = dict(args) if args else {}
-                args["parent"] = f"{self.prev[0]}:{self.prev[1]}"
-            _buf().append(("X", self.name, self.cat, self.t0, t1 - self.t0,
-                           self.sid, args))
+        if self.ring:
+            t1 = time.perf_counter()
+            _tls.ctx = self.prev
+            if _enabled:  # disabled mid-span: drop silently
+                args = self.args
+                if self.prev is not None:
+                    args = dict(args) if args else {}
+                    args["parent"] = f"{self.prev[0]}:{self.prev[1]}"
+                _buf().append(("X", self.name, self.cat, self.t0,
+                               t1 - self.t0, self.sid, args))
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
+
+    def set(self, **args: Any) -> None:
+        """Add arguments known only once the span's work is done."""
+        if self.ann is not None:
+            self.ann.set_metadata(**_numbers(args))
+        if self.ring:
+            self.args = {**(self.args or {}), **args}
 
 
 class _NullSpan:
@@ -260,6 +312,9 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **args: Any) -> None:
+        pass
+
 
 _NULL = _NullSpan()
 
@@ -270,7 +325,13 @@ def span(name: str, cat: str = "task",
     """Context manager recording one complete span (Chrome ``"X"``).
 
     ``flow_in``/``flow_out`` additionally record a flow finish/start bound
-    to this span — the cross-locality arrow.  Disabled → shared no-op."""
+    to this span — the cross-locality arrow.  A ``serve``/``train`` span
+    is also a profiler annotation (a step annotation with ``step_num``),
+    recorded in the ring only while enabled; any other span is the shared
+    no-op while disabled.  ``.set(**args)`` on the span adds arguments."""
+    if cat in PROFILED:
+        return _Span(name, cat, flow_in, flow_out, args or None,
+                     _annotation(name, args), _enabled)
     if not _enabled:
         return _NULL
     return _Span(name, cat, flow_in, flow_out, args or None)
@@ -303,13 +364,6 @@ def async_begin(name: str, aid: int, cat: str = "serve", **args: Any) -> None:
     if not _enabled:
         return
     _buf().append(("b", name, cat, time.perf_counter(), 0.0, int(aid),
-                   args or None))
-
-
-def async_instant(name: str, aid: int, cat: str = "serve", **args: Any) -> None:
-    if not _enabled:
-        return
-    _buf().append(("n", name, cat, time.perf_counter(), 0.0, int(aid),
                    args or None))
 
 

@@ -35,6 +35,17 @@ Performance counters: ``/serve{<name>}/requests/{submitted,completed}``,
 ``/serve{<name>}/tokens/generated``, ``/serve{<name>}/step/duration``,
 ``/serve{<name>}/request/{latency,first_token}``, plus the page-pool
 gauges from :mod:`repro.serve.kv_cache`.
+
+Spans (:mod:`repro.obs.trace`, category ``serve``: JAX profiler
+annotations always, ring events while the recorder is on).  On the
+decode loop: ``admit`` (prefills launched and integrated; ``admitted``,
+and ``ready_ms``, the longest KV-ready → slot-bound wait among them),
+``decode_step`` (``step_num``, ``batch``) with its
+children ``decode_step.dispatch`` (inputs uploaded, step enqueued) and
+``decode_step.wait`` (the blocking token readback), then ``emit``
+(tokens streamed, requests finished; ``finished``).  On the prefill
+pool: ``prefill`` (``rid``, ``prompt_len``, ``bucket``, ``queued_ms``)
+with its child ``prefill.wait`` (logits readback, first-token sampling).
 """
 
 from __future__ import annotations
@@ -111,6 +122,7 @@ class _Request:
     stream: Optional[Channel]
     generated: List[int] = field(default_factory=list)
     submit_t: float = 0.0
+    ready_t: float = 0.0  # prefill done: KV and first token ready
     first_token_t: float = 0.0
     # opaque picklable routing info (fleet relay: client locality, stream
     # id) that survives live migration — the destination re-attaches its
@@ -346,7 +358,8 @@ class Engine:
             logits, new_cache = self.model.decode_paged(params, cache, token)
         else:
             logits, new_cache = self.model.decode(params, cache, token)
-        nxt = sample_logits(logits, key, temp, topk, topp)[:, None]
+        with jax.named_scope("sample"):
+            nxt = sample_logits(logits, key, temp, topk, topp)[:, None]
         return nxt, new_cache
 
     def decode_compile_count(self) -> int:
@@ -565,13 +578,16 @@ class Engine:
 
     def _run_prefill(self, req: _Request):
         """Compute the request's KV cache + first token (any thread)."""
-        if _trace._enabled:
-            with _trace.span("prefill", "serve", rid=req.rid, req=req.tag,
-                             prompt_len=len(req.prompt)):
-                return self._run_prefill_body(req)
-        return self._run_prefill_body(req)
+        n = len(req.prompt)
+        bucket = self._bucket_for(n) if self._bucketed else n
+        with _trace.span("prefill", "serve", rid=req.rid, req=req.tag,
+                         prompt_len=n, bucket=bucket,
+                         queued_ms=(time.perf_counter() - req.submit_t) * 1e3):
+            payload = self._run_prefill_body(req, bucket)
+        req.ready_t = time.perf_counter()
+        return payload
 
-    def _run_prefill_body(self, req: _Request):
+    def _run_prefill_body(self, req: _Request, bucket: int):
         prompt = req.prompt
         if self.model.cfg.family == "vlm" and len(prompt) < self.model.cfg.n_patches:
             # patches occupy the first n_patches positions; a shorter prompt
@@ -580,7 +596,6 @@ class Engine:
                              f"tokens, got {len(prompt)}")
         pextra = {k: v for k, v in self.extra.items() if k != "enc_len"}
         if self._bucketed:
-            bucket = self._bucket_for(len(prompt))
             assert len(prompt) <= bucket, (len(prompt), self.scfg.cache_len)
             toks = np.zeros((1, bucket), np.int32)
             toks[0, : len(prompt)] = prompt
@@ -594,7 +609,9 @@ class Engine:
             logits, cache1 = self._prefill(self.params, pin,
                                            cache_len=self.scfg.cache_len)
         rng = np.random.default_rng((self.scfg.seed << 20) ^ req.rid)
-        tok0 = _sample_host(np.asarray(logits[0], np.float32), req.sampling, rng)
+        with _trace.span("prefill.wait", "serve"):
+            tok0 = _sample_host(np.asarray(logits[0], np.float32),
+                                req.sampling, rng)
         return req, cache1, len(prompt), tok0
 
     def _prefill_task(self, req: _Request) -> None:
@@ -644,9 +661,6 @@ class Engine:
     def _emit(self, req: _Request, tok: int) -> None:
         req.generated.append(tok)
         self.c_tok.increment()
-        if _trace._enabled:  # inter-token latency = gaps between these
-            _trace.async_instant("token", req.rid, "serve",
-                                 n=len(req.generated))
         if not req.first_token_t:
             req.first_token_t = time.perf_counter()
             self.t_first.add(req.first_token_t - req.submit_t)
@@ -671,9 +685,11 @@ class Engine:
         return (len(req.generated) >= req.max_new + 1
                 or tok == self.scfg.eos_id)
 
-    def _bind_slot(self, i: int, req: _Request, tok0: int) -> None:
+    def _bind_slot(self, i: int, req: _Request, tok0: int) -> float:
         """Occupy slot ``i`` with an admitted request and emit its prefill
-        token (shared by the pipelined and inline admission paths)."""
+        token (shared by the pipelined and inline admission paths).
+        Returns how long the request's KV waited for the slot (s)."""
+        waited = time.perf_counter() - req.ready_t
         self.slots[i] = req
         self._tokens[i, 0] = tok0
         self._temp[i] = req.sampling.temperature
@@ -682,15 +698,19 @@ class Engine:
         self._emit(req, tok0)
         if self._done_after(req, tok0):
             self._finish(i)
+        return waited
 
-    def _integrate_ready(self) -> None:
+    def _integrate_ready(self) -> List[float]:
+        """Bind finished prefills to free slots; returns the KV-ready →
+        slot-bound wait (s) of each request admitted."""
+        waits: List[float] = []
         while True:
             free = next((i for i, s in enumerate(self.slots) if s is None), None)
             if free is None:
-                return
+                return waits
             with self._lock:
                 if not self._ready:
-                    return
+                    return waits
                 payload = self._ready.pop(0)
             req, cache1, length, tok0 = payload
             if not self.backend.admit(free, cache1, length):
@@ -711,20 +731,20 @@ class Engine:
                                    rid=req.rid)
                 with self._lock:  # pool exhausted — retry after completions
                     self._ready.insert(0, payload)
-                return
-            self._bind_slot(free, req, tok0)
+                return waits
+            waits.append(self._bind_slot(free, req, tok0))
 
-    def _admit_inline(self) -> None:
+    def _admit_inline(self) -> List[float]:
         """Seed-style admission: prefill runs inside the decode loop (the
         barrier).  Kept as the A/B baseline (pipeline_admission=False)."""
-        self._integrate_ready()  # admit-failure retries parked in _ready
+        waits = self._integrate_ready()  # admit-failure retries parked in _ready
         for i, slot in enumerate(self.slots):
             if slot is not None:
                 continue
             try:
                 req = self._queue.get_nowait()
             except queue.Empty:
-                return
+                return waits
             try:
                 req2, cache1, length, tok0 = self._run_prefill(req)
             except BaseException as e:  # noqa: BLE001 — fail the one request
@@ -736,8 +756,9 @@ class Engine:
             if not self.backend.admit(i, cache1, length):
                 with self._lock:
                     self._ready.insert(0, (req2, cache1, length, tok0))
-                return
-            self._bind_slot(i, req2, tok0)
+                return waits
+            waits.append(self._bind_slot(i, req2, tok0))
+        return waits
 
     # ----------------------------------------------------------------- loop
     def _idle_or_stop(self) -> bool:
@@ -770,11 +791,16 @@ class Engine:
             with self._lock:
                 self._running = False
             return
-        if self.scfg.pipeline_admission:
-            self._pump_prefills()
-            self._integrate_ready()
-        else:
-            self._admit_inline()
+        with _trace.span("admit", "serve") as span:
+            if self.scfg.pipeline_admission:
+                self._pump_prefills()
+                waits = self._integrate_ready()
+            else:
+                waits = self._admit_inline()
+            if waits:
+                span.set(admitted=len(waits), ready_ms=max(waits) * 1e3)
+            else:
+                span.set(admitted=0)
 
         active = [i for i, s in enumerate(self.slots) if s is not None]
         for i in list(active):
@@ -788,28 +814,35 @@ class Engine:
             self._loop_exec.post(self._step)
             return
 
-        step_args: Dict[str, Any] = {"batch": len(active)}
+        step_args: Dict[str, Any] = {"step_num": self._step_count,
+                                     "batch": len(active)}
         if _trace._enabled:
             # which requests this step advanced — the analyzer charges the
             # step's duration to every request decoding in it
             step_args["reqs"] = [self.slots[i].tag for i in active]
         with _trace.span("decode_step", "serve", **step_args), \
                 self.t_step.time():
-            key = jax.random.fold_in(self._key, self._step_count)
-            nxt, new_cache = self._decode(
-                self.params, self.backend.device_cache(),
-                jnp.asarray(self._tokens), key,
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp))
+            with _trace.span("decode_step.dispatch", "serve"):
+                key = jax.random.fold_in(self._key, self._step_count)
+                nxt, new_cache = self._decode(
+                    self.params, self.backend.device_cache(),
+                    jnp.asarray(self._tokens), key,
+                    jnp.asarray(self._temp), jnp.asarray(self._topk),
+                    jnp.asarray(self._topp))
             self.backend.commit(new_cache)
-            toks = np.asarray(nxt[:, 0])
+            with _trace.span("decode_step.wait", "serve"):
+                toks = np.asarray(nxt[:, 0])
         self._step_count += 1
-        self.backend.step_bookkeeping(active)
-        self._tokens[:, 0] = toks
-        for i in active:
-            req = self.slots[i]
-            tok = int(toks[i])
-            self._emit(req, tok)
-            if self._done_after(req, tok):
-                self._finish(i)
+        with _trace.span("emit", "serve") as span:
+            self.backend.step_bookkeeping(active)
+            self._tokens[:, 0] = toks
+            finished = 0
+            for i in active:
+                req = self.slots[i]
+                tok = int(toks[i])
+                self._emit(req, tok)
+                if self._done_after(req, tok):
+                    self._finish(i)
+                    finished += 1
+            span.set(finished=finished)
         self._loop_exec.post(self._step)  # continuation chain

@@ -32,6 +32,13 @@ def test_disabled_span_is_shared_noop():
     assert trace.span("a", "t") is trace.span("b", "t")
 
 
+def test_disabled_profiled_span_records_nothing():
+    with trace.span("decode_step", "serve", step_num=3, reqs=["a"]) as s:
+        s.set(finished=1)
+    assert s is not trace.span("x", "t")  # a profiler annotation, not the no-op
+    assert trace.events() == []
+
+
 def test_span_open_across_disable_drops_cleanly():
     trace.enable()
     s = trace.span("x", "t")
@@ -50,6 +57,15 @@ def test_span_records_complete_event_with_args():
     ph, name, cat, ts, dur, eid, args = evs[0]
     assert (ph, name, cat) == ("X", "work", "sched")
     assert dur >= 0.0 and args == {"pool": "default"}
+
+
+def test_profiled_span_adds_set_args_in_ring():
+    trace.enable()
+    with trace.span("emit", "serve", batch=2, reqs=["r0:1"]) as s:
+        s.set(finished=1)
+    (ev,) = trace.events()
+    assert ev[1] == "emit"
+    assert ev[6] == {"batch": 2, "reqs": ["r0:1"], "finished": 1}
 
 
 def test_nested_span_records_parent_context():
