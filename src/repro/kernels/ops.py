@@ -113,8 +113,9 @@ def gather_paged_kv(k_pages: jax.Array, v_pages: jax.Array,
     """Materialize per-request dense caches from the block pool.
 
     k_pages/v_pages: (P, KV, page, Dh), page_table: (B, maxp)
-    → (B, KV, maxp·page, Dh).  The XLA (non-Pallas) decode path and the
-    test oracles use this; the Pallas path never materializes it.
+    → (B, KV, maxp·page, Dh).  The test oracles use this; the XLA decode
+    path reads a stacked pool in place (:func:`gather_layer_pages`) and the
+    Pallas path never materializes it.
     """
     P, KV, page, Dh = k_pages.shape
     B, maxp = page_table.shape
@@ -125,6 +126,29 @@ def gather_paged_kv(k_pages: jax.Array, v_pages: jax.Array,
         return g.reshape(B, KV, maxp * page, Dh)
 
     return dense(k_pages), dense(v_pages)
+
+
+def gather_layer_pages(pool: jax.Array, layer: jax.Array,
+                       page_table: jax.Array) -> jax.Array:
+    """One layer's pages of every request, read in place from a stacked pool.
+
+    pool: (L, P, KV, page, Dh), layer: () int32, page_table: (B, maxp)
+    → (B, KV, maxp·page, Dh), as :func:`gather_paged_kv` lays it out.  One
+    gather indexed by (layer, page): ``pool[layer]`` is never materialized.
+    Out-of-range pages read NaN, as ``jnp.take``'s default fill mode does.
+    """
+    L, P, KV, page, Dh = pool.shape
+    B, maxp = page_table.shape
+    idx = jnp.stack([jnp.broadcast_to(layer, page_table.shape).astype(
+        page_table.dtype), page_table], axis=-1)
+    g = jax.lax.gather(
+        pool, idx,
+        jax.lax.GatherDimensionNumbers(offset_dims=(2, 3, 4),
+                                       collapsed_slice_dims=(0, 1),
+                                       start_index_map=(0, 1)),
+        slice_sizes=(1, 1, KV, page, Dh),
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+    return g.transpose(0, 2, 1, 3, 4).reshape(B, KV, maxp * page, Dh)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -329,35 +329,43 @@ def decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
 # ------------------------------------------------------- paged decode attn
 def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
                            p: Dict[str, jax.Array], prefix: str,
-                           k_pages: jax.Array, v_pages: jax.Array,
-                           page_table: jax.Array, pos: jax.Array
+                           k_pool: jax.Array, v_pool: jax.Array,
+                           layer: jax.Array, page_table: jax.Array,
+                           pos: jax.Array
                            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token attention against a *paged* KV cache.
+    """One-token attention against a *paged* KV cache, read in place.
 
-    x: (B, 1, D); k_pages/v_pages: (P, KV, page, Dh) block pool shared by
-    all requests (head-major pages: the kernel's tiling wants (page, Dh)
-    last); page_table: (B, maxp) int32 (per-request page lists, 0-
-    padded past the fill — page 0 is the pool's reserved scratch page);
-    pos: (B,) current fill per slot.  The new token's K/V are scattered
-    into page ``page_table[b, pos//page]`` at offset ``pos % page``;
-    attention then walks the row's page list with per-row lengths — either
-    in the paged Pallas kernel (``attn_impl == "pallas"``) or via a dense
-    gather + masked softmax (XLA reference path).
+    x: (B, 1, D); k_pool/v_pool: (L, P, KV, page, Dh) stacked block pools
+    shared by all requests (head-major pages: the kernel's tiling wants
+    (page, Dh) last); layer: () int32, this layer's index into them;
+    page_table: (B, maxp) int32 (per-request page lists, 0-padded past the
+    fill — page 0 is the pool's reserved scratch page); pos: (B,) current
+    fill per slot.  The pools are not written: returns (out (B,1,D), k, v)
+    with the new token's k, v ``(B, KV, Dh)`` in the pools' dtype, which
+    the caller writes for every layer at once after its layer scan
+    (:func:`write_paged_kv`).  Attention sees the new token at ``pos``
+    all the same:
 
-    Pages are per-request, so the scatter destinations are unique across
-    live slots; idle slots all target the scratch page and their output is
+    - ``xla``: one gather per pool indexed by (layer, page) fetches each
+      row's page list, the new K/V are set into that gathered copy at
+      ``pos``, then a masked softmax.
+    - ``pallas``: the kernel reads the new token from the pool, so it is
+      written into this layer's copy of the pool (page
+      ``page_table[b, pos//page]``, offset ``pos % page``) first.
+
+    Pages are per-request, so the destinations are unique across live
+    slots; idle slots all target the scratch page and their output is
     discarded by the engine.
-    Returns (out (B,1,D), new_k_pages, new_v_pages).
 
     Named scopes, which the profiler's op names carry: ``qkv`` (the
-    projections and RoPE), ``kv_write`` (the two page scatters) and
-    ``paged_attention`` (the page walk, masking, softmax and PV).
+    projections and RoPE) and ``paged_attention`` (the page walk, masking,
+    softmax and PV).
     """
     dt = cdtype(cfg)
     B, _, D = x.shape
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     G = H // KV
-    page = k_pages.shape[2]
+    page = k_pool.shape[-2]
     with jax.named_scope("qkv"):
         q = x @ p[f"{prefix}wq"].astype(dt)
         k = x @ p[f"{prefix}wk"].astype(dt)
@@ -366,28 +374,30 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
             q = q + p[f"{prefix}bq"].astype(dt)
             k = k + p[f"{prefix}bk"].astype(dt)
             v = v + p[f"{prefix}bv"].astype(dt)
-        q = q.reshape(B, KV * G, Dh)
+        q = q.reshape(B, H, Dh)
         k = k.reshape(B, KV, Dh)
         v = v.reshape(B, KV, Dh)
         if cfg.rope:
             q = _rope_single(cfg, q, pos)
             k = _rope_single(cfg, k, pos)
-    with jax.named_scope("kv_write"):
-        pidx = page_table[jnp.arange(B), pos // page]  # (B,) destination pages
-        off = pos % page
-        k_pages = k_pages.at[pidx, :, off].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[pidx, :, off].set(v.astype(v_pages.dtype))
+    k = k.astype(k_pool.dtype)
+    v = v.astype(v_pool.dtype)
     lengths = pos + 1
+    rows = jnp.arange(B)
 
     from repro.kernels import ops as kops
 
     with jax.named_scope("paged_attention"):
         if cfg.attn_impl == "pallas":
-            o = kops.paged_decode_attention(q.reshape(B, H, Dh), k_pages,
-                                            v_pages, page_table, lengths)
-            o = o.reshape(B, 1, H * Dh)
+            pidx = page_table[rows, pos // page]  # (B,) destination pages
+            kp = k_pool[layer].at[pidx, :, pos % page].set(k)
+            vp = v_pool[layer].at[pidx, :, pos % page].set(v)
+            o = kops.paged_decode_attention(q, kp, vp, page_table, lengths)
         else:
-            kc, vc = kops.gather_paged_kv(k_pages, v_pages, page_table)
+            kc = kops.gather_layer_pages(k_pool, layer, page_table)
+            vc = kops.gather_layer_pages(v_pool, layer, page_table)
+            kc = kc.at[rows, :, pos].set(k)
+            vc = vc.at[rows, :, pos].set(v)
             T = kc.shape[2]
             qh = q.reshape(B, KV, G, Dh)
             s = jnp.einsum("bkgd,bktd->bkgt", qh, kc.astype(dt),
@@ -396,8 +406,32 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
             s = jnp.where(valid[:, None, None, :], s, -1e30)
             pr = jax.nn.softmax(s, axis=-1)
             o = jnp.einsum("bkgt,bktd->bkgd", pr.astype(dt), vc.astype(dt))
-            o = o.reshape(B, 1, H * Dh)
-    return o @ p[f"{prefix}wo"].astype(dt), k_pages, v_pages
+        o = o.reshape(B, 1, H * Dh)
+    return o @ p[f"{prefix}wo"].astype(dt), k, v
+
+
+def write_paged_kv(k_pool: jax.Array, v_pool: jax.Array, k: jax.Array,
+                   v: jax.Array, page_table: jax.Array, pos: jax.Array
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """Write one decode step's new K/V for every layer into the stacked
+    pools, under the named scope ``kv_write``.
+
+    k_pool/v_pool: (L, P, KV, page, Dh); k/v: (L, B, KV, Dh), as
+    :func:`paged_decode_attention` returns them per layer.
+    Row b's token goes to page ``page_table[b, pos[b] // page]`` at offset
+    ``pos[b] % page``; idle slots all hit scratch page 0.  Each (layer, row,
+    KV head) writes one ``(Dh,)`` row, which keeps the pool's (page, Dh)
+    tiling: a ``(KV, Dh)`` update window cuts across it, and XLA then lays
+    the whole pool out again and back.  In place when the pools are donated.
+    """
+    L, _, KV, page, _ = k_pool.shape
+    B = pos.shape[0]
+    with jax.named_scope("kv_write"):
+        pidx = page_table[jnp.arange(B), pos // page]
+        idx = (jnp.arange(L)[:, None, None], pidx[None, :, None],
+               jnp.arange(KV)[None, None, :], (pos % page)[None, :, None])
+        return (k_pool.at[idx].set(k.astype(k_pool.dtype)),
+                v_pool.at[idx].set(v.astype(v_pool.dtype)))
 
 
 # --------------------------------------------------------------- embedding

@@ -242,7 +242,9 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int,
     request's tokens in all layers, vLLM-style; each page is head-major,
     ``(KV, page, Dh)``, as the paged decode kernel tiles it), plus per-slot
     page tables and fill positions.  Memory scales with live tokens, not
-    ``max_batch × cache_len``."""
+    ``max_batch × cache_len``.  The decode step reads the stacked pools in
+    place during its layer scan and writes the step's new K/V once after it
+    (:func:`decode_step_paged`)."""
     KV, Dh = cfg.num_kv_heads, cfg.head_dim
     fd, Lm = cfg.first_dense, cfg.num_layers - cfg.first_dense
     dt = jnp.dtype(cfg.dtype)
@@ -258,18 +260,40 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int,
     return specs
 
 
-def _paged_decode_layer(cfg: ModelConfig, plan: ShardingPlan, x, lp, kp, vp,
-                        page_table, pos, moe_layer: bool):
-    h = Lx.norm(cfg, x, lp["ln1"])
-    h, kp, vp = Lx.paged_decode_attention(cfg, plan, h, lp, "", kp, vp,
-                                          page_table, pos)
-    x = x + h
-    h = Lx.norm(cfg, x, lp["ln2"])
-    if moe_layer:
-        ffn, _ = moe_ffn(cfg, plan, h, lp, "moe/")
-    else:
-        ffn = Lx.mlp(cfg, plan, h, lp, "")
-    return x + ffn, kp, vp
+def _paged_decode_stack(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
+                        stacked: Dict[str, jax.Array], axes: Dict[str, Tuple],
+                        k_pool: jax.Array, v_pool: jax.Array,
+                        page_table: jax.Array, pos: jax.Array,
+                        moe_layer: bool):
+    """lax.scan over one stacked layer group against its pools
+    (L, P, KV, page, Dh); returns (x, new k_pool, new v_pool).
+
+    The pools are read-only inside the scan, which carries ``x`` and scans
+    the layer params and the layer index; its ``ys`` are each layer's new
+    K/V (L, B, KV, Dh), written into the pools once after it.  Scanning the
+    pools as ``xs``/``ys`` instead makes XLA copy each layer's slice out and
+    back and copy both whole pools, every step.
+    """
+
+    def layer(x, xs):
+        lp, idx = xs
+        if not plan.gather_upfront:
+            lp = gather_constrain(plan, lp, axes)
+        h = Lx.norm(cfg, x, lp["ln1"])
+        h, k, v = Lx.paged_decode_attention(cfg, plan, h, lp, "", k_pool,
+                                            v_pool, idx, page_table, pos)
+        x = x + h
+        h = Lx.norm(cfg, x, lp["ln2"])
+        if moe_layer:
+            ffn, _ = moe_ffn(cfg, plan, h, lp, "moe/")
+        else:
+            ffn = Lx.mlp(cfg, plan, h, lp, "")
+        return x + ffn, (k, v)
+
+    x, (k, v) = jax.lax.scan(
+        layer, x, (stacked, jnp.arange(k_pool.shape[0], dtype=jnp.int32)))
+    k_pool, v_pool = Lx.write_paged_kv(k_pool, v_pool, k, v, page_table, pos)
+    return x, k_pool, v_pool
 
 
 def decode_step_paged(cfg: ModelConfig, plan: ShardingPlan,
@@ -277,7 +301,9 @@ def decode_step_paged(cfg: ModelConfig, plan: ShardingPlan,
                       cache: Dict[str, jax.Array], token: jax.Array
                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode step against the paged cache (see paged_cache_specs).
-    token: (B, 1) int32 → (logits (B,V) fp32, new cache)."""
+    token: (B, 1) int32 → (logits (B,V) fp32, new cache).  The pools are
+    read in place by every layer and the step's new K/V written once after
+    each layer scan (:func:`_paged_decode_stack`)."""
     specs = decoder_param_specs(cfg)
     pos = cache["pos"]
     pt = cache["page_table"]
@@ -285,34 +311,17 @@ def decode_step_paged(cfg: ModelConfig, plan: ShardingPlan,
     new_cache = dict(cache)
 
     if cfg.first_dense > 0:
-        d0 = _slice_params(params, "d0/")
-        a0 = _layer_axes(specs, "d0/")
-
-        def body0(x, xs):
-            lp, kp, vp = xs
-            if not plan.gather_upfront:
-                lp = gather_constrain(plan, lp, a0)
-            x, kp, vp = _paged_decode_layer(cfg, plan, x, lp, kp, vp, pt, pos, False)
-            return x, (kp, vp)
-
-        x, (nk0, nv0) = jax.lax.scan(body0, x, (d0, cache["k0"], cache["v0"]))
-        new_cache["k0"], new_cache["v0"] = nk0, nv0
+        x, new_cache["k0"], new_cache["v0"] = _paged_decode_stack(
+            cfg, plan, x, _slice_params(params, "d0/"),
+            _layer_axes(specs, "d0/"), cache["k0"], cache["v0"], pt, pos,
+            False)
 
     blk = _slice_params(params, "blk/")
     ax = _layer_axes(specs, "blk/")
     if plan.gather_upfront:
         blk = stacked_gather_constrain(plan, blk, ax)
-
-    def body(x, xs):
-        lp, kp, vp = xs
-        if not plan.gather_upfront:
-            lp = gather_constrain(plan, lp, ax)
-        x, kp, vp = _paged_decode_layer(cfg, plan, x, lp, kp, vp, pt, pos,
-                                        cfg.is_moe)
-        return x, (kp, vp)
-
-    x, (nk, nv) = jax.lax.scan(body, x, (blk, cache["k"], cache["v"]))
-    new_cache["k"], new_cache["v"] = nk, nv
+    x, new_cache["k"], new_cache["v"] = _paged_decode_stack(
+        cfg, plan, x, blk, ax, cache["k"], cache["v"], pt, pos, cfg.is_moe)
     new_cache["pos"] = pos + 1
 
     x = Lx.norm(cfg, x, params["final_ln"])

@@ -184,3 +184,125 @@ def test_decode_step_compiles_once(rt, served):
     for f in futs:
         f.get(timeout=300)
     assert eng.decode_compile_count() == 1
+
+
+def _oracle_attention(cfg, plan, h, lp, kp, vp, pt, pos):
+    """Write the token into one layer's pool, then attend over the pages:
+    ``ops.gather_paged_kv`` and a masked softmax (``xla``), or the paged
+    kernel on the written pool (``pallas``: its online softmax rounds
+    differently from a dense one)."""
+    from repro.kernels import ops
+    from repro.models import layers as Lx
+
+    dt = jnp.dtype(cfg.dtype)
+    B = h.shape[0]
+    H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    q, k, v = (h @ lp[w].astype(dt) for w in ("wq", "wk", "wv"))
+    if cfg.qkv_bias:
+        q, k, v = (a + lp[b].astype(dt) for a, b in
+                   zip((q, k, v), ("bq", "bk", "bv")))
+    q, k, v = q.reshape(B, H, Dh), k.reshape(B, KV, Dh), v.reshape(B, KV, Dh)
+    if cfg.rope:
+        q, k = Lx._rope_single(cfg, q, pos), Lx._rope_single(cfg, k, pos)
+    page = kp.shape[2]
+    pidx = pt[jnp.arange(B), pos // page]
+    kp = kp.at[pidx, :, pos % page].set(k.astype(kp.dtype))
+    vp = vp.at[pidx, :, pos % page].set(v.astype(vp.dtype))
+    if cfg.attn_impl == "pallas":
+        o = ops.paged_decode_attention(q, kp, vp, pt, pos + 1)
+    else:
+        kc, vc = ops.gather_paged_kv(kp, vp, pt)
+        T = kc.shape[2]
+        s = jnp.einsum("bkgd,bktd->bkgt", q.reshape(B, KV, H // KV, Dh),
+                       kc.astype(dt), preferred_element_type=jnp.float32)
+        s = s / np.sqrt(Dh)
+        valid = jnp.arange(T)[None, :] < (pos + 1)[:, None]
+        pr = jax.nn.softmax(jnp.where(valid[:, None, None, :], s, -1e30), -1)
+        o = jnp.einsum("bkgt,bktd->bkgd", pr.astype(dt), vc.astype(dt))
+    return o.reshape(B, 1, H * Dh) @ lp["wo"].astype(dt), kp, vp
+
+
+def _oracle_decode(cfg, plan, params, cache, token):
+    """One paged decode step: a scan over each layer group with its pools
+    as ``xs``/``ys``, every layer writing its token before it attends."""
+    from repro.models import layers as Lx
+    from repro.models.moe import moe_ffn
+
+    pt, pos = cache["page_table"], cache["pos"]
+    out = dict(cache, pos=pos + 1)
+    x = Lx.embed(cfg, plan, params["tok_embed"], token)
+    groups = [("d0/", "k0", "v0", False)] if cfg.first_dense else []
+    groups.append(("blk/", "k", "v", cfg.is_moe))
+    for prefix, kk, vk, moe_layer in groups:
+        stacked = {n[len(prefix):]: a for n, a in params.items()
+                   if n.startswith(prefix)}
+
+        def layer(x, xs):
+            lp, kp, vp = xs
+            h = Lx.norm(cfg, x, lp["ln1"])
+            h, kp, vp = _oracle_attention(cfg, plan, h, lp, kp, vp, pt, pos)
+            x = x + h
+            h = Lx.norm(cfg, x, lp["ln2"])
+            ffn = (moe_ffn(cfg, plan, h, lp, "moe/")[0] if moe_layer
+                   else Lx.mlp(cfg, plan, h, lp, ""))
+            return x + ffn, (kp, vp)
+
+        x, (out[kk], out[vk]) = jax.lax.scan(layer, x,
+                                             (stacked, cache[kk], cache[vk]))
+    x = Lx.norm(cfg, x, params["final_ln"])
+    table = params["tok_embed"] if cfg.tie_embeddings else params["lm_head"]
+    logits = Lx.unembed(cfg, plan, x, table, transpose=cfg.tie_embeddings)
+    return logits[:, 0, :], out
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("arch", ["qwen25_3b", "deepseek_moe_16b"])
+def test_decode_paged_matches_write_then_read_oracle(arch, impl):
+    """The decode step reads the pools in place and writes the step's K/V
+    once after the layer scan (``xla``), or writes each layer first
+    (``pallas``); over 3 steps its logits for live rows and its pools equal,
+    to the bit, an oracle that writes each layer's token before attending.
+    Live rows start at the first and the last offset of a page; idle slots
+    sit at 0 with every page on scratch page 0, as the engine leaves them.
+    Their logits and page 0 are left out: under the oracle's order an idle
+    row reads the other idle rows' tokens of the same layer from page 0."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config(arch, smoke=True), attn_impl=impl)
+    plan = get_plan("serve")
+    model = build_model(cfg, plan)
+    params = model.init(jax.random.PRNGKey(3))
+    page, maxp, B = 8, 4, 4
+    P = 2 * maxp + 2
+    specs = model.paged_cache_specs(P, page, B, maxp)
+    cache = {n: jax.random.normal(jax.random.PRNGKey(i), s.shape, s.dtype)
+             for i, (n, s) in enumerate(specs.items()) if n in
+             ("k", "v", "k0", "v0")}
+    assert ("k0" in cache) == (cfg.first_dense > 0)
+    rng = np.random.default_rng(0)
+    pages = 1 + rng.permutation(P - 1)[:2 * maxp].reshape(2, maxp)
+    pt = np.zeros((B, maxp), np.int32)
+    pt[:2] = pages  # rows 2, 3 idle: all of their table is page 0
+    pos0 = np.asarray([2 * page, 2 * page - 1, 0, 0], np.int32)
+    live = np.asarray([True, True, False, False])
+    cache["page_table"] = jnp.asarray(pt)
+    cache["pos"] = jnp.asarray(pos0)
+    tok = jnp.asarray([[5], [77], [3], [9]], jnp.int32)
+
+    step = jax.jit(model.decode_paged)
+    oracle = jax.jit(lambda p, c, t: _oracle_decode(cfg, plan, p, c, t))
+    got, want = cache, dict(cache)
+    for _ in range(3):
+        lg, got = step(params, got, tok)
+        lw, want = oracle(params, want, tok)
+        np.testing.assert_array_equal(np.asarray(lg)[live],
+                                      np.asarray(lw)[live])
+        for n in cache:
+            g, w = (np.asarray(c[n], np.float32) for c in (got, want))
+            if g.ndim == 5:  # a pool: every page but the scratch page
+                g, w = g[:, 1:], w[:, 1:]
+            np.testing.assert_array_equal(g, w, err_msg=n)
+        tok = jnp.argmax(lg, -1).astype(jnp.int32)[:, None]
+        # the engine re-uploads the idle slots' fill, 0, every step
+        got = dict(got, pos=jnp.where(live, got["pos"], 0))
+        want = dict(want, pos=jnp.where(live, want["pos"], 0))
