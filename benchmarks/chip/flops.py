@@ -1,70 +1,53 @@
 """Operations and bytes the model needs, from its sizes and live lengths.
 
 These are what the algorithm requires, not what an implementation happens
-to read: a decode step needs the weights once, the live K/V of each active
-row and the new K/V it writes; a prefill needs its valid (unpadded) prompt
-tokens.  A multiply-add counts as two operations.
+to read: a decode step needs the weights it computes with, the live K/V of
+each active row and the new K/V it writes; a prefill needs its valid
+(unpadded) prompt tokens.  A multiply-add counts as two operations.  The
+model's own arithmetic is its family module's (``families/``); the
+parameters as served are counted from its layout.
 """
 
 from __future__ import annotations
 
 from typing import Dict, Iterable, Sequence
 
+import manifest
+import weights
 from model_spec import ModelSpec
 
 BF16 = 2  # bytes per served weight and per K/V element
 
 
-def layer_params(m: ModelSpec) -> int:
-    D, F = m.hidden, m.ffn
-    q, kv = m.heads * m.head_dim, m.kv_heads * m.head_dim
-    n = D * q + 2 * D * kv + q * D + (3 if m.gated else 2) * D * F
-    n += 2 * D  # two norm scales
-    if m.qkv_bias:
-        n += q + 2 * kv
-    return n
-
-
-def layer_matmul_params(m: ModelSpec) -> int:
-    D, F = m.hidden, m.ffn
-    q, kv = m.heads * m.head_dim, m.kv_heads * m.head_dim
-    return D * q + 2 * D * kv + q * D + (3 if m.gated else 2) * D * F
+def _family(m: ModelSpec):
+    return manifest.family(m.model_type)
 
 
 def param_count(m: ModelSpec) -> int:
-    """Parameters as served: tied embedding (padded rows), final norm, layers."""
-    head = 0 if m.tied else m.hidden * m.padded_vocab
-    return m.padded_vocab * m.hidden + head + m.hidden + m.layers * layer_params(m)
+    """Parameters as served: every leaf of the family's layout, padded
+    vocabulary rows included."""
+    return weights.param_count(_family(m).layout(m))
 
 
 def kv_bytes_per_token(m: ModelSpec) -> int:
-    return m.layers * 2 * m.kv_heads * m.head_dim * BF16
+    return _family(m).kv_bytes_per_token(m)
 
 
 def _attn_flops(m: ModelSpec, q_len: int, k_len: float) -> float:
     """QK^T and PV for ``q_len`` queries over ``k_len`` keys, all layers."""
-    return 4.0 * m.layers * m.heads * m.head_dim * q_len * k_len
+    return _family(m).attn_flops(m, q_len, k_len)
 
 
 def decode_step(m: ModelSpec, contexts: Sequence[int]) -> Dict[str, float]:
     """One decode step over active rows whose K/V hold ``contexts`` tokens
-    before the step (the new token attends to ``context + 1`` keys)."""
-    rows = len(contexts)
-    flops = rows * 2.0 * (m.layers * layer_matmul_params(m)
-                          + m.hidden * m.padded_vocab)
-    flops += sum(_attn_flops(m, 1, c + 1) for c in contexts)
-    weights = param_count(m) * BF16
-    kv = sum(contexts) * kv_bytes_per_token(m) + rows * kv_bytes_per_token(m)
-    return {"flops": flops, "bytes": float(weights + kv)}
+    before the step (the new token attends to ``context + 1`` keys):
+    ``{"flops": ..., "bytes": ...}``."""
+    return _family(m).decode_step(m, contexts)
 
 
 def prefill(m: ModelSpec, prompt_len: int) -> float:
-    """Useful operations of one prompt: every valid token through every
-    layer, causal attention, and the logits of the last position."""
-    n = prompt_len
-    flops = 2.0 * n * m.layers * layer_matmul_params(m)
-    flops += _attn_flops(m, 1, 1) * n * (n + 1) / 2
-    return flops + 2.0 * m.hidden * m.padded_vocab
+    """Useful operations of one prompt of ``prompt_len`` valid tokens."""
+    return _family(m).prefill(m, prompt_len)
 
 
 def least_time(flops: float, nbytes: float, peaks: dict) -> Dict[str, float]:

@@ -5,8 +5,17 @@
   module ``traffic/<kind>.py``
 - metric: ``metrics/<metric name>.py``, whose ``read(run)`` gives the number
 - correctness limits: ``limits/<workload>.json``
+- model family: ``families/<model_type>.py`` (the configuration's published
+  ``model_type``): how the benchmark reads that family's configuration,
+  which weights the system holds, the reference's layer, and the
+  operation counts (``families/dense_decoder.py`` lists what a family module
+  provides)
 
-Adding a cell or a metric adds files; no file here needs an edit.
+Adding a cell, a metric or a configuration adds files; no file here needs an
+edit.  A configuration of a family the benchmark has adds
+``configs/<name>.json``; one of a new ``model_type`` adds
+``families/<model_type>.py`` beside it.  Each cell adds its traffic,
+limits and ``BENCHMARK.json`` entries.
 """
 
 from __future__ import annotations
@@ -64,6 +73,18 @@ def generator(kind: str) -> ModuleType:
     if d not in sys.path:
         sys.path.insert(0, d)
     return _module(HERE / "traffic" / f"{kind}.py", f"traffic_{kind}")
+
+
+def family(model_type: str) -> ModuleType:
+    """The family module of ``model_type``, loaded once per process (its
+    spec types and jitted functions then stay the same objects)."""
+    name = "family_" + model_type
+    if name not in sys.modules:
+        d = str(HERE / "families")
+        if d not in sys.path:
+            sys.path.insert(0, d)
+        sys.modules[name] = _module(HERE / "families" / f"{model_type}.py", name)
+    return sys.modules[name]
 
 
 def reader(metric: str) -> ModuleType:

@@ -48,9 +48,11 @@ def log(*parts) -> None:
 
 def configure_jax() -> None:
     """Persistent compilation cache inside the checkout (or where
-    ``JAX_COMPILATION_CACHE_DIR`` says), every program written to it; the
-    TPU runtime's logs inside the checkout too (not /tmp/tpu_logs).  Call
-    before JAX first touches a device."""
+    ``JAX_COMPILATION_CACHE_DIR`` says), every program written to it, keyed
+    with its debug info, so that a program loaded from the cache carries
+    the op names (named scopes) of the source that runs; the TPU runtime's
+    logs inside the checkout too (not /tmp/tpu_logs).  Call before JAX
+    first touches a device."""
     os.environ.setdefault("TPU_LOG_DIR", str(OUT / "tpu_logs"))
     import jax
 
@@ -59,6 +61,7 @@ def configure_jax() -> None:
                           str(manifest.ROOT / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def check_device(chips: int):
@@ -325,6 +328,7 @@ def main(argv=None) -> int:
     metrics = manifest.metrics_for(bench, cell["name"], bool(args.trace))
     for spec in metrics:
         manifest.reader(spec["name"])  # a missing reader fails before the run
+    manifest.family(conf["model_type"])  # so does a missing family
     configure_jax()
     devices, peaks = check_device(int(cell["chips"]))
     import repro.core as core

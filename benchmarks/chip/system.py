@@ -2,9 +2,10 @@
 
 The one module of the benchmark that imports the system (``repro``).  It
 builds the model named by a configuration file's ``system`` section,
-checks that its sizes are the file's, hands it the benchmark's weights,
-and serves through ``Router.replicate(model, params, ServeConfig, 1)`` →
-``Engine``, the path users call.  It touches only the system's public
+checks through the file's family module (``families/``) that its model is
+the file's, hands it the benchmark's weights, and serves through
+``Router.replicate(model, params, ServeConfig, 1)`` → ``Engine``, the path
+users call.  It touches only the system's public
 surface: ``Router.submit`` with a token stream, the serve counters, and
 ``Engine.pause`` after the window.  The warm-up is a request at every
 prompt length the traffic reaches.
@@ -14,36 +15,17 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from math import prod
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-import flops
+import manifest
 import weights
 from model_spec import ModelSpec
 from traffic_common import Request, host_rng, token_ids
 
 ENGINE = "engine#0"
-
-
-def _check_sizes(cfg, m: ModelSpec, cache_len: int) -> None:
-    """The system's model must be the configuration file's."""
-    have = dict(layers=cfg.num_layers, hidden=cfg.d_model,
-                heads=cfg.num_heads, kv_heads=cfg.num_kv_heads,
-                head_dim=cfg.head_dim, ffn=cfg.d_ff, vocab=cfg.vocab_size,
-                norm={"rmsnorm": "rms", "layernorm": "layer"}[cfg.norm],
-                gated=cfg.glu, act={"silu": "silu", "gelu": "gelu_tanh"}[cfg.act],
-                qkv_bias=cfg.qkv_bias, rope_theta=cfg.rope_theta,
-                tied=cfg.tie_embeddings)
-    want = {k: getattr(m, k) for k in have}
-    if have != want or cfg.family != "dense" or cfg.window:
-        raise ValueError(f"{m.name}: the system's model differs from the "
-                         f"configuration file: {have} != {want}")
-    if m.window and m.window < cache_len:
-        raise ValueError(f"{m.name}: a {m.window}-token window masks keys "
-                         f"at cache_len {cache_len}; the system has none")
 
 
 class TokenStream:
@@ -81,19 +63,16 @@ class System:
         cfg = dataclasses.replace(get_config(sysc["arch"],
                                              smoke=sysc.get("smoke", False)),
                                   **sysc["overrides"])
-        _check_sizes(cfg, m, sysc["serve"]["cache_len"])
+        fam = manifest.family(m.model_type)
+        fam.check_system(cfg, m, sysc["serve"]["cache_len"])
         self.m = m
         self.model = build_model(cfg, get_plan("serve"))
         specs = self.model.param_specs()
         expected = {n: (tuple(s.shape), jnp.dtype(s.dtype).name)
                     for n, s in specs.items()}
-        served = sum(prod(s.shape) for s in specs.values())
-        if served != flops.param_count(m):
-            raise ValueError(f"{m.name}: the system holds {served} "
-                             f"parameters, the configuration "
-                             f"{flops.param_count(m)}")
         params = jax.block_until_ready(
-            weights.served_params(m, weights.root_key(seed), expected))
+            weights.served_params(fam.layout(m), weights.root_key(seed),
+                                  expected))
         if fault is not None:
             fault(self.model)
         self.scfg = ServeConfig(**sysc["serve"], eos_id=-1)

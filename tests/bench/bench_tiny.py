@@ -16,6 +16,11 @@ QWEN = {"name": "tiny_qwen", "model_type": "qwen2", "hidden_size": 64,
         "rms_norm_eps": 1e-6, "rope_theta": 1e6, "tie_word_embeddings": True,
         "vocab_size": 512, "system": {"arch": "qwen25_3b", **SYSTEM}}
 
+# Qwen2 with its own output head, as Qwen2 models above 3B ship
+QWEN_UNTIED = {**QWEN, "name": "tiny_qwen_untied", "tie_word_embeddings": False,
+               "system": {**QWEN["system"], "overrides": {
+                   **SYSTEM["overrides"], "tie_embeddings": False}}}
+
 STARCODER = {"name": "tiny_starcoder", "model_type": "starcoder2",
              "hidden_size": 64, "intermediate_size": 128,
              "num_hidden_layers": 2, "num_attention_heads": 4,

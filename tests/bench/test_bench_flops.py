@@ -1,5 +1,7 @@
 """flops.py against hand arithmetic."""
 
+from math import prod
+
 import pytest
 
 import flops
@@ -23,7 +25,9 @@ def test_tied_parameter_counts(name, count):
 
 def test_layer_parameters_by_hand():
     q = spec("qwen25_3b")
-    assert flops.layer_params(q) == (2048 * 2048 * 2 + 2048 * 256 * 2
+    (stack,) = manifest.family("qwen2").layout(q).stacks
+    assert stack.layers == 36
+    assert sum(prod(s) for s, _ in stack.leaves.values()) == (2048 * 2048 * 2 + 2048 * 256 * 2
                                      + 3 * 2048 * 11008 + 2 * 2048
                                      + 2048 + 2 * 256)
 

@@ -6,37 +6,41 @@ import numpy as np
 import pytest
 
 import bench_tiny
+import manifest
 import reference
 import weights
 from model_spec import from_config
 
 
-@pytest.mark.parametrize("conf", [bench_tiny.QWEN, bench_tiny.STARCODER])
+@pytest.mark.parametrize("conf", [bench_tiny.QWEN, bench_tiny.QWEN_UNTIED,
+                                  bench_tiny.STARCODER])
 def test_served_and_reference_weights_agree(conf):
     m = from_config(conf["name"], conf)
+    layout = manifest.family(m.model_type).layout(m)
     key = weights.root_key(2**35 + 1)
-    shapes = {**{("tok_embed" if n == "embed" else "final_ln"): (s, "bfloat16")
-                 for n, (s, _) in weights.global_shapes(m).items()},
-              **{"blk/" + n: ((m.layers,) + s, "bfloat16")
-                 for n, (s, _) in weights.layer_shapes(m).items()}}
-    served = weights.served_params(m, key, shapes)
-    glob, layer = weights.reference_weights(m, key)
-    np.testing.assert_array_equal(np.asarray(served["tok_embed"], np.float32),
-                                  np.asarray(glob["embed"]))
+    served = weights.served_params(layout, key, layout.served())
+    glob, layer = weights.reference_weights(layout, key)
+    for n, (name, _, _) in layout.glob.items():
+        np.testing.assert_array_equal(np.asarray(served[name], np.float32),
+                                      np.asarray(glob[n]))
+    (stack,) = layout.stacks
     for l in range(m.layers):
         w = layer(l)
-        for n in weights.layer_shapes(m):
+        for n in stack.leaves:
             np.testing.assert_array_equal(
                 np.asarray(served["blk/" + n][l], np.float32), np.asarray(w[n]))
 
 
 def test_weights_refuse_another_layout():
     m = from_config("tiny_qwen", bench_tiny.QWEN)
+    layout = manifest.family(m.model_type).layout(m)
     with pytest.raises(ValueError, match="lm_head"):
-        weights.served_params(m, weights.root_key(1), {"lm_head": ((64, 512), "bfloat16")})
+        weights.served_params(layout, weights.root_key(1),
+                              {"lm_head": ((64, 512), "bfloat16")})
 
 
-@pytest.mark.parametrize("conf", [bench_tiny.QWEN, bench_tiny.STARCODER])
+@pytest.mark.parametrize("conf", [bench_tiny.QWEN, bench_tiny.QWEN_UNTIED,
+                                  bench_tiny.STARCODER])
 def test_reference_matches_the_system_prefill(conf):
     """The system's own prefill, fed the benchmark's weights, puts first
     the token the reference puts first (tiny widths, no near-ties)."""
@@ -54,7 +58,8 @@ def test_reference_matches_the_system_prefill(conf):
     key = weights.root_key(5)
     expected = {n: (tuple(s.shape), jnp.dtype(s.dtype).name)
                 for n, s in model.param_specs().items()}
-    params = weights.served_params(m, key, expected)
+    params = weights.served_params(manifest.family(m.model_type).layout(m),
+                                   key, expected)
     rng = np.random.default_rng(0)
     prompt = rng.integers(1, m.vocab, 37).tolist()
     logits, _ = model.prefill(params, {"tokens": jnp.asarray([prompt])})
