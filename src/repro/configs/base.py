@@ -48,6 +48,23 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     dense_d_ff: int = 0  # d_ff for the leading dense layers / shared experts base
+    norm_topk_prob: bool = True  # renormalise the top-k routing weights
+    routed_scaling_factor: float = 1.0  # times every routed expert's weight
+    # the routed experts this layer holds: share ``index`` of ``count`` equal
+    # shares of ``n_experts`` (expert parallelism); the router keeps all
+    expert_shard: Tuple[int, int] = (0, 1)
+    # multi-head latent attention (DeepSeek-V2); kv_lora_rank 0 = off
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling (DeepSeek-V2 ``rope_scaling``); factor 0 = off
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # SSM (Mamba-2 SSD)
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -68,9 +85,28 @@ class ModelConfig:
     param_dtype: str = "float32"
     attn_impl: str = "xla"  # xla | pallas (flash kernel; interpret on CPU)
 
+    def __post_init__(self):
+        # a configuration file gives the shard as a JSON list
+        object.__setattr__(self, "expert_shard", tuple(self.expert_shard))
+        i, n = self.expert_shard
+        if not 0 <= i < n or (self.n_experts and self.n_experts % n):
+            raise ValueError(f"expert_shard {self.expert_shard} does not "
+                             f"divide {self.n_experts} experts")
+
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """[first, first + count) of the routed experts this layer holds."""
+        i, n = self.expert_shard
+        per = self.n_experts // n
+        return i * per, per
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def padded_vocab(self) -> int:
@@ -119,6 +155,7 @@ ARCH_IDS: List[str] = [
     "granite_34b",
     "starcoder2_15b",
     "deepseek_moe_16b",
+    "deepseek_v2_lite",
     "granite_moe_3b_a800m",
     "recurrentgemma_2b",
     "internvl2_2b",

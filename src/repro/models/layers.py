@@ -220,7 +220,7 @@ def _chunked_attention(q, k, v, scale, causal) -> jax.Array:
         return None, _sdpa(qi, k, v, mask, scale)
 
     _, oc = jax.lax.scan(body, None, (jnp.arange(nc), qc))
-    return oc.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, KV, G, Dh)
+    return oc.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, KV, G, v.shape[-1])
 
 
 def _banded_attention(q, k, v, scale, window) -> jax.Array:
@@ -343,7 +343,7 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     fill per slot.  The pools are not written: returns (out (B,1,D), k, v)
     with the new token's k, v ``(B, KV, Dh)`` in the pools' dtype, which
     the caller writes for every layer at once after its layer scan
-    (:func:`write_paged_kv`).  Attention sees the new token at ``pos``
+    (:func:`write_paged_rows`).  Attention sees the new token at ``pos``
     all the same:
 
     - ``xla``: one gather per pool indexed by (layer, page) fetches each
@@ -410,28 +410,235 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     return o @ p[f"{prefix}wo"].astype(dt), k, v
 
 
-def write_paged_kv(k_pool: jax.Array, v_pool: jax.Array, k: jax.Array,
-                   v: jax.Array, page_table: jax.Array, pos: jax.Array
-                   ) -> Tuple[jax.Array, jax.Array]:
-    """Write one decode step's new K/V for every layer into the stacked
-    pools, under the named scope ``kv_write``.
+def write_paged_rows(pool: jax.Array, rows: jax.Array, page_table: jax.Array,
+                     pos: jax.Array) -> jax.Array:
+    """Write one decode step's new rows for every layer into a stacked pool,
+    under the named scope ``kv_write``.
 
-    k_pool/v_pool: (L, P, KV, page, Dh); k/v: (L, B, KV, Dh), as
-    :func:`paged_decode_attention` returns them per layer.
-    Row b's token goes to page ``page_table[b, pos[b] // page]`` at offset
-    ``pos[b] % page``; idle slots all hit scratch page 0.  Each (layer, row,
-    KV head) writes one ``(Dh,)`` row, which keeps the pool's (page, Dh)
-    tiling: a ``(KV, Dh)`` update window cuts across it, and XLA then lays
-    the whole pool out again and back.  In place when the pools are donated.
+    pool: (L, P, KV, page, Dh); rows: (L, B, KV, Dh), as the paged decode
+    attentions return them per layer.  Row b's token goes to page
+    ``page_table[b, pos[b] // page]`` at offset ``pos[b] % page``; idle
+    slots all hit scratch page 0.  Each (layer, row, KV head) writes one
+    ``(Dh,)`` row, which keeps the pool's (page, Dh) tiling: a ``(KV, Dh)``
+    update window cuts across it, and XLA then lays the whole pool out again
+    and back.  In place when the pool is donated.
     """
-    L, _, KV, page, _ = k_pool.shape
+    L, _, KV, page, _ = pool.shape
     B = pos.shape[0]
     with jax.named_scope("kv_write"):
         pidx = page_table[jnp.arange(B), pos // page]
         idx = (jnp.arange(L)[:, None, None], pidx[None, :, None],
                jnp.arange(KV)[None, None, :], (pos % page)[None, :, None])
-        return (k_pool.at[idx].set(k.astype(k_pool.dtype)),
-                v_pool.at[idx].set(v.astype(v_pool.dtype)))
+        return pool.at[idx].set(rows.astype(pool.dtype))
+
+
+# ------------------------------------------------- multi-head latent attention
+def yarn_get_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def _yarn_correction_dim(cfg: ModelConfig, rotations: float, dim: int) -> float:
+    """The rotary dim whose wavelength turns ``rotations`` times over the
+    original context."""
+    return (dim * math.log(cfg.yarn_original_max_position
+                           / (rotations * 2 * math.pi))
+            / (2 * math.log(cfg.rope_theta)))
+
+
+def rope_inv_freq(cfg: ModelConfig, dim: int) -> jax.Array:
+    """Inverse frequencies (dim/2,) fp32 of ``dim`` rotary dims:
+    ``rope_theta^(-2i/dim)``, or with YaRN (``yarn_factor`` > 1)
+    DeepSeek-V2's blend of that and that over the factor, by a linear ramp
+    from ``floor`` of the correction dim of ``yarn_beta_fast`` to ``ceil``
+    of that of ``yarn_beta_slow`` (dims below the ramp keep their frequency,
+    dims above it are interpolated)."""
+    extra = cfg.rope_theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if cfg.yarn_factor <= 1:
+        return extra
+    low = max(math.floor(_yarn_correction_dim(cfg, cfg.yarn_beta_fast, dim)), 0)
+    high = min(math.ceil(_yarn_correction_dim(cfg, cfg.yarn_beta_slow, dim)),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / cfg.yarn_factor * ramp + extra * (1.0 - ramp)
+
+
+def mla_rope(cfg: ModelConfig, x: jax.Array, pos: jax.Array) -> jax.Array:
+    """DeepSeek-V2's RoPE of the rope dims. x: (..., h, Dr); pos: x's
+    leading dims without the head dim, or a suffix of them ((S,) against
+    (B, S, h, Dr)).  The dims are de-interleaved first (``view(Dr/2, 2)``
+    transposed), then rotated half against half; cos/sin carry YaRN's
+    ``mscale / mscale_all_dim``."""
+    Dr = x.shape[-1]
+    half = Dr // 2
+    ang = pos.astype(jnp.float32)[..., None] * rope_inv_freq(cfg, Dr)
+    m = 1.0
+    if cfg.yarn_factor > 1:
+        m = (yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    c, s = (jnp.cos(ang) * m)[..., None, :], (jnp.sin(ang) * m)[..., None, :]
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+    xf = jnp.swapaxes(xf, -1, -2).reshape(x.shape)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                           axis=-1).astype(x.dtype)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-0.5, times YaRN's ``mscale_all_dim`` factor
+    squared where the model scales its rotary embedding."""
+    s = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor > 1 and cfg.yarn_mscale_all_dim:
+        m = yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        s *= m * m
+    return s
+
+
+def mla_row_width(cfg: ModelConfig) -> int:
+    """Width of one cached latent row: c_kv ‖ k_pe (R + Dr), zero-padded to
+    a multiple of 128 lanes.  The chip pads a 576-wide row to 640 all the
+    same, but with the padding implicit its default layout for the pool puts
+    the page index in the lanes, and the decode program then copies the
+    whole pool into a row-major layout and back every step."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _mla_project(cfg: ModelConfig, x: jax.Array, p: Dict[str, jax.Array],
+                 prefix: str, pos: jax.Array):
+    """x: (..., D) → q_nope (..., H, Dn), q_pe (..., H, Dr) after RoPE, and
+    the latent row (..., :func:`mla_row_width`): RMSNorm(c_kv) ‖ RoPE(k_pe)
+    ‖ zeros, which is all the cache holds of a token."""
+    dt = cdtype(cfg)
+    H, Dn, Dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    R = cfg.kv_lora_rank
+    q = (x @ p[f"{prefix}wq"].astype(dt)).reshape(*x.shape[:-1], H, Dn + Dr)
+    a = x @ p[f"{prefix}wkv_a"].astype(dt)
+    c = norm(cfg, a[..., :R], p[f"{prefix}kv_norm"])
+    k_pe = mla_rope(cfg, a[..., None, R:], pos)[..., 0, :]
+    pad = jnp.zeros((*c.shape[:-1], mla_row_width(cfg) - R - Dr), c.dtype)
+    return (q[..., :Dn], mla_rope(cfg, q[..., Dn:], pos),
+            jnp.concatenate([c, k_pe.astype(c.dtype), pad], axis=-1))
+
+
+def mla_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
+                  p: Dict[str, jax.Array], prefix: str, positions: jax.Array,
+                  return_kv: bool = False):
+    """Causal multi-head latent attention over full sequences (train /
+    prefill), as DeepSeek-V2 writes it: ``k_nope ‖ v = c_kv W_kv_b`` per
+    head, the one ``k_pe`` shared by every head.  With ``return_kv=True``
+    also returns ``(latent,)``: the (B, S, 1, :func:`mla_row_width`) rows
+    the cache holds.
+    """
+    dt = cdtype(cfg)
+    B, S, _ = x.shape
+    H, Dn, Dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    R, Dv = cfg.kv_lora_rank, cfg.v_head_dim
+    with jax.named_scope("qkv"):
+        q_nope, q_pe, lat = _mla_project(cfg, x, p, prefix, positions)
+        kv = (lat[..., :R] @ p[f"{prefix}wkv_b"].astype(dt)).reshape(
+            B, S, H, Dn + Dv)
+        k_pe = jnp.broadcast_to(lat[:, :, None, R:R + Dr], (B, S, H, Dr))
+        k = jnp.concatenate([kv[..., :Dn], k_pe], axis=-1)
+        v = kv[..., Dn:]
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    q = plan.constrain(q[:, :, :, None, :], ("batch", "seq", None, None, None))
+    scale = mla_softmax_scale(cfg)
+    if S <= _CHUNK_THRESHOLD:
+        o = _sdpa(q, k, v, _causal_mask(S, S, 0), scale)
+    else:
+        o = _chunked_attention(q, k, v, scale, True)
+    out = o.reshape(B, S, H * Dv) @ p[f"{prefix}wo"].astype(dt)
+    if return_kv:
+        return out, (lat[:, :, None, :],)
+    return out
+
+
+def _mla_absorbed(cfg: ModelConfig, p: Dict[str, jax.Array], prefix: str,
+                  q_nope: jax.Array, q_pe: jax.Array, lat: jax.Array,
+                  valid: jax.Array) -> jax.Array:
+    """One query per row against latent rows, in absorbed form.
+
+    q_nope (B, H, Dn), q_pe (B, H, Dr); lat (B, T, C): c_kv ‖ k_pe ‖ zeros,
+    C >= R + Dr; valid (B, T).  ``W_kv_b``'s key part folds into the query
+    (``q_lat = q_nope W_UK^T``), so the scores are ``[q_lat ‖ q_pe ‖ 0] ·
+    lat`` — one key shared by the heads — and the values are the rows'
+    first R columns,
+    taken back to each head's width by ``W_UV`` after the softmax.  Named
+    scopes: ``mla_absorb`` (the two absorption matmuls), ``paged_attention``
+    (scores, masking, softmax, the weighted sum of latent rows).
+    Returns (B, 1, H * Dv)."""
+    dt = cdtype(cfg)
+    B, H, Dn = q_nope.shape
+    R, Dv = cfg.kv_lora_rank, cfg.v_head_dim
+    wkv_b = p[f"{prefix}wkv_b"].astype(dt).reshape(R, H, Dn + Dv)
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wkv_b[..., :Dn],
+                           preferred_element_type=jnp.float32).astype(dt)
+    with jax.named_scope("paged_attention"):
+        pad = jnp.zeros((B, H, lat.shape[-1] - R - q_pe.shape[-1]), dt)
+        qf = jnp.concatenate([q_lat, q_pe.astype(dt), pad], axis=-1)
+        s = jnp.einsum("bhc,btc->bht", qf, lat.astype(dt),
+                       preferred_element_type=jnp.float32)
+        s = jnp.where(valid[:, None, :], s * mla_softmax_scale(cfg), -1e30)
+        pr = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bht,btr->bhr", pr.astype(dt),
+                           lat[..., :R].astype(dt))
+    with jax.named_scope("mla_absorb"):
+        o = jnp.einsum("bhr,rhv->bhv", o_lat, wkv_b[..., Dn:])
+    return o.reshape(B, 1, H * Dv)
+
+
+def mla_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
+                         p: Dict[str, jax.Array], prefix: str,
+                         cache: jax.Array, pos: jax.Array
+                         ) -> Tuple[jax.Array, jax.Array]:
+    """One-token MLA against a dense latent cache (B, T, 1, C), per-slot
+    positions.  Returns (out (B,1,D), the cache with the new row)."""
+    dt = cdtype(cfg)
+    B, T = cache.shape[:2]
+    with jax.named_scope("qkv"):
+        q_nope, q_pe, lat = _mla_project(cfg, x[:, 0], p, prefix, pos)
+    cache = cache.at[jnp.arange(B), jnp.minimum(pos, T - 1), 0].set(
+        lat.astype(cache.dtype))
+    valid = jnp.arange(T)[None, :] <= pos[:, None]
+    o = _mla_absorbed(cfg, p, prefix, q_nope, q_pe, cache[:, :, 0], valid)
+    return o @ p[f"{prefix}wo"].astype(dt), cache
+
+
+def mla_paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan,
+                               x: jax.Array, p: Dict[str, jax.Array],
+                               prefix: str, pool: jax.Array, layer: jax.Array,
+                               page_table: jax.Array, pos: jax.Array
+                               ) -> Tuple[jax.Array, jax.Array]:
+    """One-token MLA against the *paged* latent pool, read in place.
+
+    pool: (L, P, 1, page, :func:`mla_row_width`), one latent row per token
+    and layer;
+    layer, page_table, pos as :func:`paged_decode_attention`.  One gather
+    indexed by (layer, page) fetches each row's page list, the new latent
+    row is set into that copy at ``pos``, then absorbed attention
+    (:func:`_mla_absorbed`) reads only those rows: MQA with H query heads.
+    The pool is not written: returns (out (B,1,D), the new row
+    (B, 1, C) in the pool's dtype), which the caller writes for every
+    layer at once after its layer scan (:func:`write_paged_rows`)."""
+    dt = cdtype(cfg)
+    B = x.shape[0]
+    if cfg.attn_impl != "xla":
+        raise NotImplementedError("latent attention has only the xla path")
+    with jax.named_scope("qkv"):
+        q_nope, q_pe, lat = _mla_project(cfg, x[:, 0], p, prefix, pos)
+    lat = lat.astype(pool.dtype)
+
+    from repro.kernels import ops as kops
+
+    with jax.named_scope("paged_attention"):
+        lc = kops.gather_layer_pages(pool, layer, page_table)[:, 0]
+        lc = lc.at[jnp.arange(B), pos].set(lat)
+        valid = jnp.arange(lc.shape[1])[None, :] < (pos + 1)[:, None]
+    o = _mla_absorbed(cfg, p, prefix, q_nope, q_pe, lc, valid)
+    return o @ p[f"{prefix}wo"].astype(dt), lat[:, None, :]
 
 
 # --------------------------------------------------------------- embedding

@@ -32,7 +32,25 @@ from repro.models.params import ParamSpec
 
 
 # ------------------------------------------------------------------- specs
+def _mla_specs(cfg: ModelConfig, L: int, prefix: str) -> Dict[str, ParamSpec]:
+    """Latent attention, no q LoRA: ``wq`` (D, H·(Dn+Dr)); ``wkv_a`` (D,
+    R+Dr) → c_kv ‖ k_pe; ``kv_norm`` on c_kv; ``wkv_b`` (R, H·(Dn+Dv)),
+    per head k_nope ‖ v; ``wo`` (H·Dv, D)."""
+    D, H, R = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        f"{prefix}ln1": ParamSpec((L, D), ("layers", None), init="ones"),
+        f"{prefix}wq": ParamSpec((L, D, H * (Dn + Dr)), ("layers", "embed", "heads")),
+        f"{prefix}wkv_a": ParamSpec((L, D, R + Dr), ("layers", "embed", None)),
+        f"{prefix}kv_norm": ParamSpec((L, R), ("layers", None), init="ones"),
+        f"{prefix}wkv_b": ParamSpec((L, R, H * (Dn + Dv)), ("layers", None, "heads")),
+        f"{prefix}wo": ParamSpec((L, H * Dv, D), ("layers", "heads", "embed")),
+    }
+
+
 def _attn_specs(cfg: ModelConfig, L: int, prefix: str) -> Dict[str, ParamSpec]:
+    if cfg.mla:
+        return _mla_specs(cfg, L, prefix)
     D, H, KV, Dh = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     specs = {
         f"{prefix}ln1": ParamSpec((L, D), ("layers", None), init="ones"),
@@ -124,10 +142,19 @@ def stacked_gather_constrain(plan: ShardingPlan, tree: Dict[str, jax.Array],
 def _layer_body(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
                 lp: Dict[str, jax.Array], positions: jax.Array,
                 moe_layer: bool, collect_kv: bool = False):
+    """One layer over whole sequences.  ``collect_kv`` (prefill) also
+    returns what the cache holds of the layer, as a tuple in
+    :func:`cache_keys` order, and routes MoE layers as serving does (no
+    token dropped); training keeps capacity dispatch."""
     x = plan.constrain(x, ("batch", "seq_sp", None))
     h = Lx.norm(cfg, x, lp["ln1"])
-    attn_out = Lx.attention(cfg, plan, h, lp, "", positions, causal=cfg.causal,
-                            window=cfg.window, return_kv=collect_kv)
+    if cfg.mla:
+        attn_out = Lx.mla_attention(cfg, plan, h, lp, "", positions,
+                                    return_kv=collect_kv)
+    else:
+        attn_out = Lx.attention(cfg, plan, h, lp, "", positions,
+                                causal=cfg.causal, window=cfg.window,
+                                return_kv=collect_kv)
     if collect_kv:
         h, kv = attn_out
     else:
@@ -135,7 +162,7 @@ def _layer_body(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     x = x + h
     h = Lx.norm(cfg, x, lp["ln2"])
     if moe_layer:
-        ffn, aux = moe_ffn(cfg, plan, h, lp, "moe/")
+        ffn, aux = moe_ffn(cfg, plan, h, lp, "moe/", serve=collect_kv)
     else:
         ffn, aux = Lx.mlp(cfg, plan, h, lp, ""), jnp.zeros((), jnp.float32)
     return x + ffn, aux, kv
@@ -144,7 +171,8 @@ def _layer_body(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
 def _run_stack(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
                stacked: Dict[str, jax.Array], axes: Dict[str, Tuple],
                positions: jax.Array, moe_layer: bool, collect_kv: bool = False):
-    """lax.scan over a stacked layer dict; returns (x, aux_sum, stacked_kv)."""
+    """lax.scan over a stacked layer dict; returns (x, aux_sum, stacked
+    cache rows: a tuple in :func:`cache_keys` order, or None)."""
 
     def body(carry, lp):
         x, aux_sum = carry
@@ -204,28 +232,43 @@ def loss_fn(cfg: ModelConfig, plan: ShardingPlan, params: Dict[str, jax.Array],
 
 
 # -------------------------------------------------------------------- cache
+def cache_keys(cfg: ModelConfig, group: str) -> Tuple[str, ...]:
+    """The cache arrays of layer group ``group`` ("d0/" or "blk/"): one
+    latent array under MLA (``ckv``: c_kv ‖ k_pe of every token), K and V
+    otherwise; the leading dense group's names end in 0."""
+    suffix = "0" if group == "d0/" else ""
+    return tuple(n + suffix for n in (("ckv",) if cfg.mla else ("k", "v")))
+
+
+def _row_shape(cfg: ModelConfig) -> Tuple[int, int]:
+    """(heads, width) of one token's cache row in one layer."""
+    if cfg.mla:
+        return 1, Lx.mla_row_width(cfg)
+    return cfg.num_kv_heads, cfg.head_dim
+
+
+def _groups(cfg: ModelConfig):
+    """(prefix, depth) of each layer group, in order."""
+    fd = cfg.first_dense
+    return ((("d0/", fd),) if fd > 0 else ()) + (("blk/", cfg.num_layers - fd),)
+
+
 def init_cache_specs(cfg: ModelConfig, batch: int, cache_len: int) -> Dict[str, jax.ShapeDtypeStruct]:
     """Abstract KV-cache pytree for the dry-run / serve engine."""
-    KV, Dh = cfg.num_kv_heads, cfg.head_dim
-    fd, Lm = cfg.first_dense, cfg.num_layers - cfg.first_dense
+    KV, Dh = _row_shape(cfg)
     dt = jnp.dtype(cfg.dtype)
-    specs = {
-        "k": jax.ShapeDtypeStruct((Lm, batch, cache_len, KV, Dh), dt),
-        "v": jax.ShapeDtypeStruct((Lm, batch, cache_len, KV, Dh), dt),
-        "pos": jax.ShapeDtypeStruct((batch,), jnp.int32),
-    }
-    if fd > 0:
-        specs["k0"] = jax.ShapeDtypeStruct((fd, batch, cache_len, KV, Dh), dt)
-        specs["v0"] = jax.ShapeDtypeStruct((fd, batch, cache_len, KV, Dh), dt)
+    specs = {"pos": jax.ShapeDtypeStruct((batch,), jnp.int32)}
+    for group, L in _groups(cfg):
+        for key in cache_keys(cfg, group):
+            specs[key] = jax.ShapeDtypeStruct((L, batch, cache_len, KV, Dh), dt)
     return specs
 
 
 def cache_axes(cfg: ModelConfig) -> Dict[str, Tuple]:
     ax = ("layers", "batch", "kv_seq", "kv_heads", None)
-    out = {"k": ax, "v": ax, "pos": ("batch",)}
-    if cfg.first_dense > 0:
-        out["k0"] = ax
-        out["v0"] = ax
+    out = {"pos": ("batch",)}
+    for group, _ in _groups(cfg):
+        out.update({key: ax for key in cache_keys(cfg, group)})
     return out
 
 
@@ -241,38 +284,38 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int,
     ``page_size`` pages shared by every layer (same page index holds a
     request's tokens in all layers, vLLM-style; each page is head-major,
     ``(KV, page, Dh)``, as the paged decode kernel tiles it), plus per-slot
-    page tables and fill positions.  Memory scales with live tokens, not
+    page tables and fill positions.  Under MLA each layer group has one
+    latent pool of ``(1, page, C)`` pages in place of K and V (C:
+    :func:`layers.mla_row_width`).  Memory scales with live tokens, not
     ``max_batch × cache_len``.  The decode step reads the stacked pools in
-    place during its layer scan and writes the step's new K/V once after it
-    (:func:`decode_step_paged`)."""
-    KV, Dh = cfg.num_kv_heads, cfg.head_dim
-    fd, Lm = cfg.first_dense, cfg.num_layers - cfg.first_dense
+    place during its layer scan and writes the step's new rows once after
+    it (:func:`decode_step_paged`)."""
+    KV, Dh = _row_shape(cfg)
     dt = jnp.dtype(cfg.dtype)
     specs = {
-        "k": jax.ShapeDtypeStruct((Lm, num_pages, KV, page_size, Dh), dt),
-        "v": jax.ShapeDtypeStruct((Lm, num_pages, KV, page_size, Dh), dt),
         "page_table": jax.ShapeDtypeStruct((max_batch, max_pages_per_req), jnp.int32),
         "pos": jax.ShapeDtypeStruct((max_batch,), jnp.int32),
     }
-    if fd > 0:
-        specs["k0"] = jax.ShapeDtypeStruct((fd, num_pages, KV, page_size, Dh), dt)
-        specs["v0"] = jax.ShapeDtypeStruct((fd, num_pages, KV, page_size, Dh), dt)
+    for group, L in _groups(cfg):
+        for key in cache_keys(cfg, group):
+            specs[key] = jax.ShapeDtypeStruct((L, num_pages, KV, page_size, Dh), dt)
     return specs
 
 
 def _paged_decode_stack(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
                         stacked: Dict[str, jax.Array], axes: Dict[str, Tuple],
-                        k_pool: jax.Array, v_pool: jax.Array,
+                        pools: Tuple[jax.Array, ...],
                         page_table: jax.Array, pos: jax.Array,
                         moe_layer: bool):
     """lax.scan over one stacked layer group against its pools
-    (L, P, KV, page, Dh); returns (x, new k_pool, new v_pool).
+    (L, P, KV, page, Dh): K and V, or one latent pool under MLA; returns
+    (x, the new pools).
 
     The pools are read-only inside the scan, which carries ``x`` and scans
     the layer params and the layer index; its ``ys`` are each layer's new
-    K/V (L, B, KV, Dh), written into the pools once after it.  Scanning the
-    pools as ``xs``/``ys`` instead makes XLA copy each layer's slice out and
-    back and copy both whole pools, every step.
+    rows (L, B, KV, Dh) per pool, written into the pools once after it.
+    Scanning the pools as ``xs``/``ys`` instead makes XLA copy each layer's
+    slice out and back and copy the whole pools, every step.
     """
 
     def layer(x, xs):
@@ -280,20 +323,27 @@ def _paged_decode_stack(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
         if not plan.gather_upfront:
             lp = gather_constrain(plan, lp, axes)
         h = Lx.norm(cfg, x, lp["ln1"])
-        h, k, v = Lx.paged_decode_attention(cfg, plan, h, lp, "", k_pool,
-                                            v_pool, idx, page_table, pos)
+        if cfg.mla:
+            h, lat = Lx.mla_paged_decode_attention(cfg, plan, h, lp, "",
+                                                   pools[0], idx, page_table,
+                                                   pos)
+            rows = (lat,)
+        else:
+            h, k, v = Lx.paged_decode_attention(cfg, plan, h, lp, "", *pools,
+                                                idx, page_table, pos)
+            rows = (k, v)
         x = x + h
         h = Lx.norm(cfg, x, lp["ln2"])
         if moe_layer:
-            ffn, _ = moe_ffn(cfg, plan, h, lp, "moe/")
+            ffn, _ = moe_ffn(cfg, plan, h, lp, "moe/", serve=True)
         else:
             ffn = Lx.mlp(cfg, plan, h, lp, "")
-        return x + ffn, (k, v)
+        return x + ffn, rows
 
-    x, (k, v) = jax.lax.scan(
-        layer, x, (stacked, jnp.arange(k_pool.shape[0], dtype=jnp.int32)))
-    k_pool, v_pool = Lx.write_paged_kv(k_pool, v_pool, k, v, page_table, pos)
-    return x, k_pool, v_pool
+    x, rows = jax.lax.scan(
+        layer, x, (stacked, jnp.arange(pools[0].shape[0], dtype=jnp.int32)))
+    return x, tuple(Lx.write_paged_rows(pool, r, page_table, pos)
+                    for pool, r in zip(pools, rows))
 
 
 def decode_step_paged(cfg: ModelConfig, plan: ShardingPlan,
@@ -310,18 +360,16 @@ def decode_step_paged(cfg: ModelConfig, plan: ShardingPlan,
     x = Lx.embed(cfg, plan, params["tok_embed"], token)
     new_cache = dict(cache)
 
-    if cfg.first_dense > 0:
-        x, new_cache["k0"], new_cache["v0"] = _paged_decode_stack(
-            cfg, plan, x, _slice_params(params, "d0/"),
-            _layer_axes(specs, "d0/"), cache["k0"], cache["v0"], pt, pos,
-            False)
-
-    blk = _slice_params(params, "blk/")
-    ax = _layer_axes(specs, "blk/")
-    if plan.gather_upfront:
-        blk = stacked_gather_constrain(plan, blk, ax)
-    x, new_cache["k"], new_cache["v"] = _paged_decode_stack(
-        cfg, plan, x, blk, ax, cache["k"], cache["v"], pt, pos, cfg.is_moe)
+    for group, _ in _groups(cfg):
+        stacked = _slice_params(params, group)
+        ax = _layer_axes(specs, group)
+        if group == "blk/" and plan.gather_upfront:
+            stacked = stacked_gather_constrain(plan, stacked, ax)
+        keys = cache_keys(cfg, group)
+        x, pools = _paged_decode_stack(
+            cfg, plan, x, stacked, ax, tuple(cache[k] for k in keys), pt, pos,
+            cfg.is_moe and group == "blk/")
+        new_cache.update(zip(keys, pools))
     new_cache["pos"] = pos + 1
 
     x = Lx.norm(cfg, x, params["final_ln"])
@@ -330,18 +378,25 @@ def decode_step_paged(cfg: ModelConfig, plan: ShardingPlan,
     return logits[:, 0, :], new_cache
 
 
-def _decode_layer(cfg: ModelConfig, plan: ShardingPlan, x, lp, kc, vc, pos,
+def _decode_layer(cfg: ModelConfig, plan: ShardingPlan, x, lp, caches, pos,
                   moe_layer: bool):
+    """One layer of one decode step against dense per-slot caches (a tuple
+    in :func:`cache_keys` order); returns (x, the new caches)."""
     h = Lx.norm(cfg, x, lp["ln1"])
-    h, kc, vc = Lx.decode_attention(cfg, plan, h, lp, "", kc, vc, pos,
-                                    window=cfg.window)
+    if cfg.mla:
+        h, c = Lx.mla_decode_attention(cfg, plan, h, lp, "", caches[0], pos)
+        caches = (c,)
+    else:
+        h, kc, vc = Lx.decode_attention(cfg, plan, h, lp, "", *caches, pos,
+                                        window=cfg.window)
+        caches = (kc, vc)
     x = x + h
     h = Lx.norm(cfg, x, lp["ln2"])
     if moe_layer:
-        ffn, _ = moe_ffn(cfg, plan, h, lp, "moe/")
+        ffn, _ = moe_ffn(cfg, plan, h, lp, "moe/", serve=True)
     else:
         ffn = Lx.mlp(cfg, plan, h, lp, "")
-    return x + ffn, kc, vc
+    return x + ffn, caches
 
 
 def decode_step(cfg: ModelConfig, plan: ShardingPlan, params: Dict[str, jax.Array],
@@ -353,34 +408,22 @@ def decode_step(cfg: ModelConfig, plan: ShardingPlan, params: Dict[str, jax.Arra
     x = Lx.embed(cfg, plan, params["tok_embed"], token)
     new_cache = dict(cache)
 
-    if cfg.first_dense > 0:
-        d0 = _slice_params(params, "d0/")
-        a0 = _layer_axes(specs, "d0/")
+    for group, _ in _groups(cfg):
+        stacked = _slice_params(params, group)
+        ax = _layer_axes(specs, group)
+        if group == "blk/" and plan.gather_upfront:
+            stacked = stacked_gather_constrain(plan, stacked, ax)
+        moe_layer = cfg.is_moe and group == "blk/"
 
-        def body0(x, xs):
-            lp, kc, vc = xs
+        def body(x, xs, ax=ax, moe_layer=moe_layer):
+            lp, caches = xs
             if not plan.gather_upfront:
-                lp = gather_constrain(plan, lp, a0)
-            x, kc, vc = _decode_layer(cfg, plan, x, lp, kc, vc, pos, False)
-            return x, (kc, vc)
+                lp = gather_constrain(plan, lp, ax)
+            return _decode_layer(cfg, plan, x, lp, caches, pos, moe_layer)
 
-        x, (nk0, nv0) = jax.lax.scan(body0, x, (d0, cache["k0"], cache["v0"]))
-        new_cache["k0"], new_cache["v0"] = nk0, nv0
-
-    blk = _slice_params(params, "blk/")
-    ax = _layer_axes(specs, "blk/")
-    if plan.gather_upfront:
-        blk = stacked_gather_constrain(plan, blk, ax)
-
-    def body(x, xs):
-        lp, kc, vc = xs
-        if not plan.gather_upfront:
-            lp = gather_constrain(plan, lp, ax)
-        x, kc, vc = _decode_layer(cfg, plan, x, lp, kc, vc, pos, cfg.is_moe)
-        return x, (kc, vc)
-
-    x, (nk, nv) = jax.lax.scan(body, x, (blk, cache["k"], cache["v"]))
-    new_cache["k"], new_cache["v"] = nk, nv
+        keys = cache_keys(cfg, group)
+        x, caches = jax.lax.scan(body, x, (stacked, tuple(cache[k] for k in keys)))
+        new_cache.update(zip(keys, caches))
     new_cache["pos"] = pos + 1
 
     x = Lx.norm(cfg, x, params["final_ln"])
@@ -414,24 +457,16 @@ def prefill(cfg: ModelConfig, plan: ShardingPlan, params: Dict[str, jax.Array],
     positions = jnp.arange(S, dtype=jnp.int32)
     cache = init_cache(cfg, B, T)
 
-    if cfg.first_dense > 0:
-        d0 = _slice_params(params, "d0/")
-        a0 = _layer_axes(specs, "d0/")
+    for group, _ in _groups(cfg):
+        stacked = _slice_params(params, group)
+        ax = _layer_axes(specs, group)
         if plan.gather_upfront:
-            d0 = stacked_gather_constrain(plan, d0, a0)
-        x, _, (k0, v0) = _run_stack(cfg, plan, x, d0, a0, positions,
-                                    moe_layer=False, collect_kv=True)
-        cache["k0"] = _place(cache["k0"], k0)
-        cache["v0"] = _place(cache["v0"], v0)
-
-    blk = _slice_params(params, "blk/")
-    ax = _layer_axes(specs, "blk/")
-    if plan.gather_upfront:
-        blk = stacked_gather_constrain(plan, blk, ax)
-    x, _, (k, v) = _run_stack(cfg, plan, x, blk, ax, positions,
-                              moe_layer=cfg.is_moe, collect_kv=True)
-    cache["k"] = _place(cache["k"], k)
-    cache["v"] = _place(cache["v"], v)
+            stacked = stacked_gather_constrain(plan, stacked, ax)
+        x, _, rows = _run_stack(cfg, plan, x, stacked, ax, positions,
+                                moe_layer=cfg.is_moe and group == "blk/",
+                                collect_kv=True)
+        for key, r in zip(cache_keys(cfg, group), rows):
+            cache[key] = _place(cache[key], r)
     if valid_len is None:
         cache["pos"] = jnp.full((B,), S, jnp.int32)
         x_last = x[:, -1:, :]
@@ -448,5 +483,5 @@ def prefill(cfg: ModelConfig, plan: ShardingPlan, params: Dict[str, jax.Array],
 
 
 def _place(buf: jax.Array, kv: jax.Array) -> jax.Array:
-    """Write (L,B,S,KV,Dh) prefill K/V into the (L,B,T,KV,Dh) cache buffer."""
+    """Write (L,B,S,KV,Dh) prefill rows into the (L,B,T,KV,Dh) cache buffer."""
     return jax.lax.dynamic_update_slice_in_dim(buf, kv.astype(buf.dtype), 0, axis=2)

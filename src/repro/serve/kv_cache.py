@@ -42,7 +42,7 @@ import numpy as np
 from repro.core import agas as _agas
 from repro.core import counters as _counters
 
-_POOL_KEYS = ("k", "v", "k0", "v0")
+_POOL_KEYS = ("k", "v", "k0", "v0", "ckv", "ckv0")  # K/V, or the MLA latent
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -107,8 +107,9 @@ class PagedKVCache:
               length: int) -> bool:
         """Bind ``slot`` to a freshly prefilled request: allocate pages for
         its ``length`` valid tokens and scatter the (possibly right-padded)
-        prefill K/V into them.  Returns False if the pool is exhausted
-        (caller retries after the next completion frees pages)."""
+        prefill rows of every pool (K and V, or the latent) into them.
+        Returns False if the pool is exhausted (caller retries after the
+        next completion frees pages)."""
         assert not self._owned[slot], f"slot {slot} still owns pages"
         npg = -(-length // self.page_size)  # ceil
         if npg > self.max_pages_per_req:

@@ -243,7 +243,7 @@ def _oracle_decode(cfg, plan, params, cache, token):
             h, kp, vp = _oracle_attention(cfg, plan, h, lp, kp, vp, pt, pos)
             x = x + h
             h = Lx.norm(cfg, x, lp["ln2"])
-            ffn = (moe_ffn(cfg, plan, h, lp, "moe/")[0] if moe_layer
+            ffn = (moe_ffn(cfg, plan, h, lp, "moe/", serve=True)[0] if moe_layer
                    else Lx.mlp(cfg, plan, h, lp, ""))
             return x + ffn, (kp, vp)
 
