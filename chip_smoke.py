@@ -11,16 +11,17 @@ the script exits non-zero and prints no result line):
   CPU fallback.
 - ``serve`` — qwen25_3b at its published widths and full depth, bf16
   weights from seed 0, served through ``Router.replicate`` / ``Engine``
-  with the paged KV cache and the default XLA attention: 8 greedy requests
+  with the paged KV cache and the default XLA prefill attention: 8 greedy requests
   with prompts spread over 16–1024 tokens, 32 new tokens each.  The same
   requests run twice: the first pass pays the compiles, the second is the
   warm wall time, and both must give the same tokens.
-- ``pallas`` — the same requests with ``attn_impl="pallas"`` (flash prefill,
-  paged flash-decode).  The compiled decode step must hold a Mosaic kernel
-  (``tpu_custom_call``: nothing runs in interpret mode); first tokens must
-  equal phase serve's (a near-tie may resolve to another near-tied token:
-  ``TIE_MARGIN``) and prefill logits agree within ``LOGIT_TOL``; the
-  paged decode kernel alone matches the f32 oracle at serving widths.
+- ``pallas`` — the same requests with ``attn_impl="pallas"`` (flash prefill;
+  the paged decode kernel runs on TPU in both phases).  The compiled decode
+  step must hold a Mosaic kernel (``tpu_custom_call``: nothing runs in
+  interpret mode); first tokens must equal phase serve's (a near-tie may
+  resolve to another near-tied token: ``TIE_MARGIN``) and prefill logits
+  agree within ``LOGIT_TOL``; the paged decode kernel alone matches the
+  f32 oracle at serving widths.
 - ``train4`` (``--chips 4`` only) — the ``futurized`` trainer on a 2×2
   ``("data", "model")`` mesh: qwen25_3b at full widths and ``TRAIN4_LAYERS``
   layers (the deepest that fits) for 3 steps (per-chip peak memory shows
@@ -216,8 +217,10 @@ def first_tokens_agree(ref_logits, ref_first, got_first):
 
 
 def paged_kernel_vs_oracle(cfg, B=8, page=16, maxp=128, seed=SEED):
-    """The paged flash-decode kernel at serving widths against the f32
-    oracle, on shuffled page lists with mixed fills; returns max |err|."""
+    """The paged decode kernel at serving widths against the f32 oracle:
+    layer 1 of 3-layer stacked pools on shuffled page lists with mixed
+    fills, each row's newest token given beside the pools, as the decode
+    step gives it; returns max |err|."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -225,7 +228,7 @@ def paged_kernel_vs_oracle(cfg, B=8, page=16, maxp=128, seed=SEED):
     from repro.kernels import ops, ref
 
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    T = page * maxp
+    T, L = page * maxp, 3
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, H, Dh), jnp.bfloat16)
     k = jax.random.normal(ks[1], (B, T, KV, Dh), jnp.bfloat16)
@@ -234,14 +237,20 @@ def paged_kernel_vs_oracle(cfg, B=8, page=16, maxp=128, seed=SEED):
     P = B * maxp + 1
     pt = jnp.asarray(1 + rng.permutation(P - 1).reshape(B, maxp), jnp.int32)
 
-    def pool(x):  # (B, T, KV, Dh) → (P, KV, page, Dh) at the table's pages
+    def pool(x):  # (B, T, KV, Dh) → layer 1 of (L, P, KV, page, Dh)
         x = x.reshape(B * maxp, page, KV, Dh).transpose(0, 2, 1, 3)
-        return jnp.zeros((P, KV, page, Dh), x.dtype).at[pt.reshape(-1)].set(x)
+        return jnp.zeros((L, P, KV, page, Dh), x.dtype).at[
+            1, pt.reshape(-1)].set(x)
 
     lens = jnp.asarray(rng.integers(1, T + 1, size=B), jnp.int32)
-    o = ops.paged_decode_attention(q, pool(k), pool(v), pt, lens)
+    pos, rows = lens - 1, jnp.arange(B)
+    o = ops.paged_attention_kernel(
+        q.reshape(B, KV, H // KV, Dh), (pool(k), pool(v)),
+        (k[rows, pos], v[rows, pos]), jnp.int32(1), pt, pos,
+        scale=1.0 / np.sqrt(Dh), value_width=Dh)
     e = ref.decode_mha(q, k, v, length=lens)
-    return float(jnp.max(jnp.abs(o.astype(jnp.float32) - e.astype(jnp.float32))))
+    return float(jnp.max(jnp.abs(o.reshape(B, H, Dh).astype(jnp.float32)
+                                 - e.astype(jnp.float32))))
 
 
 def phase_serve_and_pallas(dev) -> None:

@@ -83,7 +83,9 @@ class ModelConfig:
     # numerics / kernels
     dtype: str = "bfloat16"  # activation/compute dtype
     param_dtype: str = "float32"
-    attn_impl: str = "xla"  # xla | pallas (flash kernel; interpret on CPU)
+    # full-sequence attention (prefill, training): xla | pallas (flash
+    # kernel; interpret on CPU).  Paged decode picks its path by backend.
+    attn_impl: str = "xla"
 
     def __post_init__(self):
         # a configuration file gives the shard as a JSON list

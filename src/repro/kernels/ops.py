@@ -4,7 +4,10 @@ Single-source contract (the HPX.Compute claim, DESIGN.md P7): call sites
 use these ops everywhere; on TPU they run the Mosaic-compiled kernels, on
 CPU they execute the same kernel bodies under ``interpret=True`` — one
 source, two backends, identical semantics (tests assert allclose against
-``ref.py`` oracles on both paths).
+``ref.py`` oracles on both paths).  The exception is paged decode
+attention (:func:`paged_attention`), which the serving engine runs every
+step: off TPU it takes the XLA gather path, which is also the kernel
+tests' oracle, rather than the interpreter.
 
 Wrappers own the ugly parts: GQA head broadcasting, layout flattening to
 kernel-friendly (rows, seq, feature) shapes, and padding to block multiples.
@@ -19,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention import (decode_attention_fwd,
-                                            paged_decode_attention_fwd)
+                                            paged_attention_fwd)
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rglru_scan import rglru_scan_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
@@ -87,45 +90,71 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return o.reshape(B, H, Dh)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                           page_table: jax.Array, lengths: jax.Array, *,
-                           interpret: Optional[bool] = None) -> jax.Array:
-    """Paged flash-decode over a block-pool KV cache.
+# The paged kernel's block: at least this many bytes of K/V (or latent)
+# copied per block, whatever the page geometry.
+BLOCK_BYTES = 512 << 10
+RING = 4  # blocks in the kernel's ring: one computing, the rest in flight
 
-    q: (B,H,Dh); k_pages/v_pages: (P, KV, page, Dh); page_table: (B, maxp)
-    int32 (entries past the fill must be valid pool indices, e.g. 0);
-    lengths: (B,) int32 → (B,H,Dh).  No dense gather — each request walks
-    its own page list via the scalar-prefetched table.
+
+def pages_per_block(pools, page: int, maxp: int) -> int:
+    """Pages per kernel block: the fewest, in a power of two, for one
+    block's copy (every pool's pages) to hold :data:`BLOCK_BYTES`, and no
+    more than a row's ``maxp``."""
+    row = sum(p.shape[2] * p.shape[4] * p.dtype.itemsize for p in pools)
+    n = -(-BLOCK_BYTES // (page * row))
+    return min(1 << (n - 1).bit_length(), maxp)
+
+
+def paged_attention(q: jax.Array, pools, new, layer: jax.Array,
+                    page_table: jax.Array, pos: jax.Array, *, scale: float,
+                    value_width: int) -> jax.Array:
+    """One-token attention over stacked paged pools, read in place (P7: the
+    Pallas kernel on TPU, the XLA gather elsewhere).
+
+    q: (B, KV, G, W); pools: (K, V) or one latent pool whose rows are key
+    and value (the value is a row's first ``value_width`` columns), each
+    (L, P, KV, page, W); new: the step's new row per pool, (B, KV, W), at
+    position ``pos`` (B,) — not in the pools; layer: () int32; page_table:
+    (B, maxp) int32.  Row b attends positions ``[0, pos[b]]``.
+    Returns (B, KV, G, value_width).
     """
+    fn = paged_attention_kernel if _on_tpu() else paged_attention_gather
+    return fn(q, pools, new, layer, page_table, pos, scale=scale,
+              value_width=value_width)
+
+
+def paged_attention_kernel(q, pools, new, layer, page_table, pos, *,
+                           scale: float, value_width: int,
+                           interpret: Optional[bool] = None) -> jax.Array:
+    """:func:`paged_attention` through the Pallas kernel
+    (:func:`~repro.kernels.decode_attention.paged_attention_fwd`), in
+    interpret mode off TPU.  Each row reads only its live pages."""
     if interpret is None:
         interpret = not _on_tpu()
-    B, H, Dh = q.shape
-    KV = k_pages.shape[1]
-    o = paged_decode_attention_fwd(q.reshape(B, KV, H // KV, Dh), k_pages,
-                                   v_pages, page_table, lengths,
-                                   interpret=interpret)
-    return o.reshape(B, H, Dh)
+    return paged_attention_fwd(
+        q, tuple(pools), tuple(new), layer, page_table, pos, scale=scale,
+        value_width=value_width,
+        pages_per_block=pages_per_block(pools, pools[0].shape[3],
+                                        page_table.shape[1]),
+        buffers=RING, interpret=interpret)
 
 
-def gather_paged_kv(k_pages: jax.Array, v_pages: jax.Array,
-                    page_table: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Materialize per-request dense caches from the block pool.
-
-    k_pages/v_pages: (P, KV, page, Dh), page_table: (B, maxp)
-    → (B, KV, maxp·page, Dh).  The test oracles use this; the XLA decode
-    path reads a stacked pool in place (:func:`gather_layer_pages`) and the
-    Pallas path never materializes it.
-    """
-    P, KV, page, Dh = k_pages.shape
-    B, maxp = page_table.shape
-
-    def dense(pages):
-        g = jnp.take(pages, page_table.reshape(-1), axis=0)
-        g = g.reshape(B, maxp, KV, page, Dh).transpose(0, 2, 1, 3, 4)
-        return g.reshape(B, KV, maxp * page, Dh)
-
-    return dense(k_pages), dense(v_pages)
+def paged_attention_gather(q, pools, new, layer, page_table, pos, *,
+                           scale: float, value_width: int) -> jax.Array:
+    """:func:`paged_attention` in XLA: each pool's page list of every row
+    gathered in full (:func:`gather_layer_pages`), the new rows set in at
+    ``pos``, then a masked softmax over all ``maxp · page`` positions."""
+    B = q.shape[0]
+    rows = jnp.arange(B)
+    kv = [gather_layer_pages(p, layer, page_table).at[rows, :, pos].set(n)
+          for p, n in zip(pools, new)]
+    k, v = kv[0], kv[-1][..., :value_width]
+    s = jnp.einsum("bkgd,bktd->bkgt", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(k.shape[2])[None, :] <= pos[:, None]
+    s = jnp.where(valid[:, None, None, :], s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bkgt,bktd->bkgd", pr.astype(v.dtype), v)
 
 
 def gather_layer_pages(pool: jax.Array, layer: jax.Array,
@@ -133,8 +162,8 @@ def gather_layer_pages(pool: jax.Array, layer: jax.Array,
     """One layer's pages of every request, read in place from a stacked pool.
 
     pool: (L, P, KV, page, Dh), layer: () int32, page_table: (B, maxp)
-    → (B, KV, maxp·page, Dh), as :func:`gather_paged_kv` lays it out.  One
-    gather indexed by (layer, page): ``pool[layer]`` is never materialized.
+    → (B, KV, maxp·page, Dh), head-major as the pages are.  One gather
+    indexed by (layer, page): ``pool[layer]`` is never materialized.
     Out-of-range pages read NaN, as ``jnp.take``'s default fill mode does.
     """
     L, P, KV, page, Dh = pool.shape

@@ -344,18 +344,10 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     with the new token's k, v ``(B, KV, Dh)`` in the pools' dtype, which
     the caller writes for every layer at once after its layer scan
     (:func:`write_paged_rows`).  Attention sees the new token at ``pos``
-    all the same:
-
-    - ``xla``: one gather per pool indexed by (layer, page) fetches each
-      row's page list, the new K/V are set into that gathered copy at
-      ``pos``, then a masked softmax.
-    - ``pallas``: the kernel reads the new token from the pool, so it is
-      written into this layer's copy of the pool (page
-      ``page_table[b, pos//page]``, offset ``pos % page``) first.
-
-    Pages are per-request, so the destinations are unique across live
-    slots; idle slots all target the scratch page and their output is
-    discarded by the engine.
+    all the same: :func:`repro.kernels.ops.paged_attention` takes it as an
+    input beside the pools (on TPU the Pallas kernel, which reads each
+    row's live pages only; elsewhere the XLA gather of every row's page
+    list).
 
     Named scopes, which the profiler's op names carry: ``qkv`` (the
     projections and RoPE) and ``paged_attention`` (the page walk, masking,
@@ -364,8 +356,6 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     dt = cdtype(cfg)
     B, _, D = x.shape
     H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G = H // KV
-    page = k_pool.shape[-2]
     with jax.named_scope("qkv"):
         q = x @ p[f"{prefix}wq"].astype(dt)
         k = x @ p[f"{prefix}wk"].astype(dt)
@@ -382,30 +372,14 @@ def paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
             k = _rope_single(cfg, k, pos)
     k = k.astype(k_pool.dtype)
     v = v.astype(v_pool.dtype)
-    lengths = pos + 1
-    rows = jnp.arange(B)
 
     from repro.kernels import ops as kops
 
     with jax.named_scope("paged_attention"):
-        if cfg.attn_impl == "pallas":
-            pidx = page_table[rows, pos // page]  # (B,) destination pages
-            kp = k_pool[layer].at[pidx, :, pos % page].set(k)
-            vp = v_pool[layer].at[pidx, :, pos % page].set(v)
-            o = kops.paged_decode_attention(q, kp, vp, page_table, lengths)
-        else:
-            kc = kops.gather_layer_pages(k_pool, layer, page_table)
-            vc = kops.gather_layer_pages(v_pool, layer, page_table)
-            kc = kc.at[rows, :, pos].set(k)
-            vc = vc.at[rows, :, pos].set(v)
-            T = kc.shape[2]
-            qh = q.reshape(B, KV, G, Dh)
-            s = jnp.einsum("bkgd,bktd->bkgt", qh, kc.astype(dt),
-                           preferred_element_type=jnp.float32) / math.sqrt(Dh)
-            valid = jnp.arange(T)[None, :] < lengths[:, None]
-            s = jnp.where(valid[:, None, None, :], s, -1e30)
-            pr = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bkgt,bktd->bkgd", pr.astype(dt), vc.astype(dt))
+        o = kops.paged_attention(q.reshape(B, KV, H // KV, Dh),
+                                 (k_pool, v_pool), (k, v), layer, page_table,
+                                 pos, scale=1.0 / math.sqrt(Dh),
+                                 value_width=Dh)
         o = o.reshape(B, 1, H * Dh)
     return o @ p[f"{prefix}wo"].astype(dt), k, v
 
@@ -555,39 +529,60 @@ def mla_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     return out
 
 
+def _mla_query(cfg: ModelConfig, p: Dict[str, jax.Array], prefix: str,
+               q_nope: jax.Array, q_pe: jax.Array, width: int) -> jax.Array:
+    """The absorbed query (B, H, width): ``W_kv_b``'s key part folds into
+    the query (``q_lat = q_nope W_UK^T``, scope ``mla_absorb``), then
+    ``[q_lat ‖ q_pe ‖ 0]``, so a score is that dotted with a latent row —
+    one key shared by the heads."""
+    dt = cdtype(cfg)
+    B, H, Dn = q_nope.shape
+    R, Dv = cfg.kv_lora_rank, cfg.v_head_dim
+    w_uk = p[f"{prefix}wkv_b"].astype(dt).reshape(R, H, Dn + Dv)[..., :Dn]
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, w_uk,
+                           preferred_element_type=jnp.float32).astype(dt)
+    pad = jnp.zeros((B, H, width - R - q_pe.shape[-1]), dt)
+    return jnp.concatenate([q_lat, q_pe.astype(dt), pad], axis=-1)
+
+
+def _mla_values(cfg: ModelConfig, p: Dict[str, jax.Array], prefix: str,
+                o_lat: jax.Array) -> jax.Array:
+    """The weighted sum of latent rows' first R columns (B, H, R), taken
+    to each head's value width by ``W_UV`` (scope ``mla_absorb``).
+    Returns (B, 1, H * Dv)."""
+    dt = cdtype(cfg)
+    B, H, R = o_lat.shape
+    Dn, Dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    w_uv = p[f"{prefix}wkv_b"].astype(dt).reshape(R, H, Dn + Dv)[..., Dn:]
+    with jax.named_scope("mla_absorb"):
+        o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(dt), w_uv)
+    return o.reshape(B, 1, H * Dv)
+
+
 def _mla_absorbed(cfg: ModelConfig, p: Dict[str, jax.Array], prefix: str,
                   q_nope: jax.Array, q_pe: jax.Array, lat: jax.Array,
                   valid: jax.Array) -> jax.Array:
     """One query per row against latent rows, in absorbed form.
 
     q_nope (B, H, Dn), q_pe (B, H, Dr); lat (B, T, C): c_kv ‖ k_pe ‖ zeros,
-    C >= R + Dr; valid (B, T).  ``W_kv_b``'s key part folds into the query
-    (``q_lat = q_nope W_UK^T``), so the scores are ``[q_lat ‖ q_pe ‖ 0] ·
-    lat`` — one key shared by the heads — and the values are the rows'
-    first R columns,
-    taken back to each head's width by ``W_UV`` after the softmax.  Named
-    scopes: ``mla_absorb`` (the two absorption matmuls), ``paged_attention``
+    C >= R + Dr; valid (B, T).  Scores are :func:`_mla_query` dotted with
+    each row, the values the rows' first R columns, taken back to each
+    head's width after the softmax (:func:`_mla_values`).  Named scopes:
+    ``mla_absorb`` (the two absorption matmuls), ``paged_attention``
     (scores, masking, softmax, the weighted sum of latent rows).
     Returns (B, 1, H * Dv)."""
     dt = cdtype(cfg)
-    B, H, Dn = q_nope.shape
-    R, Dv = cfg.kv_lora_rank, cfg.v_head_dim
-    wkv_b = p[f"{prefix}wkv_b"].astype(dt).reshape(R, H, Dn + Dv)
-    with jax.named_scope("mla_absorb"):
-        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, wkv_b[..., :Dn],
-                           preferred_element_type=jnp.float32).astype(dt)
+    R = cfg.kv_lora_rank
+    qf = _mla_query(cfg, p, prefix, q_nope, q_pe, lat.shape[-1])
     with jax.named_scope("paged_attention"):
-        pad = jnp.zeros((B, H, lat.shape[-1] - R - q_pe.shape[-1]), dt)
-        qf = jnp.concatenate([q_lat, q_pe.astype(dt), pad], axis=-1)
         s = jnp.einsum("bhc,btc->bht", qf, lat.astype(dt),
                        preferred_element_type=jnp.float32)
         s = jnp.where(valid[:, None, :], s * mla_softmax_scale(cfg), -1e30)
         pr = jax.nn.softmax(s, axis=-1)
         o_lat = jnp.einsum("bht,btr->bhr", pr.astype(dt),
                            lat[..., :R].astype(dt))
-    with jax.named_scope("mla_absorb"):
-        o = jnp.einsum("bhr,rhv->bhv", o_lat, wkv_b[..., Dn:])
-    return o.reshape(B, 1, H * Dv)
+    return _mla_values(cfg, p, prefix, o_lat)
 
 
 def mla_decode_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
@@ -615,30 +610,29 @@ def mla_paged_decode_attention(cfg: ModelConfig, plan: ShardingPlan,
     """One-token MLA against the *paged* latent pool, read in place.
 
     pool: (L, P, 1, page, :func:`mla_row_width`), one latent row per token
-    and layer;
-    layer, page_table, pos as :func:`paged_decode_attention`.  One gather
-    indexed by (layer, page) fetches each row's page list, the new latent
-    row is set into that copy at ``pos``, then absorbed attention
-    (:func:`_mla_absorbed`) reads only those rows: MQA with H query heads.
-    The pool is not written: returns (out (B,1,D), the new row
-    (B, 1, C) in the pool's dtype), which the caller writes for every
-    layer at once after its layer scan (:func:`write_paged_rows`)."""
+    and layer; layer, page_table, pos as :func:`paged_decode_attention`.
+    Absorbed attention (:func:`_mla_query`, :func:`_mla_values`) is MQA
+    with H query heads over the latent rows, each row both key and value
+    (its first R columns), through :func:`repro.kernels.ops.paged_attention`
+    with the new latent row at ``pos`` given beside the pool.  The pool is
+    not written: returns (out (B,1,D), the new row (B, 1, C) in the pool's
+    dtype), which the caller writes for every layer at once after its layer
+    scan (:func:`write_paged_rows`)."""
     dt = cdtype(cfg)
-    B = x.shape[0]
-    if cfg.attn_impl != "xla":
-        raise NotImplementedError("latent attention has only the xla path")
     with jax.named_scope("qkv"):
         q_nope, q_pe, lat = _mla_project(cfg, x[:, 0], p, prefix, pos)
-    lat = lat.astype(pool.dtype)
+    lat = lat[:, None, :].astype(pool.dtype)
+    qf = _mla_query(cfg, p, prefix, q_nope, q_pe, pool.shape[-1])
 
     from repro.kernels import ops as kops
 
     with jax.named_scope("paged_attention"):
-        lc = kops.gather_layer_pages(pool, layer, page_table)[:, 0]
-        lc = lc.at[jnp.arange(B), pos].set(lat)
-        valid = jnp.arange(lc.shape[1])[None, :] < (pos + 1)[:, None]
-    o = _mla_absorbed(cfg, p, prefix, q_nope, q_pe, lc, valid)
-    return o @ p[f"{prefix}wo"].astype(dt), lat[:, None, :]
+        o_lat = kops.paged_attention(qf[:, None], (pool,), (lat,), layer,
+                                     page_table, pos,
+                                     scale=mla_softmax_scale(cfg),
+                                     value_width=cfg.kv_lora_rank)
+    o = _mla_values(cfg, p, prefix, o_lat[:, 0])
+    return o @ p[f"{prefix}wo"].astype(dt), lat
 
 
 # --------------------------------------------------------------- embedding

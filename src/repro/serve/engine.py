@@ -33,14 +33,17 @@ reproduces the seed engine for A/B benchmarks.
 
 Performance counters: ``/serve{<name>}/requests/{submitted,completed}``,
 ``/serve{<name>}/tokens/generated``, ``/serve{<name>}/step/duration``,
-``/serve{<name>}/request/{latency,first_token}``, plus the page-pool
-gauges from :mod:`repro.serve.kv_cache`.
+``/serve{<name>}/request/{latency,first_token}``,
+``/serve{<name>}/decode/pages_walked`` (paged: the pages the decode steps'
+attention walks, per row the pages holding positions up to its new token),
+plus the page-pool gauges from :mod:`repro.serve.kv_cache`.
 
 Spans (:mod:`repro.obs.trace`, category ``serve``: JAX profiler
 annotations always, ring events while the recorder is on).  On the
 decode loop: ``admit`` (prefills launched and integrated; ``admitted``,
 and ``ready_ms``, the longest KV-ready → slot-bound wait among them),
-``decode_step`` (``step_num``, ``batch``) with its
+``decode_step`` (``step_num``, ``batch``; paged: ``pages_walked``, as the
+counter counts it for the step) with its
 children ``decode_step.dispatch`` (inputs uploaded, step enqueued) and
 ``decode_step.wait`` (the blocking token readback), then ``emit``
 (tokens streamed, requests finished; ``finished``).  On the prefill
@@ -274,6 +277,12 @@ class _PagedSlots:
     def step_bookkeeping(self, active: List[int]) -> None:
         self.kv.pos[active] += 1
 
+    def pages_walked(self, active: List[int]) -> int:
+        """Pages the step's attention reads over the active rows: those
+        holding positions ``[0, pos]``, the new token's included."""
+        page = self.kv.page_size
+        return int(np.sum((self.kv.pos[active] + page) // page))
+
     def snapshot_slot(self, slot: int) -> Dict[str, Any]:
         return self.kv.snapshot_slot(slot)
 
@@ -333,6 +342,7 @@ class Engine:
         self.c_sub = reg.counter(f"/serve{{{n}}}/requests/submitted")
         self.c_done = reg.counter(f"/serve{{{n}}}/requests/completed")
         self.c_tok = reg.counter(f"/serve{{{n}}}/tokens/generated")
+        self.c_walk = reg.counter(f"/serve{{{n}}}/decode/pages_walked")
         # percentile timers: p50/p95/p99 straight off the counter API —
         # "why is p99 bad" without needing a trace at all
         self.t_step = reg.timer(f"/serve{{{n}}}/step/duration",
@@ -816,6 +826,9 @@ class Engine:
 
         step_args: Dict[str, Any] = {"step_num": self._step_count,
                                      "batch": len(active)}
+        if self.paged:
+            step_args["pages_walked"] = self.backend.pages_walked(active)
+            self.c_walk.increment(step_args["pages_walked"])
         if _trace._enabled:
             # which requests this step advanced — the analyzer charges the
             # step's duration to every request decoding in it

@@ -7,7 +7,7 @@ This module replaces it with a vLLM-style block pool: KV lives in
 ``p`` holds a request's tokens in *every* layer array), a free list hands
 pages out on demand, and each batch slot owns a page list mirrored into a
 ``(max_batch, max_pages_per_req)`` page table that the paged decode kernel
-walks (``kernels/decode_attention.py::paged_decode_attention_fwd``).
+walks (``kernels/decode_attention.py::paged_attention_fwd``).
 Memory therefore scales with *live tokens*.
 
 Page 0 is reserved as a scratch page: idle slots' page tables point at it,

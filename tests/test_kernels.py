@@ -79,50 +79,117 @@ def test_decode_attention_per_row_lengths(dtype):
                            np.asarray(o_scalar, np.float32)[0])
 
 
+# (KV, G, W, pools, value width): separate K and V pools at qwen2.5-3b's
+# heads, and one latent pool at deepseek-v2-lite's row (c_kv 512 ‖ k_pe 64,
+# padded to 640), each row both key and value
+PAGED_GEOMETRIES = {"gqa": (2, 8, 128, 2, 128), "latent": (1, 16, 640, 1, 512)}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,KV,Dh,page,maxp", [
-    (3, 4, 2, 64, 32, 8),   # GQA
-    (2, 8, 8, 32, 16, 4),   # MHA, small pages
-    (1, 8, 1, 64, 64, 4),   # MQA
-    (2, 24, 2, 128, 16, 6),  # starcoder2-3b heads: G=12, not a multiple of 8
-])
-def test_paged_decode_attention_matches_oracle(B, H, KV, Dh, page, maxp, dtype):
-    """Paged kernel walking shuffled per-request page lists == dense oracle."""
-    T = page * maxp
-    ks = jax.random.split(jax.random.PRNGKey(8), 3)
-    q = jax.random.normal(ks[0], (B, H, Dh), dtype)
-    k = jax.random.normal(ks[1], (B, T, KV, Dh), dtype)
-    v = jax.random.normal(ks[2], (B, T, KV, Dh), dtype)
-    rng = np.random.default_rng(0)
-    P = B * maxp + 3  # pool with spare pages; page 0 reserved
-    perm = 1 + rng.permutation(P - 1)[: B * maxp].reshape(B, maxp)
-    k_pages = np.zeros((P, KV, page, Dh), np.float32)
-    v_pages = np.zeros((P, KV, page, Dh), np.float32)
-    for b in range(B):
-        for j in range(maxp):
-            blk = slice(j * page, (j + 1) * page)
-            k_pages[perm[b, j]] = np.asarray(k[b, blk], np.float32).transpose(1, 0, 2)
-            v_pages[perm[b, j]] = np.asarray(v[b, blk], np.float32).transpose(1, 0, 2)
-    lens = jnp.asarray(rng.integers(1, T + 1, size=B), jnp.int32)
-    o = ops.paged_decode_attention(q, jnp.asarray(k_pages, dtype),
-                                   jnp.asarray(v_pages, dtype),
-                                   jnp.asarray(perm, jnp.int32), lens)
-    e = ref.decode_mha(q, k, v, length=lens)
-    np.testing.assert_allclose(np.asarray(o, np.float32),
-                               np.asarray(e, np.float32), atol=_tol(dtype) * 4)
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("geometry", sorted(PAGED_GEOMETRIES))
+def test_paged_decode_attention_matches_oracle(geometry, layer, dtype):
+    """The paged kernel (interpret mode) against the XLA gather path, on
+    layer 0 or L-1 of a stacked pool: rows whose pool holds 0 tokens (an
+    idle row, its table all scratch page 0), page-2, page-1, page, a block
+    and page·maxp-1 tokens, so that with the step's new row, given only as
+    an input, they attend 1, page-1, page, page+1, a block+1 and cache_len
+    positions.  Every pool slot the rows do not attend is NaN, and the
+    output is finite: the kernel reads no page past a row's fill, and masks
+    the unused tail of the pages it reads."""
+    from repro.kernels.decode_attention import paged_attention_fwd
+
+    KV, G, W, npool, vw = PAGED_GEOMETRIES[geometry]
+    L, page, maxp, ppb = 3, 16, 12, 2
+    li = 0 if layer == "first" else L - 1
+    lens = np.asarray([0, page - 2, page - 1, page, ppb * page,
+                       page * maxp - 1], np.int32)
+    B = len(lens)
+    P = B * maxp + 1
+    rng = np.random.default_rng(11)
+    perm = 1 + rng.permutation(P - 1)
+    pt = np.zeros((B, maxp), np.int32)
+    live = np.zeros((P, page), bool)
+    for b, n in enumerate(lens):
+        npg = -(-n // page)
+        pt[b, :npg] = perm[b * maxp:b * maxp + npg]
+        for t in range(n):
+            live[pt[b, t // page], t % page] = True
+    ks = jax.random.split(jax.random.PRNGKey(12), 2 + 2 * npool)
+    pools = tuple(
+        jnp.where(jnp.asarray(live)[None, :, None, :, None] | (
+            jnp.arange(L) != li)[:, None, None, None, None],
+            jax.random.normal(ks[i], (L, P, KV, page, W)), jnp.nan
+        ).astype(dtype) for i in range(npool))
+    new = tuple(jax.random.normal(ks[npool + i], (B, KV, W), dtype)
+                for i in range(npool))
+    q = jax.random.normal(ks[-1], (B, KV, G, W), dtype)
+    args = (q, pools, new, jnp.int32(li), jnp.asarray(pt), jnp.asarray(lens))
+    kw = dict(scale=1.0 / np.sqrt(W), value_width=vw)
+    o = paged_attention_fwd(*args, pages_per_block=ppb, buffers=2,
+                            interpret=True, **kw)
+    assert o.shape == (B, KV, G, vw)
+    o = np.asarray(o, np.float32)
+    assert np.isfinite(o).all()
+    clean = tuple(jnp.nan_to_num(p) for p in pools)
+    e = ops.paged_attention_gather(q, clean, new, args[3], args[4], args[5],
+                                   **kw)
+    np.testing.assert_allclose(o, np.asarray(e, np.float32),
+                               atol=_tol(dtype) * 4)
+
+
+def test_paged_kernel_ring_spans_rows_and_idle_rows():
+    """A ring deeper than a row's blocks keeps copies in flight across rows,
+    past idle rows between them: every ring depth gives the same output."""
+    from repro.kernels.decode_attention import paged_attention_fwd
+
+    KV, G, W, page, maxp, L = 2, 4, 128, 8, 6, 2
+    lens = np.asarray([0, 9, 0, 0, 40, 1, 0], np.int32)
+    B = len(lens)
+    P = B * maxp + 1
+    pt = (1 + np.arange(B * maxp, dtype=np.int32)).reshape(B, maxp)
+    pt[lens == 0] = 0
+    ks = jax.random.split(jax.random.PRNGKey(13), 5)
+    pools = tuple(jax.random.normal(k, (L, P, KV, page, W)) for k in ks[:2])
+    new = tuple(jax.random.normal(k, (B, KV, W)) for k in ks[2:4])
+    q = jax.random.normal(ks[4], (B, KV, G, W))
+    args = (q, pools, new, jnp.int32(1), jnp.asarray(pt), jnp.asarray(lens))
+    kw = dict(scale=W ** -0.5, value_width=W)
+    e = np.asarray(ops.paged_attention_gather(*args, **kw))
+    for buffers in (2, 3, 5):
+        o = paged_attention_fwd(*args, pages_per_block=2, buffers=buffers,
+                                interpret=True, **kw)
+        np.testing.assert_allclose(np.asarray(o), e, atol=1e-5,
+                                   err_msg=str(buffers))
+
+
+def test_pages_per_block_follows_the_row_width():
+    """A block copies at least ``BLOCK_BYTES`` whatever the geometry, in the
+    fewest pages that are a power of two, and spans no more than a row."""
+    def pool(KV, W, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((2, 9, KV, 16, W), dtype)
+
+    for pools in [(pool(2, 128), pool(2, 128)), (pool(1, 640),),
+                  (pool(8, 64, jnp.float32),) * 2]:
+        n = ops.pages_per_block(pools, 16, 4096)
+        row = sum(p.shape[2] * p.shape[4] * p.dtype.itemsize for p in pools)
+        assert n & (n - 1) == 0
+        assert n * 16 * row >= ops.BLOCK_BYTES > (n // 2) * 16 * row
+        assert ops.pages_per_block(pools, 16, 3) == min(n, 3)
 
 
 def test_gather_paged_kv_roundtrip():
-    P, page, KV, Dh, B, maxp = 10, 16, 2, 32, 2, 4
-    ks = jax.random.split(jax.random.PRNGKey(10), 2)
-    k_pages = jax.random.normal(ks[0], (P, KV, page, Dh))
-    v_pages = jax.random.normal(ks[1], (P, KV, page, Dh))
+    """The XLA path's gather reads one layer of a stacked pool in place,
+    head-major: row b's j-th page lands at tokens [j·page, (j+1)·page)."""
+    L, P, page, KV, Dh, B, maxp = 3, 10, 16, 2, 32, 2, 4
+    pool = jax.random.normal(jax.random.PRNGKey(10), (L, P, KV, page, Dh))
     pt = jnp.asarray([[1, 3, 5, 7], [2, 4, 6, 8]], jnp.int32)
-    kg, vg = ops.gather_paged_kv(k_pages, v_pages, pt)
-    assert kg.shape == (B, KV, maxp * page, Dh)
-    np.testing.assert_array_equal(np.asarray(kg[0, :, :page]), np.asarray(k_pages[1]))
-    np.testing.assert_array_equal(np.asarray(vg[1, :, page:2 * page]),
-                                  np.asarray(v_pages[4]))
+    g = ops.gather_layer_pages(pool, jnp.int32(2), pt)
+    assert g.shape == (B, KV, maxp * page, Dh)
+    np.testing.assert_array_equal(np.asarray(g[0, :, :page]),
+                                  np.asarray(pool[2, 1]))
+    np.testing.assert_array_equal(np.asarray(g[1, :, page:2 * page]),
+                                  np.asarray(pool[2, 4]))
 
 
 @pytest.mark.parametrize("B,S,H,P,G,N,chunk", [

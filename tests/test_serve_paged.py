@@ -171,6 +171,25 @@ def test_seed_parity_mode_matches_greedy(rt, served):
     assert eng.submit(p).get(timeout=300) == _manual_greedy(model, params, p, 4)
 
 
+def test_pages_walked_counts_the_pages_up_to_each_new_token(rt, served):
+    """Each decode step adds, per decoding row, the pages holding positions
+    up to its new token: prompts of 14 and 3 tokens, 4 decode steps each
+    (after the prefill's token), 16-token pages, walk 1+1+2+2 and 1+1+1+1
+    pages."""
+    from repro.core import counters
+
+    cfg, model, params = served
+    name = "engine#walk"
+    eng = Engine(model, params, ServeConfig(max_batch=2, cache_len=64,
+                                            max_new_tokens=4, name=name))
+    walked = f"/serve{{{name}}}/decode/pages_walked"
+    before = counters.get_value(walked)
+    futs = [eng.submit(list(range(1, 15)), max_new=4),
+            eng.submit([7, 8, 9], max_new=4)]
+    assert [len(f.get(timeout=300)) for f in futs] == [5, 5]
+    assert counters.get_value(walked) - before == 6 + 4
+
+
 def test_decode_step_compiles_once(rt, served):
     """Admission churn (different prompt lengths, sampling params, EOS
     timings) never changes decode-step shapes: one compile, total."""
@@ -186,40 +205,53 @@ def test_decode_step_compiles_once(rt, served):
     assert eng.decode_compile_count() == 1
 
 
-def _oracle_attention(cfg, plan, h, lp, kp, vp, pt, pos):
-    """Write the token into one layer's pool, then attend over the pages:
-    ``ops.gather_paged_kv`` and a masked softmax (``xla``), or the paged
-    kernel on the written pool (``pallas``: its online softmax rounds
-    differently from a dense one)."""
+# the kernel path against the gather oracle in float32, in stds of what is
+# compared: an online and a dense softmax differ by float32 rounding (in
+# bf16 one rounding of the attention output differs, and random weights
+# with top-k routing carry it to a few hundredths of a std in the logits)
+KERNEL_ATOL = 1e-4
+
+
+def _oracle_attention(cfg, plan, h, lp, pools, pt, pos):
+    """Write the token into one layer's pools (K and V, or the latent),
+    then attend over the pages: each row's page list gathered in full and a
+    masked softmax (under MLA in absorbed form)."""
     from repro.kernels import ops
     from repro.models import layers as Lx
 
     dt = jnp.dtype(cfg.dtype)
     B = h.shape[0]
-    H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q, k, v = (h @ lp[w].astype(dt) for w in ("wq", "wk", "wv"))
-    if cfg.qkv_bias:
-        q, k, v = (a + lp[b].astype(dt) for a, b in
-                   zip((q, k, v), ("bq", "bk", "bv")))
-    q, k, v = q.reshape(B, H, Dh), k.reshape(B, KV, Dh), v.reshape(B, KV, Dh)
-    if cfg.rope:
-        q, k = Lx._rope_single(cfg, q, pos), Lx._rope_single(cfg, k, pos)
-    page = kp.shape[2]
-    pidx = pt[jnp.arange(B), pos // page]
-    kp = kp.at[pidx, :, pos % page].set(k.astype(kp.dtype))
-    vp = vp.at[pidx, :, pos % page].set(v.astype(vp.dtype))
-    if cfg.attn_impl == "pallas":
-        o = ops.paged_decode_attention(q, kp, vp, pt, pos + 1)
+    if cfg.mla:
+        q_nope, q_pe, lat = Lx._mla_project(cfg, h[:, 0], lp, "", pos)
+        rows = (lat[:, None],)
     else:
-        kc, vc = ops.gather_paged_kv(kp, vp, pt)
-        T = kc.shape[2]
+        H, KV, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        q, k, v = (h @ lp[w].astype(dt) for w in ("wq", "wk", "wv"))
+        if cfg.qkv_bias:
+            q, k, v = (a + lp[b].astype(dt) for a, b in
+                       zip((q, k, v), ("bq", "bk", "bv")))
+        q, k, v = q.reshape(B, H, Dh), k.reshape(B, KV, Dh), v.reshape(B, KV, Dh)
+        if cfg.rope:
+            q, k = Lx._rope_single(cfg, q, pos), Lx._rope_single(cfg, k, pos)
+        rows = (k, v)
+    page = pools[0].shape[2]
+    pidx = pt[jnp.arange(B), pos // page]
+    pools = tuple(p.at[pidx, :, pos % page].set(r.astype(p.dtype))
+                  for p, r in zip(pools, rows))
+    pages = [ops.gather_layer_pages(p[None], jnp.int32(0), pt) for p in pools]
+    T = pages[0].shape[2]
+    valid = jnp.arange(T)[None, :] < (pos + 1)[:, None]
+    if cfg.mla:
+        o = Lx._mla_absorbed(cfg, lp, "", q_nope, q_pe, pages[0][:, 0], valid)
+    else:
+        kc, vc = pages
         s = jnp.einsum("bkgd,bktd->bkgt", q.reshape(B, KV, H // KV, Dh),
                        kc.astype(dt), preferred_element_type=jnp.float32)
-        s = s / np.sqrt(Dh)
-        valid = jnp.arange(T)[None, :] < (pos + 1)[:, None]
+        s = s * (1.0 / np.sqrt(Dh))
         pr = jax.nn.softmax(jnp.where(valid[:, None, None, :], s, -1e30), -1)
         o = jnp.einsum("bkgt,bktd->bkgd", pr.astype(dt), vc.astype(dt))
-    return o.reshape(B, 1, H * Dh) @ lp["wo"].astype(dt), kp, vp
+        o = o.reshape(B, 1, H * Dh)
+    return o @ lp["wo"].astype(dt), pools
 
 
 def _oracle_decode(cfg, plan, params, cache, token):
@@ -227,28 +259,30 @@ def _oracle_decode(cfg, plan, params, cache, token):
     as ``xs``/``ys``, every layer writing its token before it attends."""
     from repro.models import layers as Lx
     from repro.models.moe import moe_ffn
+    from repro.models.transformer import _groups, cache_keys
 
     pt, pos = cache["page_table"], cache["pos"]
     out = dict(cache, pos=pos + 1)
     x = Lx.embed(cfg, plan, params["tok_embed"], token)
-    groups = [("d0/", "k0", "v0", False)] if cfg.first_dense else []
-    groups.append(("blk/", "k", "v", cfg.is_moe))
-    for prefix, kk, vk, moe_layer in groups:
+    for prefix, _ in _groups(cfg):
+        keys = cache_keys(cfg, prefix)
+        moe_layer = cfg.is_moe and prefix == "blk/"
         stacked = {n[len(prefix):]: a for n, a in params.items()
                    if n.startswith(prefix)}
 
         def layer(x, xs):
-            lp, kp, vp = xs
+            lp, pools = xs
             h = Lx.norm(cfg, x, lp["ln1"])
-            h, kp, vp = _oracle_attention(cfg, plan, h, lp, kp, vp, pt, pos)
+            h, pools = _oracle_attention(cfg, plan, h, lp, pools, pt, pos)
             x = x + h
             h = Lx.norm(cfg, x, lp["ln2"])
             ffn = (moe_ffn(cfg, plan, h, lp, "moe/", serve=True)[0] if moe_layer
                    else Lx.mlp(cfg, plan, h, lp, ""))
-            return x + ffn, (kp, vp)
+            return x + ffn, pools
 
-        x, (out[kk], out[vk]) = jax.lax.scan(layer, x,
-                                             (stacked, cache[kk], cache[vk]))
+        x, pools = jax.lax.scan(layer, x,
+                                (stacked, tuple(cache[k] for k in keys)))
+        out.update(zip(keys, pools))
     x = Lx.norm(cfg, x, params["final_ln"])
     table = params["tok_embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = Lx.unembed(cfg, plan, x, table, transpose=cfg.tie_embeddings)
@@ -256,29 +290,40 @@ def _oracle_decode(cfg, plan, params, cache, token):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("arch", ["qwen25_3b", "deepseek_moe_16b"])
-def test_decode_paged_matches_write_then_read_oracle(arch, impl):
-    """The decode step reads the pools in place and writes the step's K/V
-    once after the layer scan (``xla``), or writes each layer first
-    (``pallas``); over 3 steps its logits for live rows and its pools equal,
-    to the bit, an oracle that writes each layer's token before attending.
-    Live rows start at the first and the last offset of a page; idle slots
-    sit at 0 with every page on scratch page 0, as the engine leaves them.
-    Their logits and page 0 are left out: under the oracle's order an idle
-    row reads the other idle rows' tokens of the same layer from page 0."""
+@pytest.mark.parametrize("arch", ["qwen25_3b", "deepseek_moe_16b",
+                                  "deepseek_v2_lite"])
+def test_decode_paged_matches_write_then_read_oracle(arch, impl, monkeypatch):
+    """The decode step reads the pools in place, attends to the step's new
+    rows as inputs and writes them once after the layer scan; over 3 steps
+    its logits for live rows and its pools equal an oracle that writes each
+    layer's token before attending over the gathered pages.  ``xla`` is the
+    serving path off TPU, the same gather: equal to the bit.  ``pallas`` is
+    the TPU path, the paged kernel (here in interpret mode), compared in
+    float32 to ``KERNEL_ATOL`` of their spread (an online softmax rounds
+    otherwise than a dense one).  Live rows start at the first and the last
+    offset of a page; idle slots sit at 0 with every page on scratch page
+    0, as the engine leaves them.  Their logits and page 0 are left out:
+    under the oracle's order an idle row reads the other idle rows' tokens
+    of the same layer from page 0."""
     import dataclasses
 
-    cfg = dataclasses.replace(get_config(arch, smoke=True), attn_impl=impl)
+    from repro.kernels import ops
+
+    cfg = get_config(arch, smoke=True)
+    if impl == "pallas":
+        monkeypatch.setattr(ops, "paged_attention", ops.paged_attention_kernel)
+        cfg = dataclasses.replace(cfg, dtype="float32")
     plan = get_plan("serve")
     model = build_model(cfg, plan)
     params = model.init(jax.random.PRNGKey(3))
     page, maxp, B = 8, 4, 4
     P = 2 * maxp + 2
     specs = model.paged_cache_specs(P, page, B, maxp)
-    cache = {n: jax.random.normal(jax.random.PRNGKey(i), s.shape, s.dtype)
-             for i, (n, s) in enumerate(specs.items()) if n in
-             ("k", "v", "k0", "v0")}
-    assert ("k0" in cache) == (cfg.first_dense > 0)
+    pool_keys = [n for n in specs if n not in ("page_table", "pos")]
+    cache = {n: jax.random.normal(jax.random.PRNGKey(i), specs[n].shape,
+                                  specs[n].dtype)
+             for i, n in enumerate(pool_keys)}
+    assert (("k0" in cache or "ckv0" in cache) == (cfg.first_dense > 0))
     rng = np.random.default_rng(0)
     pages = 1 + rng.permutation(P - 1)[:2 * maxp].reshape(2, maxp)
     pt = np.zeros((B, maxp), np.int32)
@@ -289,19 +334,25 @@ def test_decode_paged_matches_write_then_read_oracle(arch, impl):
     cache["pos"] = jnp.asarray(pos0)
     tok = jnp.asarray([[5], [77], [3], [9]], jnp.int32)
 
+    def same(g, w, what):
+        if impl == "xla":
+            np.testing.assert_array_equal(g, w, err_msg=what)
+        else:
+            np.testing.assert_allclose(g, w, rtol=0, atol=KERNEL_ATOL * w.std(),
+                                       err_msg=what)
+
     step = jax.jit(model.decode_paged)
     oracle = jax.jit(lambda p, c, t: _oracle_decode(cfg, plan, p, c, t))
     got, want = cache, dict(cache)
     for _ in range(3):
         lg, got = step(params, got, tok)
         lw, want = oracle(params, want, tok)
-        np.testing.assert_array_equal(np.asarray(lg)[live],
-                                      np.asarray(lw)[live])
+        same(np.asarray(lg)[live], np.asarray(lw)[live], "logits")
         for n in cache:
             g, w = (np.asarray(c[n], np.float32) for c in (got, want))
             if g.ndim == 5:  # a pool: every page but the scratch page
                 g, w = g[:, 1:], w[:, 1:]
-            np.testing.assert_array_equal(g, w, err_msg=n)
+            same(g, w, n)
         tok = jnp.argmax(lg, -1).astype(jnp.int32)[:, None]
         # the engine re-uploads the idle slots' fill, 0, every step
         got = dict(got, pos=jnp.where(live, got["pos"], 0))

@@ -20,11 +20,12 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.dist.plan import get_plan
-from repro.kernels.decode_attention import (decode_attention_fwd,
-                                            paged_decode_attention_fwd)
+from repro.kernels import ops
+from repro.kernels.decode_attention import decode_attention_fwd
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rglru_scan import rglru_scan_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
+from repro.models.layers import mla_row_width
 from repro.models.model import build_model
 
 # (num_heads, num_kv_heads, head_dim) of the serving configs
@@ -50,16 +51,37 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def _paged_geometry(cfg):
+    """(KV, G, W, pools, value width) of a config's paged decode: K and V
+    pools of its heads, or under MLA one latent pool of rows."""
+    if cfg.mla:
+        return 1, cfg.num_heads, mla_row_width(cfg), 1, cfg.kv_lora_rank
+    KV = cfg.num_kv_heads
+    return KV, cfg.num_heads // KV, cfg.head_dim, 2, cfg.head_dim
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS) + ["deepseek_v2_lite"])
 def test_paged_decode_kernel_compiles(one_chip, arch):
-    H, KV, Dh = WIDTHS[arch]
-    B, page, maxp, P = 8, 16, 128, 1025  # max_batch 8, cache_len 2048
+    """The paged kernel as the TPU path runs it, on stacked pools read in
+    place: max_batch 24, cache_len 4096, every row's pages in the pool."""
+    KV, G, W, npool, vw = _paged_geometry(get_config(arch))
+    B, page, maxp, L = 24, 16, 256, 3
+    P = B * maxp + 1
     bf = jnp.bfloat16
-    hlo = _compile(paged_decode_attention_fwd, one_chip,
-                   ((B, KV, H // KV, Dh), bf), ((P, KV, page, Dh), bf),
-                   ((P, KV, page, Dh), bf), ((B, maxp), jnp.int32),
-                   ((B,), jnp.int32))
-    assert "tpu_custom_call" in hlo
+
+    def attend(q, pools, new, layer, pt, pos):
+        return ops.paged_attention_kernel(q, pools, new, layer, pt, pos,
+                                          scale=0.1, value_width=vw,
+                                          interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((B, KV, G, W), bf), ((L, P, KV, page, W), bf), ((B, KV, W), bf),
+        ((), jnp.int32), ((B, maxp), jnp.int32), ((B,), jnp.int32))]
+    q, pool, new, *rest = args
+    compiled = jax.jit(attend).lower(q, (pool,) * npool, (new,) * npool,
+                                     *rest).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < B * KV * G * W * 4
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
@@ -165,6 +187,44 @@ def test_paged_decode_step_reads_pools_in_place(one_chip):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < gathered_bytes + slice_bytes, temp
     large = _large_ops(hlo, slice_bytes)
+    writes = _donated_outputs(hlo) & set(large)
+    assert len(writes) == 2, large  # one in-place write per pool
+    assert set(large) == writes, large
+
+
+def test_paged_latent_decode_step_runs_the_kernel_in_place(one_chip,
+                                                         monkeypatch):
+    """The serving decode program as the TPU compiles it (the paged kernel)
+    at deepseek_v2_lite's widths, 2 layers (the dense one and one holding 8
+    experts), batch 24, 256 pages a row, 6,145 pages, cache donated: the
+    latent pools are read in place.  The temporaries stay under one
+    layer's page list of one pool, so nothing gathers the pages, slices
+    ``pool[layer]`` or selects over a gathered copy; and the only ops that
+    yield as many bytes as one layer's slice of a pool are the step's
+    writes, one per pool, each an output aliased to its donated pool."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("deepseek_v2_lite"), num_layers=2,
+                              param_dtype="bfloat16", expert_shard=(0, 8))
+    model = build_model(cfg, get_plan("serve"))
+    B, maxp, page = 24, 256, 16
+    P, C = B * maxp + 1, mla_row_width(cfg)
+
+    def on_chip(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = {n: on_chip(s) for n, s in model.abstract_params().items()}
+    cache = {n: on_chip(s) for n, s in
+             model.paged_cache_specs(P, page, B, maxp).items()}
+    token = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.decode_paged, donate_argnums=(1,)).lower(
+        params, cache, token).compile()
+    hlo = compiled.as_text()
+
+    assert "tpu_custom_call" in hlo
+    page_list_bytes = B * maxp * page * C * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < page_list_bytes, temp
+    large = _large_ops(hlo, P * page * C * 2)
     writes = _donated_outputs(hlo) & set(large)
     assert len(writes) == 2, large  # one in-place write per pool
     assert set(large) == writes, large
