@@ -4,51 +4,13 @@
 
 PY ?= python
 
-.PHONY: verify test bench bench-compare bench-serve bench-algorithms \
-	bench-net bench-net-check bench-container bench-obs bench-obs-check \
-	bench-fleet bench-fleet-check smoke
+.PHONY: verify test smoke
 
 verify:
 	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m pytest -x -q
 
 test:
 	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m pytest -q
-
-bench:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.run
-
-# Regression gate: re-run the fast suites and band-check their headline
-# metrics against the committed results/ baselines (benchmarks/run.py GATES).
-bench-compare:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.run \
-		--suites algorithms,obs --compare results/
-
-bench-serve:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_serve
-
-bench-algorithms:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_algorithms
-
-bench-net:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_net
-
-bench-net-check:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_net --check
-
-bench-container:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_container
-
-bench-obs:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_obs
-
-bench-obs-check:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_obs --check
-
-bench-fleet:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_fleet
-
-bench-fleet-check:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m benchmarks.bench_fleet --check
 
 smoke:
 	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m repro.launch.train \

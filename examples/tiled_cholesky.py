@@ -13,13 +13,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import sys
-from pathlib import Path
-
-sys.path.insert(0, str(Path(__file__).resolve().parents[1]))  # for benchmarks/
-
 import repro.core as core
-from benchmarks.bench_cholesky import tiled_cholesky
+from repro.core.dataflow import dataflow
+
+
+def tiled_cholesky(A: np.ndarray, tile: int):
+    """Right-looking blocked Cholesky as a dataflow DAG of jitted tile ops."""
+    n = A.shape[0] // tile
+    potrf = jax.jit(jnp.linalg.cholesky)
+    trsm = jax.jit(lambda L, B: jax.scipy.linalg.solve_triangular(
+        L, B.T, lower=True).T)
+    syrk = jax.jit(lambda C, L: C - L @ L.T)
+    gemm = jax.jit(lambda C, A_, B_: C - A_ @ B_.T)
+
+    tiles = {(i, j): core.make_ready_future(
+        jnp.asarray(A[i * tile:(i + 1) * tile, j * tile:(j + 1) * tile]))
+        for i in range(n) for j in range(n) if j <= i}
+
+    for k in range(n):
+        tiles[(k, k)] = dataflow(potrf, tiles[(k, k)])
+        for i in range(k + 1, n):
+            tiles[(i, k)] = dataflow(trsm, tiles[(k, k)], tiles[(i, k)])
+        for i in range(k + 1, n):
+            tiles[(i, i)] = dataflow(syrk, tiles[(i, i)], tiles[(i, k)])
+            for j in range(k + 1, i):
+                tiles[(i, j)] = dataflow(gemm, tiles[(i, j)], tiles[(i, k)],
+                                         tiles[(j, k)])
+    out = np.zeros_like(A)
+    for (i, j), fut in tiles.items():
+        out[i * tile:(i + 1) * tile, j * tile:(j + 1) * tile] = np.asarray(fut.get())
+    return np.tril(out)
 
 
 def main() -> None:

@@ -55,8 +55,7 @@ any window (``FleetView.pool_utilization``), which is what adaptive
 policies predicate on — the instantaneous fraction counters are the
 since-birth summary an operator reads.  ``accounting=False`` disables
 the transition bookkeeping (and skips registering the counters) for A/B
-overhead measurement; the measured cost is gated ≤2% on the algorithms
-bench (``BENCH_algorithms.json: sched_accounting``).
+overhead measurement.
 
 Outside :mod:`repro.core`, tasks reach a pool exclusively through the
 executors of :mod:`repro.core.executor` (``Runtime.get_executor``); the

@@ -4,7 +4,7 @@ Reproduces the paper's HPX.Compute claim — "porting STREAM to the
 single-source abstraction results in no loss of performance" — at the
 Pallas layer: the kernel is pure bandwidth, so parity with the native jnp
 expression (one fused multiply-add over HBM) is the pass criterion
-(benchmarks/bench_stream.py).
+(``tests/test_kernels.py::test_stream_triad_sweep`` checks the parity).
 """
 
 from __future__ import annotations

@@ -40,10 +40,6 @@ def main() -> None:
                          "and runs one engine per locality")
     # cache layer
     ap.add_argument("--page-size", type=int, default=16)
-    ap.add_argument("--no-paged", action="store_true",
-                    help="dense per-slot cache instead of the block pool")
-    ap.add_argument("--no-pipeline", action="store_true",
-                    help="seed-style inline prefill (the barrier baseline)")
     # sampling / streaming
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--top-k", type=int, default=0)
@@ -115,16 +111,15 @@ def main() -> None:
     compile_cache.enable()
 
     # Resource partition: decode continuations on "default", prefill on its
-    # own pool, host I/O (logging/ckpt/parcel pumps) on "io" — capacity goes
+    # own pool (sized as the engine sizes it when it partitions the pool
+    # itself), host I/O (logging/ckpt/parcel pumps) on "io" — capacity goes
     # where the work is, and I/O can never stall a decode step.
     pools = {"default": args.workers, "prefill": 2, "io": 1}
     core.init(pools=pools)
     cfg = get_config(args.arch, smoke=args.smoke)
 
     scfg = ServeConfig(max_batch=args.max_batch, cache_len=args.cache_len,
-                       max_new_tokens=args.max_new, page_size=args.page_size,
-                       paged=not args.no_paged,
-                       pipeline_admission=not args.no_pipeline)
+                       max_new_tokens=args.max_new, page_size=args.page_size)
     net = None
     if args.localities > 1:
         from repro import net as rnet

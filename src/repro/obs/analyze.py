@@ -2,8 +2,9 @@
 
 Makes merged Chrome traces consumable without a browser:
 
-    # where did the fleet's time go, per SLO tier
-    python -m repro.obs.analyze results/obs_trace_demo.json --slow-report
+    # where did the fleet's time go, per SLO tier (trace.json exported by
+    # python -m repro.launch.serve ... --trace trace.json)
+    python -m repro.obs.analyze trace.json --slow-report
 
     # one request's tiled admission->finish timeline
     python -m repro.obs.analyze trace.json --critical-path r0:3

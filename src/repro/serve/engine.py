@@ -1,16 +1,15 @@
 """Serving engine: paged-KV continuous batching on the AMT runtime.
 
-The seed engine ran prefill *inside* the decode loop — a bulk-synchronous
-barrier: every admission stalled every in-flight decode.  This version is
-task-pipelined, HPX-style:
+Task-pipelined, HPX-style: no prefill runs inside the decode loop, so an
+admission never stalls the in-flight decodes.
 
 1. **Admission** — ``submit`` enqueues the request and a prefill task is
    posted through a ``PriorityExecutor`` over the dedicated ``prefill``
    pool of the resource partitioner (falling back to the decode pool at
    ``PRIORITY_HIGH`` on unpartitioned runtimes), so admissions never steal
-   decode-continuation slots.  Prompts are right-padded to static *buckets*
-   so admission never recompiles; ``valid_len`` keeps logits/cache
-   positions exact.  Finished prefills land in a ready queue.
+   decode-continuation slots.  Paged families right-pad prompts to static
+   *buckets* so admission never recompiles; ``valid_len`` keeps
+   logits/cache positions exact.  Finished prefills land in a ready queue.
 2. **Decode continuation chain** — each step is a scheduler task that
    integrates ready prefills into free slots (paged: scatter the prefill
    KV into block-pool pages; dense fallback: migrate into the slot row),
@@ -25,11 +24,10 @@ per-slot parameter vectors — admission churn never changes shapes, so after
 warmup the decode step never recompiles.  ``temperature=0`` rows reduce to
 exact argmax (greedy equivalence).
 
-Cache backends: block-pool paged KV (:mod:`repro.serve.kv_cache`) for
-KV-cache families (dense/moe/vlm) — memory ∝ live tokens, per-row lengths
-in the kernel — and the seed's dense per-slot cache for recurrent families
-(ssm/hybrid/encdec).  ``ServeConfig(paged=False, pipeline_admission=False)``
-reproduces the seed engine for A/B benchmarks.
+Cache backends, chosen by the model family: block-pool paged KV
+(:mod:`repro.serve.kv_cache`) for KV-cache families (dense/moe/vlm) —
+memory ∝ live tokens, per-row lengths in the kernel — and a dense per-slot
+cache for recurrent families (ssm/hybrid/encdec).
 
 Performance counters: ``/serve{<name>}/requests/{submitted,completed}``,
 ``/serve{<name>}/tokens/generated``, ``/serve{<name>}/step/duration``,
@@ -73,6 +71,18 @@ from repro.obs import trace as _trace
 
 _NEG = -1e30
 
+# Admission: prefills in flight beyond free slots, and the blocking queue
+# wait of a drained decode loop (no hot-spin).
+_PREFILL_OVERSUB = 2
+_IDLE_TIMEOUT = 0.05
+# Resource partitioning: the decode continuation chain runs on
+# ``_DECODE_POOL``; prefill tasks go to a PriorityExecutor over a dedicated
+# ``_PREFILL_POOL`` (auto-partitioned with ``_PREFILL_WORKERS`` workers; on
+# a runtime without one they fall back to the decode pool at PRIORITY_HIGH).
+_DECODE_POOL = "default"
+_PREFILL_POOL = "prefill"
+_PREFILL_WORKERS = 2
+
 
 @dataclass(frozen=True)
 class SamplingParams:
@@ -92,22 +102,7 @@ class ServeConfig:
     cache_len: int = 256
     max_new_tokens: int = 32
     eos_id: int = -1  # -1: never stops early
-    # paged cache layer
-    paged: bool = True       # block-pool cache (KV families); dense fallback
-    page_size: int = 16
-    num_pages: int = 0       # 0 → auto: every slot can reach cache_len
-    # engine pipeline
-    pipeline_admission: bool = True  # False → seed-style inline prefill barrier
-    prefill_oversub: int = 2  # prefills in flight beyond free slots
-    idle_timeout: float = 0.05  # blocking queue wait when drained (no hot-spin)
-    # resource partitioning: the decode continuation chain runs on
-    # ``decode_pool``; prefill tasks go to a PriorityExecutor over a
-    # dedicated ``prefill_pool`` (auto-partitioned with ``prefill_workers``
-    # workers; on a runtime without one they fall back to decode_pool at
-    # PRIORITY_HIGH — the pre-partitioner behavior).
-    decode_pool: str = "default"
-    prefill_pool: str = "prefill"
-    prefill_workers: int = 2
+    page_size: int = 16  # paged cache: tokens per block-pool page
     # Counters are get-or-create by name: same-named engines *share* them
     # (the seed's observability contract).  Replicas behind a Router must
     # use distinct names or load() merges — Router.replicate does this.
@@ -197,7 +192,8 @@ def _sample_host(logits: np.ndarray, sp: SamplingParams,
 
 # --------------------------------------------------------------- backends
 class _DenseSlots:
-    """Seed-style dense per-slot cache: (L, max_batch, cache_len, KV, Dh)."""
+    """Dense per-slot cache, (L, max_batch, cache_len, ...): the backend of
+    the families without paged support (ssm/hybrid/encdec)."""
 
     def __init__(self, model: Model, scfg: ServeConfig,
                  extra: Dict[str, Any]):
@@ -236,12 +232,12 @@ class _DenseSlots:
     def snapshot_slot(self, slot: int) -> Dict[str, Any]:
         raise NotImplementedError(
             "dense cache backend does not support live migration — "
-            "use the paged backend (ServeConfig.paged=True)")
+            "only paged (KV-cache) families migrate")
 
     def restore_slot(self, slot: int, snap: Dict[str, Any]) -> bool:
         raise NotImplementedError(
             "dense cache backend does not support live migration — "
-            "use the paged backend (ServeConfig.paged=True)")
+            "only paged (KV-cache) families migrate")
 
 
 class _PagedSlots:
@@ -253,9 +249,9 @@ class _PagedSlots:
         page = scfg.page_size
         assert scfg.cache_len % page == 0, (scfg.cache_len, page)
         maxp = scfg.cache_len // page
-        num_pages = scfg.num_pages or (scfg.max_batch * maxp + 1)
-        self.kv = PagedKVCache(model, num_pages=num_pages, page_size=page,
-                               max_batch=scfg.max_batch,
+        # every slot can reach cache_len, plus the scratch page
+        self.kv = PagedKVCache(model, num_pages=scfg.max_batch * maxp + 1,
+                               page_size=page, max_batch=scfg.max_batch,
                                max_pages_per_req=maxp, name=scfg.name)
         self.gid = self.kv.gid
 
@@ -299,13 +295,11 @@ class Engine:
         self.scfg = scfg
         self.extra = extra_inputs or {}
         B = scfg.max_batch
-        self.paged = scfg.paged and model.supports_paged
+        # paged families also prefill in static-shape buckets (valid_len);
+        # the dense-slot families prefill at the prompt's exact length
+        self.paged = model.supports_paged
         self.backend = (_PagedSlots(model, scfg) if self.paged
                         else _DenseSlots(model, scfg, self.extra))
-        # bucketed (static-shape) prefill needs valid_len (transformer fams)
-        # and belongs to the pipelined stack — the seed-parity baseline keeps
-        # the seed's exact-length prefill (and its per-length recompiles)
-        self._bucketed = model.supports_paged and scfg.pipeline_admission
         self.slots: List[Optional[_Request]] = [None] * B
         self._tokens = np.zeros((B, 1), np.int32)
         self._temp = np.zeros((B,), np.float32)
@@ -330,12 +324,12 @@ class Engine:
         # only path to scheduler pools.  Pool names resolve lazily at
         # submission, so engines survive runtime restarts.
         rt = current_runtime()
-        if rt is not None and scfg.pipeline_admission:
-            rt.add_pool(scfg.prefill_pool, scfg.prefill_workers)
+        if rt is not None:
+            rt.add_pool(_PREFILL_POOL, _PREFILL_WORKERS)
         self._loop_exec = _executor.get_executor(
-            scfg.decode_pool, fallback=scfg.decode_pool)  # → runtime default
+            _DECODE_POOL, fallback=_DECODE_POOL)  # → runtime default
         self._prefill_exec = _executor.get_executor(
-            scfg.prefill_pool, priority=PRIORITY_HIGH, fallback=scfg.decode_pool)
+            _PREFILL_POOL, priority=PRIORITY_HIGH, fallback=_DECODE_POOL)
 
         reg = _counters.default()
         n = scfg.name
@@ -373,8 +367,8 @@ class Engine:
         return nxt, new_cache
 
     def decode_compile_count(self) -> int:
-        """Distinct decode-step compilations (bench asserts this stays at 1
-        after warmup — admission churn must never change step shapes)."""
+        """Distinct decode-step compilations (stays at 1 after warmup —
+        admission churn must never change step shapes)."""
         return int(self._decode._cache_size())
 
     # ------------------------------------------------------------------ api
@@ -589,7 +583,7 @@ class Engine:
     def _run_prefill(self, req: _Request):
         """Compute the request's KV cache + first token (any thread)."""
         n = len(req.prompt)
-        bucket = self._bucket_for(n) if self._bucketed else n
+        bucket = self._bucket_for(n) if self.paged else n
         with _trace.span("prefill", "serve", rid=req.rid, req=req.tag,
                          prompt_len=n, bucket=bucket,
                          queued_ms=(time.perf_counter() - req.submit_t) * 1e3):
@@ -605,14 +599,13 @@ class Engine:
             raise ValueError(f"vlm prompt needs ≥ {self.model.cfg.n_patches} "
                              f"tokens, got {len(prompt)}")
         pextra = {k: v for k, v in self.extra.items() if k != "enc_len"}
-        if self._bucketed:
+        if self.paged:
             assert len(prompt) <= bucket, (len(prompt), self.scfg.cache_len)
             toks = np.zeros((1, bucket), np.int32)
             toks[0, : len(prompt)] = prompt
             pin = {"tokens": jnp.asarray(toks), **pextra}
-            cache_len = bucket if self.paged else self.scfg.cache_len
             logits, cache1 = self._prefill(
-                self.params, pin, cache_len=cache_len,
+                self.params, pin, cache_len=bucket,
                 valid_len=jnp.asarray([len(prompt)], jnp.int32))
         else:
             pin = {"tokens": jnp.asarray(prompt, jnp.int32)[None, :], **pextra}
@@ -652,7 +645,7 @@ class Engine:
             with self._lock:
                 active = sum(s is not None for s in self.slots)
                 budget = (self.scfg.max_batch - active
-                          + self.scfg.prefill_oversub
+                          + _PREFILL_OVERSUB
                           - self._inflight_prefills - len(self._ready))
             if budget <= 0:
                 return
@@ -697,8 +690,7 @@ class Engine:
 
     def _bind_slot(self, i: int, req: _Request, tok0: int) -> float:
         """Occupy slot ``i`` with an admitted request and emit its prefill
-        token (shared by the pipelined and inline admission paths).
-        Returns how long the request's KV waited for the slot (s)."""
+        token.  Returns how long the request's KV waited for the slot (s)."""
         waited = time.perf_counter() - req.ready_t
         self.slots[i] = req
         self._tokens[i, 0] = tok0
@@ -744,32 +736,6 @@ class Engine:
                 return waits
             waits.append(self._bind_slot(free, req, tok0))
 
-    def _admit_inline(self) -> List[float]:
-        """Seed-style admission: prefill runs inside the decode loop (the
-        barrier).  Kept as the A/B baseline (pipeline_admission=False)."""
-        waits = self._integrate_ready()  # admit-failure retries parked in _ready
-        for i, slot in enumerate(self.slots):
-            if slot is not None:
-                continue
-            try:
-                req = self._queue.get_nowait()
-            except queue.Empty:
-                return waits
-            try:
-                req2, cache1, length, tok0 = self._run_prefill(req)
-            except BaseException as e:  # noqa: BLE001 — fail the one request
-                if req.stream is not None:
-                    req.stream.close()
-                self.c_done.increment()
-                req.promise.set_exception(e)
-                continue
-            if not self.backend.admit(i, cache1, length):
-                with self._lock:
-                    self._ready.insert(0, (req2, cache1, length, tok0))
-                return waits
-            waits.append(self._bind_slot(i, req2, tok0))
-        return waits
-
     # ----------------------------------------------------------------- loop
     def _idle_or_stop(self) -> bool:
         """No active slots: block briefly on the queue (no hot-spin burning a
@@ -781,7 +747,7 @@ class Engine:
             self._work_event.clear()
             return False
         try:
-            req = self._queue.get(timeout=self.scfg.idle_timeout)
+            req = self._queue.get(timeout=_IDLE_TIMEOUT)
         except queue.Empty:
             with self._lock:
                 if (self._queue.empty() and not self._ready
@@ -789,10 +755,7 @@ class Engine:
                     self._running = False  # chain ends; submit() restarts it
                     return True
             return False
-        if self.scfg.pipeline_admission:
-            self._launch_prefill(req)
-        else:
-            self._queue.put(req)  # inline admission pops it next iteration
+        self._launch_prefill(req)
         return False
 
     def _step(self) -> None:
@@ -802,11 +765,8 @@ class Engine:
                 self._running = False
             return
         with _trace.span("admit", "serve") as span:
-            if self.scfg.pipeline_admission:
-                self._pump_prefills()
-                waits = self._integrate_ready()
-            else:
-                waits = self._admit_inline()
+            self._pump_prefills()
+            waits = self._integrate_ready()
             if waits:
                 span.set(admitted=len(waits), ready_ms=max(waits) * 1e3)
             else:

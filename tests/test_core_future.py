@@ -39,6 +39,15 @@ def test_then_chain(rt):
     assert g.get() == 7
 
 
+def test_long_then_chain_runs_every_link(rt):
+    """A 2,000-link continuation chain runs every link once: none is lost,
+    and resolving the chain does not exhaust the stack."""
+    f = make_ready_future(0)
+    for _ in range(2000):
+        f = f.then_value(lambda x: x + 1)
+    assert f.get(timeout=60) == 2000
+
+
 def test_then_sees_exception(rt):
     f = make_exceptional_future(ValueError("boom"))
     g = f.then(lambda fut: "caught" if fut.has_exception() else "missed")

@@ -159,13 +159,15 @@ def test_router_least_loaded_dispatch(rt, served):
     e0.c_sub.increment(-3)  # restore
 
 
-def test_seed_parity_mode_matches_greedy(rt, served):
-    """The A/B baseline (dense cache + inline-prefill barrier) still
-    produces exact greedy tokens — the bench compares against it."""
-    cfg, model, params = served
+@pytest.mark.parametrize("arch", ["mamba2_780m", "recurrentgemma_2b"])
+def test_dense_slot_families_match_greedy(rt, arch):
+    """Families without paged support (ssm, hybrid) serve through the dense
+    per-slot cache and its exact-length prefill, token for token as a
+    manual prefill + decode greedy loop."""
+    model = build_model(get_config(arch, smoke=True), get_plan("futurized"))
+    params = model.init(jax.random.PRNGKey(1))
     eng = Engine(model, params, ServeConfig(max_batch=2, cache_len=96,
-                                            max_new_tokens=4, paged=False,
-                                            pipeline_admission=False))
+                                            max_new_tokens=4))
     assert not eng.paged
     p = [11, 12, 13]
     assert eng.submit(p).get(timeout=300) == _manual_greedy(model, params, p, 4)
