@@ -11,12 +11,14 @@ the script exits non-zero and prints no result line):
   CPU fallback.
 - ``serve`` — qwen25_3b at its published widths and full depth, bf16
   weights from seed 0, served through ``Router.replicate`` / ``Engine``
-  with the paged KV cache and the default XLA prefill attention: 8 greedy requests
+  with the paged KV cache (on TPU the prefill's attention is the flash
+  kernel, decode the paged kernel): 8 greedy requests
   with prompts spread over 16–1024 tokens, 32 new tokens each.  The same
   requests run twice: the first pass pays the compiles, the second is the
   warm wall time, and both must give the same tokens.
-- ``pallas`` — the same requests with ``attn_impl="pallas"`` (flash prefill;
-  the paged decode kernel runs on TPU in both phases).  The compiled decode
+- ``pallas`` — the same requests with ``attn_impl="pallas"``, which picks
+  only the training path's attention: serving runs the same kernels as in
+  phase serve, through a second model and engine.  The compiled decode
   step must hold a Mosaic kernel (``tpu_custom_call``: nothing runs in
   interpret mode); first tokens must equal phase serve's (a near-tie may
   resolve to another near-tied token: ``TIE_MARGIN``) and prefill logits

@@ -1,24 +1,34 @@
 """Flash attention forward kernel (TPU Pallas): blocked online-softmax.
 
 TPU adaptation of FlashAttention [arXiv:2205.14135] — the HBM→VMEM
-hierarchy replaces SRAM tiling: Q blocks of ``block_q`` rows live in VMEM,
-the kernel streams K/V blocks of ``block_k`` rows, maintaining the running
-(max, sum, acc) online-softmax state in fp32 VMEM scratch.  Block sizes are
-multiples of 128 to keep the MXU systolic array full (DESIGN.md §6).
+hierarchy replaces SRAM tiling: a Q block of ``block_q`` rows lives in
+VMEM, the kernel streams K/V blocks of ``block_k`` rows through it and keeps
+the running (max, sum, acc) online-softmax state in fp32 VMEM scratch.  No
+score leaves VMEM.  Both dots take their operands in the input dtype (bf16
+on the MXU) and accumulate in fp32; P is cast to the value dtype before PV,
+as the XLA path (``layers._sdpa``) does.
 
-Grid: (batch·kv_head·q_group, S/block_q, S/block_k) with the K dimension
-``arbitrary`` (sequential) — the carry lives in scratch across the K steps.
-Causal masking skips fully-masked K blocks via ``pl.when`` (halves the work
-like the original's block-skipping); sliding-window masking composes.
+Layout is head-major: q (B, H, S, Dk), k (B, KV, S, Dk), v (B, KV, S, Dv),
+out (B, H, S, Dv).  Key and value widths come from the inputs (GQA: Dk = Dv
+= Dh; DeepSeek-V2's latent attention in its plain form: Dk = 192, Dv =
+128, KV = H).  Grid: (batch, query head, Q blocks, K blocks), the K axis
+sequential (the carry lives in scratch across it).  GQA without copies:
+the K/V ``index_map`` maps query head ``h`` to its KV head ``h // G``.
 
-GQA layout: callers (ops.py) reshape q to (B·KV·G, S, Dh) and k/v to
-(B·KV, S, Dh); the kernel maps program id → its kv row.
+Each batch row's valid length is scalar-prefetched into SMEM, so one
+compiled kernel serves every prompt length of a bucket.  ``pl.when`` skips
+each K block above the causal diagonal, outside the window or past the
+valid length, and each Q block past it; the K/V ``index_map`` clamps those
+steps to a block the pipeline already holds, so they issue no DMA.  Blocks
+wholly inside the causal triangle and the valid length take an unmasked
+body.  Rows past the valid length are written as zeros; keys past it are
+masked by score and their values zeroed, so whatever they hold (NaN
+included) reaches no sum.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +38,17 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref,
+def _flash_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                       m_scr, l_scr, acc_scr,
                       *, scale: float, block_q: int, block_k: int,
-                      causal: bool, window: int, seq_len: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+                      causal: bool, window: int):
+    """Prefetched valid lengths (B,), then q (1, 1, block_q, Dk), k
+    (1, 1, block_k, Dk), v (1, 1, block_k, Dv) and o (1, 1, block_q, Dv)
+    blocks; scratch m, l (block_q, 1) and acc (block_q, Dv) fp32."""
+    valid = len_ref[pl.program_id(0)]
+    q_start = pl.program_id(2) * block_q
+    ki = pl.program_id(3)
+    k_start = ki * block_k
 
     @pl.when(ki == 0)
     def _init():
@@ -42,86 +56,111 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-
-    def _body():
-        q = q_ref[0].astype(jnp.float32)  # (block_q, Dh)
-        k = k_ref[0].astype(jnp.float32)  # (block_k, Dh)
-        v = v_ref[0].astype(jnp.float32)
+    def _step(masked: bool):
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = kpos < seq_len
-        if causal:
-            ok = jnp.logical_and(ok, kpos <= qpos)
-        if window > 0:
-            ok = jnp.logical_and(ok, kpos > qpos - window)
-        s = jnp.where(ok, s, NEG_INF)
-
+        if masked:
+            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            ok = kpos < valid
+            if causal:
+                ok = jnp.logical_and(ok, kpos <= qpos)
+            if window > 0:
+                ok = jnp.logical_and(ok, kpos > qpos - window)
+            s = jnp.where(ok, s, NEG_INF)
+            vpos = k_start + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(vpos < valid, v, jnp.zeros_like(v))
         m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_scr[...] = m_new
-        l_scr[...] = l_new
 
-    # skip K blocks that are entirely masked out (flash block skipping)
-    if causal or window > 0:
-        run = k_start <= q_start + block_q - 1 if causal else (k_start >= 0)
-        if window > 0:
-            run = jnp.logical_and(run, k_start + block_k > q_start - window + 1)
-        pl.when(run)(_body)
-    else:
-        _body()
+    q_last = q_start + block_q - 1
+    k_last = k_start + block_k - 1
+    run = jnp.logical_and(q_start < valid, k_start < valid)
+    full = k_last < valid
+    if causal:
+        run = jnp.logical_and(run, k_start <= q_last)
+        full = jnp.logical_and(full, k_last <= q_start)
+    if window > 0:
+        run = jnp.logical_and(run, k_last > q_start - window)
+        full = jnp.logical_and(full, k_start > q_last - window)
+    pl.when(jnp.logical_and(run, full))(functools.partial(_step, False))
+    pl.when(jnp.logical_and(run, jnp.logical_not(full)))(
+        functools.partial(_step, True))
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(3) - 1)
     def _finish():
-        l = l_scr[...]
-        o_ref[0] = (acc_scr[...] / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+        o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-20)
+        qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, o.shape, 0)
+        o_ref[0, 0] = jnp.where(qpos < valid, o, 0.0).astype(o_ref.dtype)
 
 
-def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
+def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
+                        valid_len: jax.Array, *, scale: float,
                         causal: bool = True, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
-                        valid_len: int = 0,
                         interpret: bool = False) -> jax.Array:
-    """q: (R, S, Dh) with R = B·KV·G; k/v: (R, S, Dh) (pre-broadcast KV).
-
-    Returns (R, S, Dh). Sequence length must be a multiple of the blocks
-    (ops.py pads); ``valid_len`` masks K positions beyond the true length.
-    """
-    R, S, Dh = q.shape
+    """q: (B, H, S, Dk); k: (B, KV, S, Dk); v: (B, KV, S, Dv) with H a
+    multiple of KV; valid_len: (B,) int32, each row's count of valid
+    positions (at least 1).  Returns (B, H, S, Dv) in q's dtype, zero past
+    each row's valid length.  S must be a multiple of both blocks (ops.py
+    pads)."""
+    B, H, S, Dk = q.shape
+    KV, Dv = k.shape[1], v.shape[3]
+    G = H // KV
     assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
-    scale = 1.0 / math.sqrt(Dh)
-    grid = (R, S // block_q, S // block_k)
+
+    def kv_index(b, h, qi, ki, lens):
+        last = jnp.maximum(lens[b] - 1, 0)
+        tail = last // block_k  # the last block any query needs
+        if causal:
+            last = jnp.minimum(last, qi * block_q + block_q - 1)
+        kb = jnp.minimum(ki, last // block_k)
+        if window > 0:
+            kb = jnp.maximum(kb, jnp.maximum(qi * block_q - window + 1, 0)
+                             // block_k)
+            kb = jnp.minimum(kb, tail)
+        # a Q block past the valid length keeps the block the last valid
+        # one ended on
+        return (b, h // G, jnp.where(qi * block_q <= last, kb, tail), 0)
+
+    def q_index(b, h, qi, ki, lens):
+        return (b, h, jnp.minimum(qi, jnp.maximum(lens[b] - 1, 0) // block_q), 0)
 
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-        causal=causal, window=window, seq_len=valid_len or S)
-
+        causal=causal, window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, H, S // block_q, S // block_k),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, Dk), q_index),
+            pl.BlockSpec((1, 1, block_k, Dk), kv_index),
+            pl.BlockSpec((1, 1, block_k, Dv), kv_index),
+        ],
+        out_specs=pl.BlockSpec((1, 1, block_q, Dv),
+                               lambda b, h, qi, ki, lens: (b, h, qi, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
+        ],
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, Dh), lambda r, qi, ki: (r, qi, 0)),
-            pl.BlockSpec((1, block_k, Dh), lambda r, qi, ki: (r, ki, 0)),
-            pl.BlockSpec((1, block_k, Dh), lambda r, qi, ki: (r, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, Dh), lambda r, qi, ki: (r, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, S, Dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, Dh), jnp.float32),
-        ],
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(jnp.asarray(valid_len, jnp.int32), q, k, v)

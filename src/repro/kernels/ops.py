@@ -4,18 +4,20 @@ Single-source contract (the HPX.Compute claim, DESIGN.md P7): call sites
 use these ops everywhere; on TPU they run the Mosaic-compiled kernels, on
 CPU they execute the same kernel bodies under ``interpret=True`` — one
 source, two backends, identical semantics (tests assert allclose against
-``ref.py`` oracles on both paths).  The exception is paged decode
-attention (:func:`paged_attention`), which the serving engine runs every
-step: off TPU it takes the XLA gather path, which is also the kernel
-tests' oracle, rather than the interpreter.
+``ref.py`` oracles on both paths).  The exceptions are what the serving
+engine runs on every request, paged decode attention
+(:func:`paged_attention`) and the prefill's causal self-attention
+(:func:`prefill_attention`): off TPU they take the XLA path, which is also
+the kernel tests' oracle, rather than the interpreter.
 
-Wrappers own the ugly parts: GQA head broadcasting, layout flattening to
-kernel-friendly (rows, seq, feature) shapes, and padding to block multiples.
+Wrappers own the ugly parts: layout transposes to the kernels' head-major
+shapes and padding to block multiples.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -53,19 +55,68 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if interpret is None:
         interpret = not _on_tpu()
     B, S, H, Dh = q.shape
-    KV = k.shape[2]
-    G = H // KV
-    qr = q.reshape(B, S, KV, G, Dh).transpose(0, 2, 3, 1, 4).reshape(B * KV * G, S, Dh)
-    kr = jnp.repeat(k.transpose(0, 2, 1, 3), G, axis=1).reshape(B * KV * G, S, Dh)
-    vr = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(B * KV * G, S, Dh)
-    qr, S0 = _pad_to(qr, 1, max(block_q, block_k))
-    kr, _ = _pad_to(kr, 1, max(block_q, block_k))
-    vr, _ = _pad_to(vr, 1, max(block_q, block_k))
-    o = flash_attention_fwd(qr, kr, vr, causal=causal, window=window,
-                            block_q=block_q, block_k=block_k, valid_len=S0,
+    blk = max(block_q, block_k)
+    qh, _ = _pad_to(q.transpose(0, 2, 1, 3), 2, blk)
+    kh, _ = _pad_to(k.transpose(0, 2, 1, 3), 2, blk)
+    vh, _ = _pad_to(v.transpose(0, 2, 1, 3), 2, blk)
+    o = flash_attention_fwd(qh, kh, vh, jnp.full((B,), S, jnp.int32),
+                            scale=1.0 / math.sqrt(Dh), causal=causal,
+                            window=window, block_q=block_q, block_k=block_k,
                             interpret=interpret)
-    o = o[:, :S0]
-    return o.reshape(B, KV, G, S0, Dh).transpose(0, 3, 1, 2, 4).reshape(B, S0, H, Dh)
+    return o[:, :, :S].transpose(0, 2, 1, 3)
+
+
+# The prefill kernel's blocks, from a sweep on one v5e at the 1,024-4,096
+# buckets in both geometries (PERF.md).
+PREFILL_BLOCK_Q = 512
+PREFILL_BLOCK_K = 512
+
+
+def prefill_blocks(S: int) -> Tuple[int, int, int]:
+    """(block_q, block_k, padded length) of the prefill kernel at sequence
+    length ``S``: the swept blocks, or one block of the whole (16-row
+    aligned) sequence where it is shorter."""
+    n = -(-S // 16) * 16
+    bq, bk = min(PREFILL_BLOCK_Q, n), min(PREFILL_BLOCK_K, n)
+    blk = max(bq, bk)
+    return bq, bk, -(-n // blk) * blk
+
+
+def prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                      valid_len: Optional[jax.Array], *,
+                      scale: float) -> jax.Array:
+    """Causal self-attention over a prefill's (padded) sequence (P7: the
+    Pallas flash kernel on TPU, XLA elsewhere).
+
+    q: (B, S, KV, G, Dk); k: (B, S, KV, Dk); v: (B, S, KV, Dv); valid_len:
+    (B,) or scalar int32 valid positions, or None for all S.  Returns
+    (B, S, KV, G, Dv).  Rows at or past ``valid_len`` are the kernel's
+    zeros on TPU; no valid row depends on them either way.
+    """
+    if _on_tpu():
+        return prefill_attention_kernel(q, k, v, valid_len, scale=scale)
+    from repro.models.layers import causal_attention
+
+    return causal_attention(q, k, v, scale)
+
+
+def prefill_attention_kernel(q, k, v, valid_len, *, scale: float,
+                             interpret: Optional[bool] = None) -> jax.Array:
+    """:func:`prefill_attention` through the Pallas flash kernel
+    (:func:`~repro.kernels.flash_attention.flash_attention_fwd`), in
+    interpret mode off TPU."""
+    if interpret is None:
+        interpret = not _on_tpu()
+    B, S, KV, G, Dk = q.shape
+    bq, bk, Sp = prefill_blocks(S)
+    vl = jnp.broadcast_to(jnp.asarray(S if valid_len is None else valid_len,
+                                      jnp.int32), (B,))
+    qh, _ = _pad_to(q.reshape(B, S, KV * G, Dk).transpose(0, 2, 1, 3), 2, Sp)
+    kh, _ = _pad_to(k.transpose(0, 2, 1, 3), 2, Sp)
+    vh, _ = _pad_to(v.transpose(0, 2, 1, 3), 2, Sp)
+    o = flash_attention_fwd(qh, kh, vh, vl, scale=scale, causal=True,
+                            block_q=bq, block_k=bk, interpret=interpret)
+    return o[:, :, :S].transpose(0, 2, 1, 3).reshape(B, S, KV, G, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))

@@ -161,13 +161,30 @@ def _causal_mask(sq: int, t: int, q_start, window: int = 0) -> jax.Array:
     return jnp.where(ok, 0.0, -1e30).astype(jnp.float32)
 
 
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     scale: float) -> jax.Array:
+    """Exact causal attention in XLA, q: (B,S,KV,G,Dk), k: (B,S,KV,Dk),
+    v: (B,S,KV,Dv): one score matrix up to ``_CHUNK_THRESHOLD`` positions,
+    Q-chunked above it."""
+    S = q.shape[1]
+    if S <= _CHUNK_THRESHOLD:
+        return _sdpa(q, k, v, _causal_mask(S, S, 0), scale)
+    return _chunked_attention(q, k, v, scale, True)
+
+
 def attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
               p: Dict[str, jax.Array], prefix: str, positions: jax.Array,
-              causal: bool = True, window: int = 0, return_kv: bool = False):
+              causal: bool = True, window: int = 0, return_kv: bool = False,
+              valid_len: Optional[jax.Array] = None):
     """Self-attention over full sequences (train / prefill path).
 
-    With ``return_kv=True`` also returns the (post-RoPE) K/V used — the
-    prefill path collects them into the cache in the same pass.
+    With ``return_kv=True`` (prefill) also returns the (post-RoPE) K/V used
+    — the prefill path collects them into the cache in the same pass — and
+    causal, unwindowed attention goes through
+    :func:`repro.kernels.ops.prefill_attention` under the named scope
+    ``prefill_attention`` (on TPU the flash kernel, which skips blocks past
+    ``valid_len``: (B,) valid positions of a right-padded prompt, or None
+    for all).  ``cfg.attn_impl`` picks the training path only.
     """
     dt = cdtype(cfg)
     B, S, D = x.shape
@@ -186,9 +203,12 @@ def attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
     v = plan.constrain(v, ("batch", "seq", None, None))
     scale = 1.0 / math.sqrt(Dh)
 
-    if cfg.attn_impl == "pallas":  # flash kernel path (single source, P7)
-        from repro.kernels import ops as kops
+    from repro.kernels import ops as kops
 
+    if return_kv and causal and window == 0:
+        with jax.named_scope("prefill_attention"):
+            o = kops.prefill_attention(q, k, v, valid_len, scale=scale)
+    elif cfg.attn_impl == "pallas":  # flash kernel path (single source, P7)
         o = kops.flash_attention_trainable(
             q.reshape(B, S, KV, G, Dh).reshape(B, S, H, Dh), k, v,
             causal, window).reshape(B, S, KV, G, Dh)
@@ -498,12 +518,15 @@ def _mla_project(cfg: ModelConfig, x: jax.Array, p: Dict[str, jax.Array],
 
 def mla_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
                   p: Dict[str, jax.Array], prefix: str, positions: jax.Array,
-                  return_kv: bool = False):
+                  return_kv: bool = False,
+                  valid_len: Optional[jax.Array] = None):
     """Causal multi-head latent attention over full sequences (train /
     prefill), as DeepSeek-V2 writes it: ``k_nope ‖ v = c_kv W_kv_b`` per
     head, the one ``k_pe`` shared by every head.  With ``return_kv=True``
-    also returns ``(latent,)``: the (B, S, 1, :func:`mla_row_width`) rows
-    the cache holds.
+    (prefill) also returns ``(latent,)``: the (B, S, 1,
+    :func:`mla_row_width`) rows the cache holds; the attention then goes
+    through :func:`repro.kernels.ops.prefill_attention` under the named
+    scope ``prefill_attention``, as in :func:`attention`.
     """
     dt = cdtype(cfg)
     B, S, _ = x.shape
@@ -519,10 +542,13 @@ def mla_attention(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
     q = plan.constrain(q[:, :, :, None, :], ("batch", "seq", None, None, None))
     scale = mla_softmax_scale(cfg)
-    if S <= _CHUNK_THRESHOLD:
-        o = _sdpa(q, k, v, _causal_mask(S, S, 0), scale)
+    if return_kv:
+        from repro.kernels import ops as kops
+
+        with jax.named_scope("prefill_attention"):
+            o = kops.prefill_attention(q, k, v, valid_len, scale=scale)
     else:
-        o = _chunked_attention(q, k, v, scale, True)
+        o = causal_attention(q, k, v, scale)
     out = o.reshape(B, S, H * Dv) @ p[f"{prefix}wo"].astype(dt)
     if return_kv:
         return out, (lat[:, :, None, :],)

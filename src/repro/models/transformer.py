@@ -141,20 +141,22 @@ def stacked_gather_constrain(plan: ShardingPlan, tree: Dict[str, jax.Array],
 # ------------------------------------------------------------------ blocks
 def _layer_body(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
                 lp: Dict[str, jax.Array], positions: jax.Array,
-                moe_layer: bool, collect_kv: bool = False):
+                moe_layer: bool, collect_kv: bool = False,
+                valid_len: Optional[jax.Array] = None):
     """One layer over whole sequences.  ``collect_kv`` (prefill) also
     returns what the cache holds of the layer, as a tuple in
-    :func:`cache_keys` order, and routes MoE layers as serving does (no
-    token dropped); training keeps capacity dispatch."""
+    :func:`cache_keys` order, routes MoE layers as serving does (no token
+    dropped) and gives the attention each row's ``valid_len``; training
+    keeps capacity dispatch."""
     x = plan.constrain(x, ("batch", "seq_sp", None))
     h = Lx.norm(cfg, x, lp["ln1"])
     if cfg.mla:
         attn_out = Lx.mla_attention(cfg, plan, h, lp, "", positions,
-                                    return_kv=collect_kv)
+                                    return_kv=collect_kv, valid_len=valid_len)
     else:
         attn_out = Lx.attention(cfg, plan, h, lp, "", positions,
                                 causal=cfg.causal, window=cfg.window,
-                                return_kv=collect_kv)
+                                return_kv=collect_kv, valid_len=valid_len)
     if collect_kv:
         h, kv = attn_out
     else:
@@ -170,7 +172,8 @@ def _layer_body(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
 
 def _run_stack(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
                stacked: Dict[str, jax.Array], axes: Dict[str, Tuple],
-               positions: jax.Array, moe_layer: bool, collect_kv: bool = False):
+               positions: jax.Array, moe_layer: bool, collect_kv: bool = False,
+               valid_len: Optional[jax.Array] = None):
     """lax.scan over a stacked layer dict; returns (x, aux_sum, stacked
     cache rows: a tuple in :func:`cache_keys` order, or None)."""
 
@@ -178,7 +181,8 @@ def _run_stack(cfg: ModelConfig, plan: ShardingPlan, x: jax.Array,
         x, aux_sum = carry
         if not plan.gather_upfront:  # futurized: per-layer gather point
             lp = gather_constrain(plan, lp, axes)
-        x, aux, kv = _layer_body(cfg, plan, x, lp, positions, moe_layer, collect_kv)
+        x, aux, kv = _layer_body(cfg, plan, x, lp, positions, moe_layer,
+                                 collect_kv, valid_len)
         return (x, aux_sum + aux), kv
 
     body = Lx.remat_wrap(plan, body)
@@ -446,7 +450,9 @@ def prefill(cfg: ModelConfig, plan: ShardingPlan, params: Dict[str, jax.Array],
     serve engine pads to static buckets so admission never recompiles):
     logits are taken at position ``valid_len - 1`` instead of ``S - 1`` and
     the cache ``pos`` starts at ``valid_len``.  Causality makes the pad
-    positions inert — no valid token attends to them.
+    positions inert — no valid token attends to them; the attention is
+    given ``valid_len`` too, and on TPU skips the pad (whose rows it leaves
+    zero, so the pad's cache rows differ from an unpadded run's, unread).
     """
     specs = decoder_param_specs(cfg)
     B, S = tokens.shape
@@ -456,6 +462,8 @@ def prefill(cfg: ModelConfig, plan: ShardingPlan, params: Dict[str, jax.Array],
         x = jnp.concatenate([patches.astype(x.dtype), x[:, cfg.n_patches:, :]], axis=1)
     positions = jnp.arange(S, dtype=jnp.int32)
     cache = init_cache(cfg, B, T)
+    vl = None if valid_len is None else jnp.broadcast_to(
+        jnp.asarray(valid_len, jnp.int32), (B,))
 
     for group, _ in _groups(cfg):
         stacked = _slice_params(params, group)
@@ -464,14 +472,13 @@ def prefill(cfg: ModelConfig, plan: ShardingPlan, params: Dict[str, jax.Array],
             stacked = stacked_gather_constrain(plan, stacked, ax)
         x, _, rows = _run_stack(cfg, plan, x, stacked, ax, positions,
                                 moe_layer=cfg.is_moe and group == "blk/",
-                                collect_kv=True)
+                                collect_kv=True, valid_len=vl)
         for key, r in zip(cache_keys(cfg, group), rows):
             cache[key] = _place(cache[key], r)
-    if valid_len is None:
+    if vl is None:
         cache["pos"] = jnp.full((B,), S, jnp.int32)
         x_last = x[:, -1:, :]
     else:
-        vl = jnp.broadcast_to(jnp.asarray(valid_len, jnp.int32), (B,))
         cache["pos"] = vl
         idx = jnp.clip(vl - 1, 0, S - 1)
         x_last = jnp.take_along_axis(

@@ -41,6 +41,46 @@ def test_flash_attention_window():
     np.testing.assert_allclose(np.asarray(o), np.asarray(e), atol=1e-4)
 
 
+@pytest.mark.parametrize("valid", [1, 127, 128, 129, 384])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("geometry", ["gqa", "latent"])
+def test_prefill_flash_attention_matches_sdpa(geometry, dtype, valid,
+                                              monkeypatch):
+    """The prefill kernel (interpret mode, 128-row blocks over a 384-token
+    bucket) against ``layers._sdpa`` in float32 on the valid rows, at valid
+    lengths about the block edges.  GQA: 2 KV heads of 8 queries, width
+    128; latent: 16 heads, keys 192 wide, values 128, the MLA scale.  K/V
+    past the valid length hold NaN: the valid rows are unmoved and every
+    row is finite, the pad rows zero."""
+    from repro.configs import get_config
+    from repro.models import layers
+
+    monkeypatch.setattr(ops, "PREFILL_BLOCK_Q", 128)
+    monkeypatch.setattr(ops, "PREFILL_BLOCK_K", 128)
+    if geometry == "gqa":
+        KV, G, Dk, Dv, scale = 2, 8, 128, 128, 128 ** -0.5
+    else:
+        KV, G, Dk, Dv = 16, 1, 192, 128
+        scale = layers.mla_softmax_scale(get_config("deepseek_v2_lite"))
+    S = 384
+    ks = jax.random.split(jax.random.PRNGKey(valid), 3)
+    q = jax.random.normal(ks[0], (1, S, KV, G, Dk), dtype)
+    k = jax.random.normal(ks[1], (1, S, KV, Dk), dtype)
+    v = jax.random.normal(ks[2], (1, S, KV, Dv), dtype)
+    pad = (jnp.arange(S) >= valid)[None, :, None, None]
+    k, v = jnp.where(pad, jnp.nan, k), jnp.where(pad, jnp.nan, v)
+    o = ops.prefill_attention_kernel(q, k, v, jnp.asarray([valid]),
+                                     scale=scale)
+    assert o.shape == (1, S, KV, G, Dv) and o.dtype == dtype
+    f32 = [a[:, :valid].astype(jnp.float32) for a in (q, k, v)]
+    want = layers._sdpa(*f32, layers._causal_mask(valid, valid, 0), scale)
+    got = np.asarray(o, np.float32)
+    assert np.isfinite(got).all()
+    assert not got[:, valid:].any()
+    np.testing.assert_allclose(got[:, :valid], np.asarray(want),
+                               atol=_tol(dtype) * 2)
+
+
 @pytest.mark.parametrize("B,T,H,KV,Dh,length", [
     (2, 512, 4, 2, 64, 300),
     (1, 1024, 8, 8, 32, 1024),

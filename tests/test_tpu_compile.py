@@ -10,6 +10,7 @@ test file.  Keep all such compiles in this one file.
 """
 
 import dataclasses
+import math
 import os
 import re
 
@@ -22,10 +23,9 @@ from repro.configs import get_config
 from repro.dist.plan import get_plan
 from repro.kernels import ops
 from repro.kernels.decode_attention import decode_attention_fwd
-from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rglru_scan import rglru_scan_fwd
 from repro.kernels.ssd_scan import ssd_scan_fwd
-from repro.models.layers import mla_row_width
+from repro.models.layers import mla_row_width, mla_softmax_scale
 from repro.models.model import build_model
 
 # (num_heads, num_kv_heads, head_dim) of the serving configs
@@ -95,14 +95,37 @@ def test_dense_decode_kernel_compiles(one_chip, arch):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("arch", sorted(WIDTHS))
+def _prefill_geometry(cfg):
+    """(KV, G, Dk, Dv, scale) of a config's prefill attention: its GQA
+    heads, or under MLA the plain form's per-head keys and values."""
+    if cfg.mla:
+        return (cfg.num_heads, 1, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                cfg.v_head_dim, mla_softmax_scale(cfg))
+    KV = cfg.num_kv_heads
+    return KV, cfg.num_heads // KV, cfg.head_dim, cfg.head_dim, 0.1
+
+
+@pytest.mark.parametrize("arch", sorted(WIDTHS) + ["deepseek_v2_lite"])
 def test_flash_attention_kernel_compiles(one_chip, arch):
-    H, KV, Dh = WIDTHS[arch]
-    R, S = H, 1024  # one request's heads, a 1024-token prefill bucket
+    """The prefill kernel as the TPU path runs it, one request at the
+    4,096-token bucket, K/V not repeated per query head."""
+    KV, G, Dk, Dv, scale = _prefill_geometry(get_config(arch))
+    S = 4096
     bf = jnp.bfloat16
-    hlo = _compile(flash_attention_fwd, one_chip,
-                   ((R, S, Dh), bf), ((R, S, Dh), bf), ((R, S, Dh), bf))
-    assert "tpu_custom_call" in hlo
+
+    def attend(q, k, v, n):
+        return ops.prefill_attention_kernel(q, k, v, n, scale=scale,
+                                            interpret=False)
+
+    hlo = _compile(attend, one_chip, ((1, S, KV, G, Dk), bf),
+                   ((1, S, KV, Dk), bf), ((1, S, KV, Dv), bf),
+                   ((1,), jnp.int32))
+    # the kernel's operands: lengths, q per query head, K/V per KV head
+    operands = re.search(r'custom_call_target="tpu_custom_call", '
+                         r'operand_layout_constraints=\{(.*?)\}, \w+=',
+                         hlo).group(1)
+    assert re.findall(r"\w+\[([\d,]*)\]", operands) == [
+        "1", f"1,{KV * G},{S},{Dk}", f"1,{KV},{S},{Dk}", f"1,{KV},{S},{Dv}"]
 
 
 _DTYPE_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
@@ -228,6 +251,51 @@ def test_paged_latent_decode_step_runs_the_kernel_in_place(one_chip,
     writes = _donated_outputs(hlo) & set(large)
     assert len(writes) == 2, large  # one in-place write per pool
     assert set(large) == writes, large
+
+
+@pytest.mark.parametrize("arch", ["qwen25_3b", "deepseek_v2_lite"])
+def test_prefill_program_holds_no_score_matrix(one_chip, monkeypatch, arch):
+    """The serving prefill program as the TPU compiles it (``ops._on_tpu``
+    patched: the flash kernel), 2 layers at the published widths, one
+    request at the 4,096-token bucket: every layer's attention is the
+    kernel, and no value of the module, inside a fusion or not, holds even
+    one 1,024-query chunk of the heads' float32 scores.  XLA's path fuses
+    its scores into the softmax and PV fusions, each computing them anew
+    over the whole bucket, four chunks a layer."""
+    cfg = dataclasses.replace(get_config(arch), num_layers=2,
+                              param_dtype="bfloat16", expert_shard=(0, 8))
+    model = build_model(cfg, get_plan("serve"))
+    S = 4096
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {n: on_chip(s.shape, s.dtype)
+              for n, s in model.abstract_params().items()}
+    pin = {"tokens": on_chip((1, S), jnp.int32)}
+
+    def compile_hlo():  # a new function each time: traced anew
+        def prefill(params, pin, n):
+            return model.prefill(params, pin, cache_len=S, valid_len=n)
+
+        return jax.jit(prefill).lower(params, pin, on_chip((1,), jnp.int32)
+                                      ).compile().as_text()
+
+    def largest_scores(hlo):
+        """Elements of the largest float32 value shaped (..., queries, S)."""
+        sizes = [0]
+        for dims in re.findall(r"\bf32\[([\d,]*)\]", hlo):
+            d = [int(x) for x in dims.split(",") if x]
+            if len(d) >= 2 and d[-1] == S and d[-2] >= 1024:
+                sizes.append(math.prod(d))
+        return max(sizes)
+
+    chunk = cfg.num_heads * 1024 * S
+    assert largest_scores(compile_hlo()) >= chunk  # XLA's path, as off TPU
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    hlo = compile_hlo()
+    assert "tpu_custom_call" in hlo
+    assert largest_scores(hlo) < chunk
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError, reason=(
